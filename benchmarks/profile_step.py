@@ -154,6 +154,10 @@ def main() -> None:
                    help="skip capture; attribute an existing .xplane.pb")
     args = p.parse_args()
 
+    from tpu_trainer.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     path = args.xplane or _capture(args)
     print(f"# xplane: {path}", file=sys.stderr)
     table = _hlo_stats(path)

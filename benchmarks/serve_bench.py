@@ -255,7 +255,12 @@ def main(argv=None) -> int:
                         "behind the same front-end; with --ab, lane A is "
                         "the identical fleet in-process — the transport "
                         "A/B on one trace, stamping per-request RPC "
-                        "overhead on the rpc record")
+                        "overhead on the rpc record. A CPU-fleet tool: "
+                        "a TPU chip belongs to ONE process, this parent "
+                        "builds the params (so it holds the chip before "
+                        "it spawns) and N workers cannot share what is "
+                        "left — run it with JAX_PLATFORMS=cpu; on a chip "
+                        "use --replicas (in-process fleet)")
     p.add_argument("--worker-kill", type=int, default=0,
                    help="with --workers: add a lane that SIGKILLs one "
                         "worker process at this front-end iteration "
@@ -349,6 +354,10 @@ def main(argv=None) -> int:
                         "bit-identity A/B for 'tracing is free'; on by "
                         "default)")
     args = p.parse_args(argv)
+
+    from tpu_trainer.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.profile_trace and (args.replicas > 0 or args.workers > 0):
         p.error("--profile-trace profiles the single-engine serve loop; "

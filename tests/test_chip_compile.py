@@ -1,0 +1,144 @@
+"""Compile the main-path Pallas kernels for a DESCRIBED TPU v5e.
+
+No chip is attached here: the TPU compiler that ships with jaxlib lowers
+each kernel for ``v5e:2x2`` from shapes alone, and raises what the chip's
+compiler would raise (tiling rules, VMEM limits) — the things interpret
+mode cannot see. A compile that passes is not a chip run.
+
+The topology is described inside the module-scoped ``topo`` fixture — never
+at import, in a ``skipif`` or a ``parametrize`` argument — so every xdist
+worker collects the same tests and only the worker that runs this file
+loads libtpu. Keep every such test in THIS file.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from tpu_trainer.ops.flash import flash_attention, flash_decode
+from tpu_trainer.ops.grouped_matmul import gmm, tgmm
+from tpu_trainer.ops.head_ce import pallas_head_ce
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A described-device executable is written to the persistent cache but
+    # cannot be read back without a chip; keep the cache out of it.
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """``chip(shape, dtype)`` -> ShapeDtypeStruct placed on one v5e."""
+    one = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one)
+
+
+def _compile(fn, *shapes) -> str:
+    """Lower + compile for the described chip; return the compiled HLO."""
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    return text
+
+
+# --- training kernels ------------------------------------------------------
+
+@pytest.mark.parametrize("b,s,h,d", [(8, 1024, 12, 64), (2, 4096, 12, 64),
+                                     (4, 2048, 16, 128)])
+def test_flash_attention_fwd_and_grad(chip, b, s, h, d):
+    # s=1024: fused backward (the `small` preset's training shape);
+    # s=4096: the split dkv/dq backward; d=128: the queued configurations.
+    x = chip((b, s, h, d), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    _compile(flash_attention, x, x, x)
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+
+
+def test_flash_attention_rope_dropout_fused(chip):
+    b, s, h, d = 8, 1024, 12, 64
+    x = chip((b, s, h, d), jnp.bfloat16)
+    tab = chip((s, d), jnp.float32)
+    key = chip((2,), jnp.uint32)
+
+    def loss(q, k, v, cos, sin, key):
+        out = flash_attention(q, k, v, rope=(cos, sin), dropout_rate=0.1,
+                              dropout_rng=jax.random.wrap_key_data(key))
+        return out.astype(jnp.float32).sum()
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), x, x, x, tab, tab, key)
+
+
+@pytest.mark.parametrize("vocab", [50257, 50304])
+def test_pallas_head_ce_fwd_and_grad(chip, vocab):
+    b, s, hid = 8, 1024, 768
+    emb = chip((vocab, hid), jnp.float32)
+    x = chip((b, s, hid), jnp.bfloat16)
+    labels = chip((b, s), jnp.int32)
+    mask = chip((b, s), jnp.float32)
+    _compile(pallas_head_ce, emb, x, labels, mask)
+    _compile(jax.grad(pallas_head_ce, argnums=(0, 1)), emb, x, labels, mask)
+
+
+def test_grouped_matmul_fwd_wgrad_and_grad(chip):
+    g, hid, n, e = 8192, 768, 3072, 8
+    lhs = chip((g, hid), jnp.bfloat16)
+    rhs = chip((e, hid, n), jnp.bfloat16)
+    dout = chip((g, n), jnp.bfloat16)
+    sizes = chip((e,), jnp.int32)
+    kernel = dict(use_kernel=True, interpret=False)
+
+    def loss(lhs, rhs, sizes):
+        return gmm(lhs, rhs, sizes, **kernel).astype(jnp.float32).sum()
+
+    _compile(functools.partial(gmm, **kernel), lhs, rhs, sizes)
+    _compile(functools.partial(tgmm, **kernel), lhs, dout, sizes)
+    _compile(jax.grad(loss, argnums=(0, 1)), lhs, rhs, sizes)
+
+
+# --- serving kernel --------------------------------------------------------
+
+# (heads, kv_heads, head_dim): GPT-2-small serving geometry, then the d=128
+# geometries every queued configuration has (MHA and GQA group 4).
+_DECODE_GEOMETRIES = [(12, 12, 64), (16, 16, 128), (16, 4, 128)]
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("h,kvh,d", _DECODE_GEOMETRIES)
+def test_flash_decode(chip, h, kvh, d, int8):
+    b, bsz, mb = 8, 16, 64
+    nblk = b * mb + 1
+    q = chip((b, h, d), jnp.bfloat16)
+    pool = chip((nblk, bsz, kvh, d), jnp.int8 if int8 else jnp.bfloat16)
+    tables = chip((b, mb), jnp.int32)
+    lengths = chip((b,), jnp.int32)
+    if int8:
+        scale = chip((nblk, bsz, kvh, 1), jnp.float32)
+
+        def fn(q, pk, pv, tb, ln, sk, sv):
+            return flash_decode(q, pk, pv, tb, ln, k_scale=sk, v_scale=sv,
+                                interpret=False)
+
+        _compile(fn, q, pool, pool, tables, lengths, scale, scale)
+    else:
+        _compile(functools.partial(flash_decode, interpret=False),
+                 q, pool, pool, tables, lengths)

@@ -246,3 +246,89 @@ class TestMetricLogger:
         peak = 100e12
         tok_s = peak / fpt
         assert mfu(tok_s, cfg, n_chips=1, peak_flops=peak) == pytest.approx(1.0)
+
+
+class TestDevicePeaks:
+    """No made-up hardware: an unknown TPU kind is an error, and off-TPU
+    there is no peak (so no MFU) rather than an assumed one."""
+
+    def test_known_kinds_match_reported_strings(self):
+        from tpu_trainer.utils.logging import peak_flops_for_kind
+
+        # What jax reports for a v5e, and the short spellings users pass.
+        assert peak_flops_for_kind("TPU v5 lite") == 197e12
+        assert peak_flops_for_kind("v5e") == 197e12
+        assert peak_flops_for_kind("TPU v5p") == 459e12
+        assert peak_flops_for_kind("TPU v4") == 275e12
+
+    @pytest.mark.parametrize("kind", ["TPU v9", "", "cpu", None])
+    def test_unknown_kind_raises(self, kind):
+        from tpu_trainer.utils.logging import peak_flops_for_kind
+
+        with pytest.raises(ValueError, match="no peak FLOP/s on record"):
+            peak_flops_for_kind(kind)
+
+    def test_unknown_tpu_device_raises_and_cpu_has_no_peak(self):
+        from types import SimpleNamespace
+
+        from tpu_trainer.utils.logging import device_peak_flops
+
+        with pytest.raises(ValueError, match="TPU v9"):
+            device_peak_flops(
+                SimpleNamespace(platform="tpu", device_kind="TPU v9"))
+        assert device_peak_flops(jax.devices()[0]) is None
+        # ... so MFU is absent on the CPU, not computed against a guess.
+        assert mfu(1000.0, GPTConfig.gpt2_small()) is None
+
+    def test_unknown_kind_has_no_ici_default(self):
+        from tpu_trainer.parallel.comms_model import _ici_bytes_per_sec
+
+        assert _ici_bytes_per_sec("TPU v5 lite") == _ici_bytes_per_sec("v5e")
+        with pytest.raises(ValueError, match="no ICI bandwidth on record"):
+            _ici_bytes_per_sec("TPU v9")
+
+
+class TestCompileCachePlacement:
+    def test_env_var_is_left_to_jax(self, monkeypatch, tmp_path):
+        from tpu_trainer.utils import compile_cache
+
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before  # untouched
+
+    def test_default_is_one_fixed_dir_in_the_checkout(self, monkeypatch):
+        from tpu_trainer.utils import compile_cache
+
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        # This process is pinned to the CPU: it is told the path, and the
+        # cache stays off (XLA:CPU entries are not worth keeping).
+        assert compile_cache.enable_compile_cache() == os.path.join(
+            repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.setattr(compile_cache, "_pinned_to_cpu", lambda: False)
+        try:
+            got = compile_cache.enable_compile_cache()
+            assert got == os.path.join(repo, ".jax_cache")
+            assert got == compile_cache.enable_compile_cache()  # no pid/time
+            assert jax.config.jax_compilation_cache_dir == got
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+
+
+class TestChipSmokeRefusesTheCpu:
+    def test_exits_nonzero_without_a_phase(self):
+        import subprocess
+        import sys
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        env.pop("XLA_FLAGS", None)
+        proc = subprocess.run(
+            [sys.executable, os.path.join(repo, "chip_smoke.py")],
+            env=env, capture_output=True, text=True, timeout=120)
+        assert proc.returncode != 0
+        assert "no TPU" in proc.stderr and "'cpu'" in proc.stderr
+        assert proc.stdout.strip() == ""  # no phase line, no result line

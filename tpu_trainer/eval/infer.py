@@ -83,6 +83,9 @@ def main(argv=None) -> int:
 
     if args.device == "cpu":
         force_cpu()
+    from tpu_trainer.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     path = args.checkpoint
     resolved = latest_checkpoint(path)

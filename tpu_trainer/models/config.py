@@ -141,7 +141,7 @@ class GPTConfig:
     # exact f32; backward probabilities carry bf16 rounding (same order as
     # the flash kernel's backward). The kernel shard_maps over batch
     # (data x fsdp) AND sequence axes, and an expert axis (which shards
-    # only expert params) does not block it. Falls back off-TPU; under a
+    # only expert params) does not prevent it. Falls back off-TPU; under a
     # stage axis the pipeline owns the head, and under single-stage TP
     # the loss routes to the vocab-sharded XLA head (ops/loss._tp_loss).
     fused_loss_pallas: bool = True

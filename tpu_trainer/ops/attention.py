@@ -97,6 +97,7 @@ def _sharded_kernel(q, k, v, mesh, kernel_kwargs):
     from tpu_trainer.utils.jax_compat import shard_map
     from jax.sharding import PartitionSpec as P
 
+    from tpu_trainer.parallel.context import kernel_manual_axes
     from tpu_trainer.parallel.mesh import (
         attention_shard_coord, attention_shard_spec,
     )
@@ -143,7 +144,8 @@ def _sharded_kernel(q, k, v, mesh, kernel_kwargs):
             segment_ids=seg_local, **static_kwargs
         )
 
-    # Manual only over the axes this wrapper actually shards: other axes
+    # Manual over the axes this wrapper actually shards, plus the size-1
+    # axes Mosaic needs manual too (kernel_manual_axes): other sharded axes
     # (e.g. a pipeline `stage` axis whose manual region we may be nested
     # inside) stay untouched, letting the kernel keep its batch/head
     # sharding inside the GPipe stage body. When tracing inside another
@@ -155,23 +157,18 @@ def _sharded_kernel(q, k, v, mesh, kernel_kwargs):
         used_axes.update(b_spec)
     if h_spec is not None:
         used_axes.add(h_spec)
-    try:
-        from jax.sharding import get_abstract_mesh
-    except ImportError:  # old jax: no abstract meshes — trace on the
-        get_abstract_mesh = None  # concrete mesh as before
+    from jax.sharding import get_abstract_mesh
 
     sm_mesh = mesh
-    if get_abstract_mesh is not None:
-        ctx_mesh = get_abstract_mesh()
-        if (getattr(ctx_mesh, "shape_tuple", ())
-                and ctx_mesh.shape == mesh.shape):
-            sm_mesh = ctx_mesh
+    ctx_mesh = get_abstract_mesh()
+    if ctx_mesh.shape_tuple and ctx_mesh.shape == mesh.shape:
+        sm_mesh = ctx_mesh
     fn = shard_map(
         local,
         mesh=sm_mesh,
         in_specs=(spec, spec, spec) + extra_specs,
         out_specs=spec,
-        axis_names=used_axes,
+        axis_names=kernel_manual_axes(mesh, used_axes),
         check_vma=False,
     )
     return fn(q, k, v, *extras)
@@ -242,23 +239,20 @@ def flash_attention(
     interpret = os.environ.get(_INTERPRET_ENV, "0") == "1"
     on_tpu = any(d.platform == "tpu" for d in jax.devices())
     if on_tpu or interpret:
-        try:
-            from tpu_trainer.ops import flash
-        except ImportError:
-            flash = None  # degrade to the XLA/manual paths below
-        if flash is not None:
-            kernel_kwargs = dict(
-                causal=True,
-                dropout_rate=dropout_rate if active_dropout else 0.0,
-                dropout_rng=dropout_rng,
-                rope=rope,
-                interpret=interpret,
-                segment_ids=segment_ids,
-            )
-            mesh = _flash_mesh(q)
-            if mesh is not None:
-                return _sharded_kernel(q, k, v, mesh, kernel_kwargs)
-            return flash.flash_attention(q, k, v, **kernel_kwargs)
+        from tpu_trainer.ops import flash
+
+        kernel_kwargs = dict(
+            causal=True,
+            dropout_rate=dropout_rate if active_dropout else 0.0,
+            dropout_rng=dropout_rng,
+            rope=rope,
+            interpret=interpret,
+            segment_ids=segment_ids,
+        )
+        mesh = _flash_mesh(q)
+        if mesh is not None:
+            return _sharded_kernel(q, k, v, mesh, kernel_kwargs)
+        return flash.flash_attention(q, k, v, **kernel_kwargs)
     if rope is not None:
         from tpu_trainer.ops.rope import apply_rotary_pos_emb
 

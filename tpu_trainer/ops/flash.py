@@ -1550,16 +1550,29 @@ def flash_attention(
 # KV history lives in fixed-size blocks scattered through a preallocated
 # pool, addressed by a per-request block table. The kernel is the
 # split-KV sibling of the split dkv/dq backward above — grid
-# ``(batch, heads, n_splits, blocks_per_split)`` where each (batch, head,
-# split) program walks its share of the request's cache blocks with an
-# online softmax in VMEM scratch and flushes a partial (m, l, acc)
-# triple; the per-split partials merge in plain jnp (the standard
-# flash-decoding recombination: ``o = sum_s exp(m_s - m*) acc_s /
+# ``(batch, n_splits, blocks_per_split)`` where each (batch, split)
+# program walks its share of the request's cache blocks with an online
+# softmax over ALL heads in VMEM scratch and flushes a partial
+# (m, l, acc) triple; the per-split partials merge in plain jnp (the
+# standard flash-decoding recombination: ``o = sum_s exp(m_s - m*) acc_s /
 # sum_s exp(m_s - m*) l_s``). The block gather rides the BlockSpec index
 # maps via scalar prefetch: the block table and lengths are
 # ``num_scalar_prefetch`` operands, so ``tables[b, split*bps + j]``
 # *indexes the k/v pool block to DMA* — the gather costs nothing beyond
 # the reads the attention needed anyway.
+#
+# Tiling. Mosaic wants the last two dims of every block to be (8, 128)-
+# tileable or to span the whole array, and it does not slice lanes at a
+# 64-offset, so a program never slices a head out of anything: it DMAs
+# the page's whole ``[block_size, kv_heads * d]`` row slab and multiplies
+# it with a BLOCK-DIAGONAL query ``[heads, kv_heads * d]`` (head ``ih``'s
+# vector sits in kv head ``ih // group``'s lanes, zeros elsewhere — built
+# outside the kernel). ``q_bd @ k^T`` is then exactly the per-head score
+# tile ``[heads, block_size]`` and ``p @ v`` carries each head's output on
+# its own lanes; the flush masks the foreign lanes and folds the slab into
+# 128-lane chunks. The MXU does ``kv_heads`` times the useful work, which
+# is free next to the page reads decode is bound by. ``m``/``l`` leave
+# lane-broadcast as ``[.., heads, 128]``.
 #
 # An int8 cache mode dequantizes gathered blocks in VMEM: the pools carry
 # ``int8 [nblk, bs, kvh, d]`` plus blockwise absmax scales
@@ -1570,65 +1583,89 @@ def flash_attention(
 # a full-table gather, used as the CPU serving path and the parity oracle
 # tier-1 pins the kernel against (interpret=True).
 
+_LANES = 128
+
 
 def _decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *rest,
-                   block_size, bps, int8, nbq):
-    """One (batch row, head, split) program; grid dim 3 walks the split's
-    cache blocks sequentially with (m, l, acc) online-softmax state in
-    VMEM scratch."""
+                   block_size, bps, int8, d, group, fold):
+    """One (batch row, split) program over all heads; grid dim 2 walks the
+    split's cache blocks sequentially with (m, l, acc) online-softmax
+    state in VMEM scratch."""
     if int8:
         ks_ref, vs_ref, m_ref, l_ref, acc_ref, m_scr, l_scr, acc_scr = rest
     else:
         m_ref, l_ref, acc_ref, m_scr, l_scr, acc_scr = rest
     ib = pl.program_id(0)
-    isp = pl.program_id(2)
-    jb = pl.program_id(3)
-    d = q_ref.shape[2]
+    isp = pl.program_id(1)
+    jb = pl.program_id(2)
+    h, width = acc_scr.shape                            # width = kvh * d
 
     @pl.when(jb == 0)
     def _zero():
-        m_scr[...] = jnp.full((1, 1), _NEG_INF, jnp.float32)
-        l_scr[...] = jnp.zeros((1, 1), jnp.float32)
-        acc_scr[...] = jnp.zeros((1, d), jnp.float32)
+        m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
     length = lengths_ref[ib]
     start = (isp * bps + jb) * block_size
+
+    def _load(p_ref, s_ref):
+        x = p_ref[0].astype(jnp.float32)                # [block_size, width]
+        if int8:
+            # Spread each scale over its quant block's lanes with a 0/1
+            # matmul (one non-zero term per output: exact at HIGHEST).
+            sc = s_ref[0]                               # [block_size, kvh*nbq]
+            blkq = width // sc.shape[1]
+            spread = (
+                jax.lax.broadcasted_iota(
+                    jnp.int32, (sc.shape[1], width), 1) // blkq
+                == jax.lax.broadcasted_iota(
+                    jnp.int32, (sc.shape[1], width), 0)
+            ).astype(jnp.float32)
+            x = x * jax.lax.dot_general(
+                sc, spread, (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+        return x
 
     # Static body, predicated off for blocks wholly past this row's length
     # (same structure as the causal block skip in the training kernels).
     @pl.when(start < length)
     def _body():
-        q = q_ref[0]                                    # [1, d] (pre-scaled)
-        if int8:
-            blkq = d // nbq
-            k = (k_ref[0].astype(jnp.float32)
-                 .reshape(block_size, nbq, blkq)
-                 * ks_ref[0][:, :, None]).reshape(block_size, d)
-            v = (v_ref[0].astype(jnp.float32)
-                 .reshape(block_size, nbq, blkq)
-                 * vs_ref[0][:, :, None]).reshape(block_size, d)
-        else:
-            k = k_ref[0]                                # [block_size, d]
-            v = v_ref[0]
+        q = q_ref[0]                          # [h, width] block-diag, scaled
+        k = _load(k_ref, ks_ref if int8 else None)
+        v = _load(v_ref, vs_ref if int8 else None)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
                                 preferred_element_type=jnp.float32)
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
+        pos = start + jax.lax.broadcasted_iota(jnp.int32, (h, block_size), 1)
         s = jnp.where(pos < length, s, _NEG_INF)
-        m_old = m_scr[...]
+        m_old = jnp.max(m_scr[...], axis=-1, keepdims=True)        # [h, 1]
+        l_old = jnp.max(l_scr[...], axis=-1, keepdims=True)
         m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_old - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        l_new = l_old * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            p, v, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(jb == pl.num_programs(3) - 1)
+    @pl.when(jb == pl.num_programs(2) - 1)
     def _flush():
-        m_ref[0, 0, 0, 0] = m_scr[0, 0]
-        l_ref[0, 0, 0, 0] = l_scr[0, 0]
-        acc_ref[0, 0, 0, :] = acc_scr[0, :]
+        m_ref[0, 0] = m_scr[...]
+        l_ref[0, 0] = l_scr[...]
+        # Keep each head's own kv-head lanes, then fold the slab into
+        # ``fold``-lane chunks (exact: every other term is a masked 0).
+        own = (jax.lax.broadcasted_iota(jnp.int32, (h, width), 1) // d
+               == jax.lax.broadcasted_iota(jnp.int32, (h, width), 0) // group)
+        acc = jnp.where(own, acc_scr[...], 0.0)
+        out = acc[:, :fold]
+        for c in range(1, width // fold):
+            out = out + acc[:, c * fold:(c + 1) * fold]
+        acc_ref[0, 0] = out
 
 
 def _auto_splits(max_blocks: int) -> int:
@@ -1665,9 +1702,11 @@ def flash_decode(
       current one (so >= 1 for live rows; a length-0 row yields NaN).
 
     Returns f32 ``[batch, heads, head_dim]``. GQA: query head ``ih`` reads
-    kv head ``ih // (heads // kv_heads)``. Compiled-mode tiling needs
-    ``head_dim`` lane-compatible (64/128-multiples); interpret mode (the
-    CPU serving and tier-1 path) has no constraint.
+    kv head ``ih // (heads // kv_heads)``. Compiled mode has no head_dim
+    constraint of its own (d = 32/64/96/128, odd head counts and a single
+    kv head all compile for a v5e — the programs only ever touch whole
+    ``[block_size, kv_heads * d]`` slabs); interpret mode is the CPU
+    serving and tier-1 path.
     """
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1679,7 +1718,6 @@ def flash_decode(
     int8 = pool_k.dtype == jnp.int8
     if int8 and (k_scale is None or v_scale is None):
         raise ValueError("int8 pools need k_scale/v_scale")
-    nbq = k_scale.shape[-1] if int8 else 1
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if not n_splits:
@@ -1687,66 +1725,73 @@ def flash_decode(
     if mb % n_splits != 0:
         raise ValueError(f"max_blocks {mb} % n_splits {n_splits} != 0")
     bps = mb // n_splits
+    width = kvh * d
+    # The flush folds the slab to one lane tile when it divides into them;
+    # a narrower or ragged slab leaves whole and folds below.
+    fold = _LANES if width % _LANES == 0 and _LANES % d == 0 else width
 
-    qf = (q.astype(jnp.float32) * (1.0 / math.sqrt(d)))
-    # Folded pool layouts so the BlockSpecs slice per kv head on the last
-    # dim (same no-transpose trick as the training kernels' [b, s, h*d]).
-    k3 = pool_k.reshape(nblk, bsz, kvh * d)
-    v3 = pool_v.reshape(nblk, bsz, kvh * d)
+    # Block-diagonal query: head ih's (pre-scaled) vector on kv head
+    # ih // group's lanes of the [kvh * d] slab, zeros elsewhere.
+    qf = q.astype(jnp.float32) * (1.0 / math.sqrt(d))
+    own = jnp.arange(h)[:, None] // group == jnp.arange(kvh)[None, :]
+    q_bd = jnp.where(own[None, :, :, None], qf[:, :, None, :], 0.0)
+    q_bd = q_bd.reshape(b, h, width)
+    # Folded pool layouts: a page's rows are contiguous [bsz, kvh * d]
+    # slabs (same no-transpose trick as the training kernels' [b, s, h*d]).
+    k3 = pool_k.reshape(nblk, bsz, width)
+    v3 = pool_v.reshape(nblk, bsz, width)
 
-    def _blk(width, col_scale):
+    def _page(last):
         return pl.BlockSpec(
-            (1, bsz, width),
-            lambda ib, ih, isp, jb, tr, lr, _w=width, _c=col_scale:
-            (tr[ib, isp * bps + jb], 0, ih // group),
-        )
+            (1, bsz, last),
+            lambda ib, isp, jb, tr, lr: (tr[ib, isp * bps + jb], 0, 0))
 
     in_specs = [
-        pl.BlockSpec((1, 1, d), lambda ib, ih, isp, jb, tr, lr: (ib, ih, 0)),
-        _blk(d, 1),
-        _blk(d, 1),
+        pl.BlockSpec((1, h, width), lambda ib, isp, jb, tr, lr: (ib, 0, 0)),
+        _page(width),
+        _page(width),
     ]
-    ops = [qf, k3, v3]
+    ops = [q_bd, k3, v3]
     if int8:
-        in_specs += [_blk(nbq, 1), _blk(nbq, 1)]
-        ops += [k_scale.reshape(nblk, bsz, kvh * nbq),
-                v_scale.reshape(nblk, bsz, kvh * nbq)]
+        nsc = kvh * k_scale.shape[-1]
+        in_specs += [_page(nsc), _page(nsc)]
+        ops += [k_scale.reshape(nblk, bsz, nsc),
+                v_scale.reshape(nblk, bsz, nsc)]
+
+    def _out(last):
+        return pl.BlockSpec(
+            (1, 1, h, last), lambda ib, isp, jb, tr, lr: (ib, isp, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h, n_splits, bps),
+        grid=(b, n_splits, bps),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, 1),
-                         lambda ib, ih, isp, jb, tr, lr: (ib, ih, 0, isp)),
-            pl.BlockSpec((1, 1, 1, 1),
-                         lambda ib, ih, isp, jb, tr, lr: (ib, ih, 0, isp)),
-            pl.BlockSpec((1, 1, 1, d),
-                         lambda ib, ih, isp, jb, tr, lr: (ib, ih, isp, 0)),
-        ],
+        out_specs=[_out(_LANES), _out(_LANES), _out(fold)],
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
+            pltpu.VMEM((h, _LANES), jnp.float32),
+            pltpu.VMEM((h, _LANES), jnp.float32),
+            pltpu.VMEM((h, width), jnp.float32),
         ],
     )
     m, l, acc = pl.pallas_call(
         functools.partial(_decode_kernel, block_size=bsz, bps=bps,
-                          int8=int8, nbq=nbq),
+                          int8=int8, d=d, group=group, fold=fold),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, 1, n_splits), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, 1, n_splits), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, n_splits, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, n_splits, h, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b, n_splits, h, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b, n_splits, h, fold), jnp.float32),
         ],
         interpret=interpret,
     )(tables, lengths, *ops)
+    m, l = m[..., 0], l[..., 0]                              # [b, S, h]
+    # Finish the lane fold: [.., fold] holds fold // d head slots, one live.
+    acc = acc.reshape(b, n_splits, h, fold // d, d).sum(axis=3)
     # Split merge: renormalize each split's accumulator by the global max
     # and combine (empty splits carry m = -inf -> weight exp(-inf) = 0).
-    m_star = jnp.max(m, axis=-1, keepdims=True)              # [b, h, 1, 1]
-    w = jnp.exp(m - m_star)[:, :, 0, :]                      # [b, h, S]
-    l_tot = jnp.sum(l[:, :, 0, :] * w, axis=-1)              # [b, h]
-    return jnp.einsum("bhs,bhsd->bhd", w, acc) / l_tot[:, :, None]
+    w = jnp.exp(m - jnp.max(m, axis=1, keepdims=True))       # [b, S, h]
+    l_tot = jnp.sum(l * w, axis=1)                           # [b, h]
+    return jnp.einsum("bsh,bshd->bhd", w, acc) / l_tot[:, :, None]
 
 
 def paged_attention_reference(

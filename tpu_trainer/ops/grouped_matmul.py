@@ -100,19 +100,13 @@ def _group_ids(group_sizes: jax.Array, num_rows: int) -> jax.Array:
 
 def gmm_reference(lhs: jax.Array, rhs: jax.Array,
                   group_sizes: jax.Array) -> jax.Array:
-    """jnp twin of ``gmm`` — ``jax.lax.ragged_dot`` where available.
+    """jnp twin of ``gmm`` — ``jax.lax.ragged_dot``.
 
     Accumulates in f32 and returns ``lhs.dtype`` (the kernel contract).
     """
-    if hasattr(jax.lax, "ragged_dot"):
-        out = jax.lax.ragged_dot(
-            lhs, rhs, group_sizes.astype(jnp.int32),
-            preferred_element_type=jnp.float32)
-    else:  # pragma: no cover - jax without ragged_dot
-        gid = _group_ids(group_sizes, lhs.shape[0])
-        w = jnp.take(rhs, jnp.minimum(gid, rhs.shape[0] - 1), axis=0)
-        out = jnp.einsum("gh,ghn->gn", lhs.astype(jnp.float32),
-                         w.astype(jnp.float32))
+    out = jax.lax.ragged_dot(
+        lhs, rhs, group_sizes.astype(jnp.int32),
+        preferred_element_type=jnp.float32)
     return out.astype(lhs.dtype)
 
 

@@ -236,6 +236,7 @@ def _fwd_parts(emb, x, labels, mask, mesh, interpret):
     if b_axes is None and s_axes is None:
         logits_t, lse, ll = local(x, e_c, labels)
     else:
+        from tpu_trainer.parallel.context import kernel_manual_axes
         from tpu_trainer.utils.jax_compat import shard_map
         from jax.sharding import PartitionSpec as P
 
@@ -250,7 +251,7 @@ def _fwd_parts(emb, x, labels, mask, mesh, interpret):
             in_specs=(P(b_axes, s_axes), P(), P(b_axes, s_axes)),
             out_specs=(P(None, b_axes, s_axes), P(b_axes, s_axes),
                        P(b_axes, s_axes)),
-            axis_names=set(t_axes),
+            axis_names=kernel_manual_axes(mesh, t_axes),
             check_vma=False,
         )(x, e_c, labels)
     denom = jnp.maximum(jnp.sum(mask), 1.0)

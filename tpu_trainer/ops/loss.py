@@ -559,20 +559,13 @@ def fused_shifted_cross_entropy(
         from tpu_trainer.ops.head_ce import pallas_head_ce
 
         return pallas_head_ce(emb, x, shifted, mask, mesh, False)
-    from tpu_trainer.utils.jax_compat import PARTIAL_MANUAL_OK
-
     if (mesh is not None and mesh.shape.get("tensor", 1) > 1
             and mesh.shape.get("stage", 1) == 1
             # The h-slice -> vocab-slice all_to_all needs H divisible by
             # the axis; indivisible H keeps the embedding replicated under
             # the TP rules (sharding.py _tensor_dim) and the blockwise
             # path below handles it as before.
-            and emb.shape[1] % mesh.shape["tensor"] == 0
-            # Old-jax ``auto=`` shard_map aborts the SPMD partitioner on
-            # this composition; the blockwise path below is the same math
-            # under pure GSPMD (partial logits + all-reduce), just without
-            # the vocab-slice memory optimization.
-            and PARTIAL_MANUAL_OK):
+            and emb.shape[1] % mesh.shape["tensor"] == 0):
         return _tp_loss(emb, x, shifted, mask, mesh, chunk_size)
     chunk = _chunk_len(b, s, chunk_size)
     return _chunked_ce(emb, x, shifted, mask, chunk)

@@ -32,7 +32,8 @@ import numpy as np
 
 from tpu_trainer.parallel import mesh as mesh_lib
 from tpu_trainer.parallel import sharding as shard_lib
-from tpu_trainer.utils.logging import device_peak_flops, flops_per_token
+from tpu_trainer.utils.logging import (
+    cost_model_kind, flops_per_token, lookup_by_kind, peak_flops_for_kind)
 
 # Gradients accumulate and reduce in float32 regardless of compute dtype.
 GRAD_BYTES = 4
@@ -40,7 +41,8 @@ GRAD_BYTES = 4
 # Assumed per-device interconnect bandwidth (bytes/s) by device_kind
 # substring, for the roofline estimate only. Aggregate ICI figures good to
 # a factor of ~2 — enough to classify a config as comms- or compute-bound,
-# not to predict step time. Matched longest-substring-first.
+# not to predict step time. Matched like the peak-FLOPs table
+# (utils.logging.lookup_by_kind); an unknown kind raises.
 _ICI_BYTES_PER_SEC = {
     "v6": 1.8e11,
     "v5p": 1.2e11,
@@ -50,7 +52,6 @@ _ICI_BYTES_PER_SEC = {
     "v3": 7.0e10,
     "v2": 5.0e10,
 }
-_DEFAULT_ICI = 4.5e10
 
 _HLO_COLLECTIVES = (
     "all-reduce", "all-gather", "reduce-scatter", "collective-permute",
@@ -123,11 +124,7 @@ def _shard_factor(spec, axis_sizes, exclude=()) -> int:
 
 
 def _ici_bytes_per_sec(device_kind: str) -> float:
-    kind = (device_kind or "").lower()
-    for key in sorted(_ICI_BYTES_PER_SEC, key=len, reverse=True):
-        if key in kind:
-            return _ICI_BYTES_PER_SEC[key]
-    return _DEFAULT_ICI
+    return lookup_by_kind(_ICI_BYTES_PER_SEC, device_kind, "ICI bandwidth")
 
 
 def build_core(
@@ -152,10 +149,10 @@ def build_core(
       axes default to 1) — ``mesh.shape`` or a planner candidate;
     - ``strategy``: sharding strategy (aliases accepted);
     - ``batch_size``: per-data-shard rows per micro-batch;
-    - ``device_kind`` / ``peak_flops``: roofline hardware constants.
-      ``peak_flops=None`` keeps the live-trainer behavior (local device
-      lookup); the offline planner passes an explicit figure so plans for a
-      different device kind don't inherit this process's hardware.
+    - ``device_kind`` / ``peak_flops``: roofline hardware constants, looked
+      up by kind (an unknown kind raises; off-TPU the roofline is drawn for
+      ``utils.logging.OFF_CHIP_MODEL_KIND`` and says so in
+      ``assumptions.roofline_kind``). ``peak_flops`` overrides the table.
 
     This is what the mesh auto-planner (``parallel/planner.py``) scores
     candidate meshes with; :func:`build` is the thin trainer wrapper and its
@@ -286,8 +283,11 @@ def build_core(
     total = sum(v["bytes"] for v in per_axis.values())
 
     # Roofline: serial (no-overlap) comms time vs analytic compute time.
-    peak = peak_flops if peak_flops is not None else device_peak_flops()
-    ici = _ici_bytes_per_sec(device_kind)
+    # Drawn for the mesh's chip; off-TPU for the named model target.
+    roofline_kind = cost_model_kind(device_kind)
+    peak = (peak_flops if peak_flops is not None
+            else peak_flops_for_kind(roofline_kind))
+    ici = _ici_bytes_per_sec(roofline_kind)
     tokens_per_step = rows * accum * d * f * max_seq_len
     flops_step = flops_per_token(mc, seq_len=max_seq_len) * tokens_per_step
     per_device_flops = flops_step / n_devices
@@ -315,6 +315,7 @@ def build_core(
             "peak_flops_per_device": peak,
             "ici_bytes_per_sec": ici,
             "device_kind": device_kind or "unknown",
+            "roofline_kind": roofline_kind,
         },
     }
 

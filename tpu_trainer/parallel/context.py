@@ -52,3 +52,19 @@ def mesh_scope(mesh: Optional[Mesh]):
 
 def current_mesh() -> Optional[Mesh]:
     return _ACTIVE.mesh if _ACTIVE is not None else None
+
+
+def kernel_manual_axes(mesh, used) -> set:
+    """Mesh axes a Pallas kernel's ``shard_map`` goes manual over.
+
+    Mosaic refuses a kernel under a PARTIAL-manual region ("cannot be
+    automatically partitioned"): every mesh axis has to be manual where the
+    ``pallas_call`` lowers. So the size-1 axes — nothing can be sharded
+    over them, making them manual changes no program — join the axes the
+    wrapper actually shards. Axes an enclosing ``shard_map`` already made
+    manual (the pipeline stage body) stay out; they count as manual there.
+    """
+    from jax.sharding import get_abstract_mesh
+
+    idle = {a for a in mesh.axis_names if mesh.shape[a] == 1}
+    return (set(used) | idle) - set(get_abstract_mesh().manual_axes)

@@ -112,10 +112,7 @@ def initialize_distributed(
             # supervisor's N-process CPU rendezvous (and the elastic chaos
             # tests) into a real collective fabric. Must happen before the
             # first backend instantiation; harmless on TPU (ignored).
-            try:
-                jax.config.update("jax_cpu_collectives_implementation", "gloo")
-            except Exception:
-                pass  # older/newer jax without the knob: leave the default
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
         kwargs = {}
         timeout_s = _int_env("COORDINATOR_TIMEOUT_S")
         if timeout_s is not None:

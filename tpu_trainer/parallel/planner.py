@@ -44,7 +44,7 @@ import numpy as np
 from tpu_trainer.parallel import comms_model as comms_lib
 from tpu_trainer.parallel import mesh as mesh_lib
 from tpu_trainer.parallel import sharding as shard_lib
-from tpu_trainer.utils.logging import SCHEMA_VERSION, peak_flops_for_kind
+from tpu_trainer.utils.logging import SCHEMA_VERSION
 
 GiB = float(2**30)
 
@@ -355,10 +355,10 @@ def plan(
     """Enumerate, prune, score, rank; return the ``mesh_plan`` record.
 
     ``device_kind`` drives both the ICI-bandwidth table and (when
-    ``peak_flops`` is not given and the kind is non-empty) the peak-FLOPs
-    table — so ``--device-kind v5e`` plans consistently for hardware this
-    process doesn't own. With neither given, the roofline falls back to
-    the local device exactly like the live comms model.
+    ``peak_flops`` is not given) the peak-FLOPs table — so
+    ``--device-kind v5e`` plans consistently for hardware this process
+    doesn't own. An unknown kind raises; an empty or CPU kind plans for
+    ``utils.logging.OFF_CHIP_MODEL_KIND`` like the live comms model.
 
     ``exclude_axes`` drops candidates that split the named axes — for
     platform capability gaps rather than model arithmetic (e.g. the CPU
@@ -369,8 +369,6 @@ def plan(
     (message includes the per-candidate reasons, capped).
     """
     strategy = shard_lib.canonical_strategy(strategy)
-    if peak_flops is None and device_kind:
-        peak_flops = peak_flops_for_kind(device_kind)
     param_shapes = comms_lib.abstract_params(model_config)
     budget = hbm_budget_bytes(hbm_gb)
 
@@ -481,8 +479,6 @@ def plan_single(
     :func:`plan` with a one-entry ranking (trivially its own argmin).
     """
     strategy = shard_lib.canonical_strategy(strategy)
-    if peak_flops is None and device_kind:
-        peak_flops = peak_flops_for_kind(device_kind)
     sizes = {ax: axis_sizes.get(ax, 1) for ax in mesh_lib.MESH_AXES}
     n_devices = int(np.prod(list(sizes.values())))
     param_shapes = comms_lib.abstract_params(model_config)

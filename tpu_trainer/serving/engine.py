@@ -484,6 +484,27 @@ class ServingEngine:
         self.stats["cancelled"] += 1
         return True
 
+    def compiled_decode_text(self) -> str:
+        """Post-optimization HLO of the jitted single-token decode step at
+        this engine's shapes — the serving twin of
+        ``Trainer.compiled_step_text``: it shows which paged-attention
+        implementation the step contains (``tpu_custom_call`` = the
+        ``flash_decode`` kernel). Same jit object and shapes as the running
+        step, so after a decode this hits the executable cache."""
+        n = self.max_batch
+
+        def arg(dtype, *shape):
+            return jax.ShapeDtypeStruct(shape, dtype)
+
+        return self._step_jit.lower(
+            self.params, self.device_cache,
+            arg(jnp.int32, *self.cache_state.tables.shape),
+            arg(jnp.int32, n), arg(jnp.int32, n), arg(jnp.int32, n, 1),
+            arg(jnp.float32, n), arg(jnp.int32, n), arg(jnp.float32, n),
+            arg(jnp.uint32, n, 2), arg(jnp.int32, n),
+            k_cap=self._k_cap, prefill=False, hist_blocks=0,
+        ).compile().as_text()
+
     def _forward(self, reqs: List[Request], *, prefill: bool) -> List[Request]:
         slots = self.max_batch
         cs = self.cache_state
@@ -733,14 +754,15 @@ class ServingEngine:
 
     def _build_pricer(self, link_gbps: float) -> MigrationPricer:
         from tpu_trainer.utils.logging import (
+            OFF_CHIP_MODEL_KIND,
             device_peak_flops,
             flops_per_token,
+            peak_flops_for_kind,
         )
 
-        try:
-            peak = float(device_peak_flops())
-        except Exception:
-            peak = 1e12
+        # The attached chip's peak; off-TPU the price model is drawn for
+        # the named target (an unknown TPU kind raises).
+        peak = device_peak_flops() or peak_flops_for_kind(OFF_CHIP_MODEL_KIND)
         # flops_per_token counts fwd+bwd (6N + attn); the recompute a
         # migration avoids is one forward pass — a third of that.
         fwd = flops_per_token(self.config) / 3.0
@@ -1240,6 +1262,9 @@ def _main() -> int:
     p.add_argument("--max-seq-len", type=int, default=256)
     args = p.parse_args()
 
+    from tpu_trainer.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     config = GPTConfig(
         vocab_size=args.vocab, hidden_size=args.hidden,
         num_layers=args.layers, num_heads=args.heads,

@@ -432,6 +432,9 @@ def main(argv=None) -> int:
     if not args.socket and not args.tcp:
         p.error("one of --socket or --tcp is required")
 
+    from tpu_trainer.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     with open(args.spec) as f:
         spec = json.load(f)
 

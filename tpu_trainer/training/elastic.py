@@ -785,7 +785,13 @@ def build_parser() -> argparse.ArgumentParser:
                     "--allow_grow, re-expand to the desired world when "
                     "capacity returns. Trainer flags go after '--'.",
     )
-    p.add_argument("--num_processes", type=int, required=True)
+    p.add_argument("--num_processes", type=int, required=True,
+                   help="trainer processes to launch, one per HOST. The "
+                        "supervisor itself never initialises a jax "
+                        "backend, but a TPU host's chips belong to one "
+                        "process: N > 1 on a single TPU host cannot start "
+                        "(the later processes fail or hang at backend "
+                        "init) — use N hosts, or JAX_PLATFORMS=cpu")
     p.add_argument("--run_dir", type=str, required=True,
                    help="supervisor state: heartbeats, per-host logs, "
                         "capacity.json, supervisor.jsonl (the trainer's "

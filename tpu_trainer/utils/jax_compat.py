@@ -1,31 +1,16 @@
-"""Version shims for jax APIs that moved between releases.
+"""The one place the repo names jax/orbax APIs that have moved before.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` to the top-level
-``jax`` namespace, renaming ``check_rep`` to ``check_vma`` and replacing the
-``auto`` axis set with its complement ``axis_names`` along the way. The
-kernels (ops/attention.py, ops/ring.py, ops/head_ce.py, ops/loss.py) and the
-pipeline schedule are written against the new spelling; this module makes
-that spelling run on both API generations so the repo tracks one idiom.
+``shard_map`` is ``jax.shard_map`` (``check_vma``; ``axis_names`` = the set
+of MANUAL mesh axes, the rest left to GSPMD). The kernels
+(ops/attention.py, ops/ring.py, ops/head_ce.py, ops/loss.py) and the
+pipeline schedule import it from here.
 """
 
 from __future__ import annotations
 
-try:  # new API: top-level, check_vma, axis_names
-    from jax import shard_map as _shard_map_new
+import os as _os
 
-    _NEW = True
-except ImportError:  # old API: experimental, check_rep, auto
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    _NEW = False
-
-# Partial-manual regions (manual over a subset of mesh axes, the rest left
-# to GSPMD) are unreliable on the old API: the ``auto=`` path can trip a
-# fatal SPMD-partitioner check ("target.IsManualSubgroup() ==
-# sharding().IsManualSubgroup()") when the manual axis composes with
-# GSPMD-sharded operands. Optimizations that have an equivalent pure-GSPMD
-# fallback should consult this flag and take the fallback on old jax.
-PARTIAL_MANUAL_OK = _NEW
+from jax import shard_map  # noqa: F401  (re-exported)
 
 # Async checkpoint writes (utils/checkpoint.py AsyncSaver) prefer orbax's
 # AsyncCheckpointer for the background shard write when the installed orbax
@@ -34,8 +19,6 @@ PARTIAL_MANUAL_OK = _NEW
 # snapshot — this gate selects the writer implementation, not the overlap.
 # TPU_TRAINER_NO_ORBAX_ASYNC=1 forces the fallback (used by tests to cover
 # both writers on one orbax version).
-import os as _os
-
 try:
     import orbax.checkpoint as _ocp
 
@@ -47,39 +30,3 @@ try:
     )
 except ImportError:  # orbax absent entirely (inference-only installs)
     ORBAX_ASYNC_OK = False
-
-# The blockwise fused head+CE (ops/loss.py fused_shifted_cross_entropy)
-# produces NaN under sequence-sharded activations when the mesh composes
-# sequence x tensor axes on the old API generation. Localized by --nan_scan
-# (ROADMAP open item): every activation site including the full-vocab
-# logits is finite, the loss is the first non-finite value, and the same
-# mesh with ``fused_loss: false`` is finite end to end. The Trainer
-# auto-disables fused_loss on those meshes when this is False.
-FUSED_LOSS_SEQ_TP_OK = _NEW
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-              check_vma=True):
-    """``jax.shard_map`` (new-API keyword spelling) on any jax.
-
-    ``axis_names`` is the set of *manual* mesh axes (new semantics); on old
-    jax it is translated to ``auto`` = the complement. Old ``shard_map``
-    does not support a replication check over a partial-manual region, so
-    ``auto`` forces ``check_rep=False`` there (the check is a validation
-    aid, not a semantics change).
-    """
-    if _NEW:
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return _shard_map_new(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=check_vma, **kw)
-    kw = {}
-    check_rep = check_vma
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kw["auto"] = auto
-            check_rep = False
-    return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_rep, **kw)

@@ -34,7 +34,9 @@ from tpu_trainer.utils import telemetry as telemetry_lib
 # record field semantics.
 SCHEMA_VERSION = 1
 
-# Peak dense bf16 FLOP/s per chip, by device_kind substring (public figures).
+# Peak dense bf16 FLOP/s per chip, by device_kind substring (public figures;
+# v5e: Google Cloud "TPU v5e" page). jax reports a v5e as "TPU v5 lite",
+# which normalizes to "tpuv5lite". Matched longest key first.
 _PEAK_FLOPS = {
     "v6": 918e12,        # Trillium (v6e)
     "v5p": 459e12,
@@ -44,33 +46,56 @@ _PEAK_FLOPS = {
     "v3": 123e12,
     "v2": 45e12,
 }
-_DEFAULT_PEAK = 275e12   # assume v4 when the kind string is unrecognized
+# The chip a cost MODEL (comms roofline, mesh planner, KV-migration pricer)
+# is drawn for when the process holds no TPU: an estimate for a named
+# target, recorded as such — never a utilization of the device it ran on.
+OFF_CHIP_MODEL_KIND = "v5e"
+
+
+def lookup_by_kind(table: dict, device_kind: str, what: str):
+    """``table`` entry whose key occurs in ``device_kind`` (case and spaces
+    ignored, longest key first). A kind that is not in the table is an
+    error, never a default."""
+    kind = (device_kind or "").lower().replace(" ", "")
+    for key in sorted(table, key=len, reverse=True):
+        if key in kind:
+            return table[key]
+    raise ValueError(
+        f"no {what} on record for device_kind {device_kind!r}; add it to "
+        f"the table (known: {', '.join(table)})")
 
 
 def peak_flops_for_kind(device_kind: str) -> float:
-    """Peak bf16 FLOP/s for a ``device_kind`` string (best-effort match).
+    """Peak bf16 FLOP/s for a ``device_kind`` string.
 
     Split out of :func:`device_peak_flops` so offline consumers — the mesh
     auto-planner planning for a device kind the process doesn't own
     (``tools/plan --device-kind``) — share the exact lookup the live
-    telemetry uses.
+    telemetry uses. A kind that is not in the table is an error: a
+    utilization against a guessed peak is worse than none.
     """
-    kind = (device_kind or "").lower().replace(" ", "")
-    for key, flops in _PEAK_FLOPS.items():
-        if key in kind:
-            return flops
-    return _DEFAULT_PEAK
+    return lookup_by_kind(_PEAK_FLOPS, device_kind, "peak FLOP/s")
 
 
-def device_peak_flops(device: Optional[jax.Device] = None) -> float:
-    """Peak bf16 FLOP/s of one chip (best-effort from device_kind).
+def cost_model_kind(device_kind: str) -> str:
+    """The kind a cost model is drawn for: ``device_kind`` itself, or
+    ``OFF_CHIP_MODEL_KIND`` when the process holds no accelerator."""
+    kind = (device_kind or "").strip()
+    return OFF_CHIP_MODEL_KIND if kind.lower() in ("", "cpu") else kind
+
+
+def device_peak_flops(device: Optional[jax.Device] = None) -> Optional[float]:
+    """Peak bf16 FLOP/s of one chip; ``None`` off-TPU (no peak, no MFU).
 
     Defaults to ``jax.local_devices()[0]`` — same accessor as
     ``memory_stats`` — so multi-host processes describe a chip they
     actually own (``jax.devices()[0]`` is host 0's first chip everywhere).
+    An unrecognized TPU kind raises (``peak_flops_for_kind``).
     """
     device = device or jax.local_devices()[0]
-    return peak_flops_for_kind(getattr(device, "device_kind", ""))
+    if device.platform != "tpu":
+        return None
+    return peak_flops_for_kind(device.device_kind)
 
 
 def flops_per_token(config: GPTConfig, seq_len: Optional[int] = None) -> float:
@@ -98,10 +123,13 @@ def mfu(
     n_chips: Optional[int] = None,
     peak_flops: Optional[float] = None,
     seq_len: Optional[int] = None,
-) -> float:
-    """Model FLOPs utilization: achieved model FLOP/s over peak hardware FLOP/s."""
+) -> Optional[float]:
+    """Model FLOPs utilization: achieved model FLOP/s over peak hardware
+    FLOP/s. ``None`` where there is no peak to divide by (off-TPU)."""
     n_chips = n_chips if n_chips is not None else jax.device_count()
     peak = peak_flops if peak_flops is not None else device_peak_flops()
+    if peak is None:
+        return None
     return tokens_per_sec * flops_per_token(config, seq_len) / (n_chips * peak)
 
 
@@ -209,7 +237,6 @@ class MetricLogger:
         self._window_tokens = 0
         self._n_chips = jax.device_count()
         self._peak = device_peak_flops()
-        self._on_accelerator = jax.devices()[0].platform != "cpu"
 
     def log(self, step: int, metrics: dict, extra: Optional[dict] = None) -> Optional[dict]:
         """Record one step; emit (and return) a record every ``log_interval``.
@@ -244,7 +271,7 @@ class MetricLogger:
             record["effective_tokens_per_sec"] = round(
                 tok_per_sec * float(self.non_pad_frac), 1
             )
-        if self.model_config is not None and self._on_accelerator:
+        if self.model_config is not None and self._peak is not None:
             record["mfu"] = round(
                 mfu(tok_per_sec, self.model_config, self._n_chips, self._peak,
                     self.seq_len), 4
