@@ -11,13 +11,16 @@ worker collects the same tests and only the worker that runs this file
 loads libtpu. Keep every such test in THIS file.
 """
 
+import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from perf import program_trace
 from tpu_trainer.ops.flash import flash_attention, flash_decode
 from tpu_trainer.ops.grouped_matmul import gmm, tgmm
 from tpu_trainer.ops.head_ce import pallas_head_ce
@@ -142,3 +145,66 @@ def test_flash_decode(chip, h, kvh, d, int8):
     else:
         _compile(functools.partial(flash_decode, interpret=False),
                  q, pool, pool, tables, lengths)
+
+
+# --- the whole train step: where the compiled kernels say they came from ---
+
+@pytest.fixture(scope="module", params=[1, 4], ids=["one-chip", "fsdp4"])
+def small_step(request, topo):
+    """``[(name, target, op_name)]`` of the custom calls of the `small`
+    preset's train step (one layer, 1024 tokens a row) compiled for one
+    described v5e, or for the 2x2 as an ``fsdp=4`` ZeRO-3 mesh. The kernel
+    dispatch asks ``jax.devices()`` whether a TPU is there; the test, not
+    the program, steers that."""
+    from tpu_trainer.models.config import GPTConfig
+    from tpu_trainer.parallel.mesh import MeshConfig, make_mesh
+    from tpu_trainer.training.config import TrainingConfig
+    from tpu_trainer.training.trainer import ParallelConfig, Trainer
+
+    chips = request.param
+    patch = pytest.MonkeyPatch()
+    patch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
+    try:
+        model = dataclasses.replace(
+            GPTConfig.preset("small"), num_layers=1, max_seq_len=1024,
+            use_flash_attention=True, fused_loss=True,
+            fused_loss_pallas=True, dropout=0.0, attention_dropout=0.0)
+        accum = 2 if chips == 1 else 1
+        train = TrainingConfig(
+            batch_size=2, gradient_accumulation_steps=accum,
+            max_seq_len=1024, mixed_precision="bf16")
+        mesh_cfg = MeshConfig(data=1, fsdp=chips)
+        trainer = Trainer(
+            model, train,
+            ParallelConfig(mesh_cfg, "zero3" if chips > 1 else "replicated"),
+            mesh=make_mesh(mesh_cfg, devices=list(topo.devices)[:chips]))
+        shapes = jax.eval_shape(trainer._make_state, jax.random.PRNGKey(0))
+        state = jax.tree_util.tree_map(
+            lambda s, sharding: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=sharding),
+            shapes, trainer.state_shardings)
+        batch = jax.ShapeDtypeStruct((accum, 2 * chips, 1024), jnp.int32,
+                                     sharding=trainer.batch_sharding)
+        text = trainer.compiled_step_text(state, batch)
+    finally:
+        patch.undo()
+    return chips, re.findall(
+        r'%?([\w.\-]+) = [^\n]*? custom-call\([^\n]*?'
+        r'custom_call_target="([^"]+)"[^\n]*?op_name="([^"]*)"', text)
+
+
+def test_flash_kernels_fall_in_the_flash_region_with_both_phases(small_step):
+    chips, calls = small_step
+    kernels = [c for c in calls if c[1] == "tpu_custom_call"]
+    flash = [c for c in kernels
+             if program_trace.region_of(*c) == "flash"]
+    assert {program_trace.phase_of(c[2]) for c in flash} == {"fwd", "bwd"}
+    # Every other kernel of the step is the head + CE, under its scope.
+    rest = [c for c in kernels if c not in flash]
+    assert rest and all(
+        program_trace.region_of(*c) == "head_loss" for c in rest)
+    if chips == 1:
+        # What perf/readers/flash_roofline.py matches (PR 24) still holds.
+        assert all(re.match(r"^attention(\.\d+)*$", c[0]) for c in flash)
+    else:
+        assert all(c[0].startswith("shard_map") for c in flash)

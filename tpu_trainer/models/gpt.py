@@ -940,62 +940,66 @@ class GPT(nn.Module):
             telemetry.record("final_norm", {
                 "rms": telemetry.rms(x), "absmax": telemetry.absmax(x),
             })
-        # Weight tying (reference gpt.py:342): logits via the embedding matrix.
-        logits = embed.attend(x).astype(jnp.float32)
-        if telemetry.capturing(deep=True):
-            # Between final_norm and the loss, nan-scan only: making the
-            # logits live here would defeat the fused/remat loss heads'
-            # memory savings on periodic telemetry steps, but without this
-            # site a NaN entering in the head matmul is indistinguishable
-            # from one entering in the loss math (the seq x tensor repro,
-            # ROADMAP open items).
-            telemetry.record("logits", {
-                "rms": telemetry.rms(logits), "absmax": telemetry.absmax(logits),
-            })
+        # One scope round the head matmul and the loss, whichever branch
+        # computes them: in a device trace they are `head_loss`, not the
+        # anonymous rest of `GPT` (utils/profiling.py).
+        with jax.named_scope("head_loss"):
+            # Weight tying (reference gpt.py:342): logits via the embedding matrix.
+            logits = embed.attend(x).astype(jnp.float32)
+            if telemetry.capturing(deep=True):
+                # Between final_norm and the loss, nan-scan only: making the
+                # logits live here would defeat the fused/remat loss heads'
+                # memory savings on periodic telemetry steps, but without this
+                # site a NaN entering in the head matmul is indistinguishable
+                # from one entering in the loss math (the seq x tensor repro,
+                # ROADMAP open items).
+                telemetry.record("logits", {
+                    "rms": telemetry.rms(logits), "absmax": telemetry.absmax(logits),
+                })
 
-        loss = None
-        if labels is not None:
-            # Shifted next-token cross entropy (reference gpt.py:450-453), mean
-            # over batch * (seq - 1) positions, computed in float32.
-            if cfg.fused_loss:
-                # Blockwise fused head+CE: full logits never materialize in
-                # either pass (ops/loss.py; the `logits` above are dead code
-                # in the training graph, which only consumes the loss).
-                loss = fused_shifted_cross_entropy(
-                    embed.embedding, x, labels,
-                    chunk_size=cfg.loss_chunk_size,
-                    allow_pallas=cfg.fused_loss_pallas,
-                    segment_ids=segment_ids,
-                )
-            elif cfg.remat_lm_head:
-                # Nothing of the [b, s, vocab] softmax survives forward; the
-                # backward recomputes one vocab matmul instead of re-reading
-                # a ~bytes(b*s*V*4) buffer. (The unused `logits` above is
-                # dead-code-eliminated in the training graph, which only
-                # consumes the loss.)
-                def head_loss(xf):
-                    lg = embed.attend(xf).astype(jnp.float32)
-                    return _masked_shifted_mean(
-                        optax_softmax_cross_entropy(
-                            lg[:, :-1, :], labels[:, 1:]
-                        ),
+            loss = None
+            if labels is not None:
+                # Shifted next-token cross entropy (reference gpt.py:450-453), mean
+                # over batch * (seq - 1) positions, computed in float32.
+                if cfg.fused_loss:
+                    # Blockwise fused head+CE: full logits never materialize in
+                    # either pass (ops/loss.py; the `logits` above are dead code
+                    # in the training graph, which only consumes the loss).
+                    loss = fused_shifted_cross_entropy(
+                        embed.embedding, x, labels,
+                        chunk_size=cfg.loss_chunk_size,
+                        allow_pallas=cfg.fused_loss_pallas,
+                        segment_ids=segment_ids,
+                    )
+                elif cfg.remat_lm_head:
+                    # Nothing of the [b, s, vocab] softmax survives forward; the
+                    # backward recomputes one vocab matmul instead of re-reading
+                    # a ~bytes(b*s*V*4) buffer. (The unused `logits` above is
+                    # dead-code-eliminated in the training graph, which only
+                    # consumes the loss.)
+                    def head_loss(xf):
+                        lg = embed.attend(xf).astype(jnp.float32)
+                        return _masked_shifted_mean(
+                            optax_softmax_cross_entropy(
+                                lg[:, :-1, :], labels[:, 1:]
+                            ),
+                            segment_ids,
+                        )
+
+                    loss = jax.checkpoint(
+                        head_loss,
+                        policy=jax.checkpoint_policies.nothing_saveable,
+                    )(x)
+                else:
+                    loss = _masked_shifted_mean(
+                        optax_softmax_cross_entropy(logits[:, :-1, :], labels[:, 1:]),
                         segment_ids,
                     )
-
-                loss = jax.checkpoint(
-                    head_loss,
-                    policy=jax.checkpoint_policies.nothing_saveable,
-                )(x)
-            else:
-                loss = _masked_shifted_mean(
-                    optax_softmax_cross_entropy(logits[:, :-1, :], labels[:, 1:]),
-                    segment_ids,
-                )
-            if cfg.num_experts > 0:
-                # MoE auxiliaries (mean over layers). The layer returns them
-                # pre-weighted: moe_aux_weight * load-balance +
-                # router_z_weight * z-loss (models/moe.py).
-                loss = loss + moe_aux / cfg.num_layers
+                if cfg.num_experts > 0:
+                    # MoE auxiliaries (mean over layers). The layer returns them
+                    # pre-weighted: moe_aux_weight * load-balance +
+                    # router_z_weight * z-loss (models/moe.py).
+                    loss = loss + moe_aux / cfg.num_layers
         return logits, loss
 
 

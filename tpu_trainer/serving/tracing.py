@@ -45,6 +45,7 @@ import contextlib
 import time
 from typing import Callable, Dict, List, Optional
 
+from tpu_trainer.utils import profiling
 from tpu_trainer.utils.logging import SCHEMA_VERSION
 
 # Terminal span events: one per accepted rid, mirroring the scheduler's
@@ -236,9 +237,12 @@ class ServingLedger:
 
     @contextlib.contextmanager
     def track(self, category: str):
+        # Also a `serve:<category>` span in any open profiler trace
+        # (utils/profiling.py); the arithmetic below does not change.
         t = self._clock()
         try:
-            yield
+            with profiling.span("serve:" + category):
+                yield
         finally:
             self.add(category, self._clock() - t)
 

@@ -49,7 +49,7 @@ from tpu_trainer.parallel import mesh as mesh_lib
 from tpu_trainer.parallel import sharding as shard_lib
 from tpu_trainer.training.config import TrainingConfig
 from tpu_trainer.training.optimizer import make_optimizer
-from tpu_trainer.utils import telemetry
+from tpu_trainer.utils import profiling, telemetry
 
 _MP_TO_DTYPE = {"fp32": "float32", "bf16": "bfloat16", "fp16": "float16"}
 
@@ -513,6 +513,12 @@ class Trainer:
             out_shardings=None,
         )
         self._eval_batch_sharding = eval_batch_sharding
+        # Host-side count of train_step calls: the `step` the spans of one
+        # step share (state.step lives on the device; reading it would sync).
+        self._calls = 0
+        # Abstract (shape, dtype, sharding) arguments of each step variant's
+        # first call: compiled_step_text needs no live buffers after it.
+        self._step_signature: dict = {}
 
     # --- rank discovery (↔ reference rank/world_size, ddp_trainer.py:101-103)
     @property
@@ -691,7 +697,8 @@ class Trainer:
         """Initialize (sharded directly on the mesh — params never exist
         unsharded, unlike the reference's build-on-CPU-then-wrap)."""
         seed = self.training_config.seed if seed is None else seed
-        return self._init_jit(jax.random.PRNGKey(seed))
+        with profiling.span("trainer:init_state", step=self._calls):
+            return self._init_jit(jax.random.PRNGKey(seed))
 
     # --- data placement -----------------------------------------------------
 
@@ -744,12 +751,13 @@ class Trainer:
         Public: the device-prefetch feed (``data/device_prefetch.py``) uses
         this to enqueue H2D copies ahead of the step."""
         if not isinstance(batch, jax.Array):
-            batch = np.asarray(batch)
-            packed = batch.shape[-1] == 2
-            flat_ndim = 3 if packed else 2
-            if batch.ndim == flat_ndim + 1:
-                batch = batch.reshape(-1, *batch.shape[2:])
-            batch = self.put_batch(batch)
+            with profiling.span("trainer:place_batch", step=self._calls):
+                batch = np.asarray(batch)
+                packed = batch.shape[-1] == 2
+                flat_ndim = 3 if packed else 2
+                if batch.ndim == flat_ndim + 1:
+                    batch = batch.reshape(-1, *batch.shape[2:])
+                batch = self.put_batch(batch)
         return batch
 
     def train_step(self, state: TrainState, batch,
@@ -766,9 +774,22 @@ class Trainer:
         activation RMS/absmax, and MoE router stats.
         """
         batch = self.place_batch(batch)
-        if telemetry:
-            return self._step_tel_jit(state, batch)
-        return self._step_jit(state, batch)
+        variant = "telemetry" if telemetry else "plain"
+        if variant not in self._step_signature:
+            self._step_signature[variant] = jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, sharding=x.sharding), (state, batch))
+            profiling.register_program(
+                "train_step" if variant == "plain" else "train_step_telemetry",
+                functools.partial(self.compiled_step_text,
+                                  telemetry=telemetry))
+        step_jit = self._step_tel_jit if telemetry else self._step_jit
+        # The dispatch: it returns before the device is done.
+        with profiling.span("trainer:train_step", step=self._calls,
+                            variant=variant):
+            out = step_jit(state, batch)
+        self._calls += 1
+        return out
 
     def step_memory_analysis(self, state: TrainState, batch) -> Optional[dict]:
         """Compiler-reported per-device HBM footprint of the compiled train
@@ -826,16 +847,33 @@ class Trainer:
             out["peak_bytes"] = mem["peak_bytes"]
         return out or None
 
-    def compiled_step_text(self, state: TrainState, batch) -> str:
+    def compiled_step_text(self, state: Optional[TrainState] = None,
+                           batch=None, telemetry: bool = False) -> str:
         """Post-optimization HLO of the compiled train step.
 
-        Used by parallel/comms_model.crosscheck to count the collective ops
-        GSPMD actually inserted against the analytic traffic model. Same jit
-        object + shapes as the running step, so this hits the executable
-        cache rather than recompiling.
+        Read by parallel/comms_model.crosscheck, which counts the collective
+        ops GSPMD actually inserted against the analytic traffic model, and,
+        through ``utils.profiling.program_texts``, by whoever attributes a
+        device trace to the program (``perf/program_trace.py``): every
+        instruction's ``metadata={op_name=...}`` is the path of scopes it
+        came from. Without ``state`` and ``batch`` the step is lowered from
+        the abstract arguments remembered at the variant's first call, so
+        it needs no live buffers (the running state has been donated by
+        then); ``jax.ShapeDtypeStruct`` arguments do as well (a compile
+        for a described chip). Same jit object + shapes as the running
+        step: the lowering and the executable come from jax's caches.
         """
-        batch = self.place_batch(batch)
-        return self._step_jit.lower(state, batch).compile().as_text()
+        step_jit = self._step_tel_jit if telemetry else self._step_jit
+        if state is None:
+            variant = "telemetry" if telemetry else "plain"
+            if variant not in self._step_signature:
+                raise ValueError(
+                    f"the {variant} train step has not run yet: pass state "
+                    f"and batch")
+            state, batch = self._step_signature[variant]
+        elif not isinstance(batch, jax.ShapeDtypeStruct):
+            batch = self.place_batch(batch)
+        return step_jit.lower(state, batch).compile().as_text()
 
     def executable_cache_size(self) -> Optional[int]:
         """Number of executables cached across the train-step jit variants.
@@ -898,7 +936,8 @@ class Trainer:
                 self._eval_batch_sharding, local,
                 (n * self.data_feed_world, seq) + local.shape[2:]
             )
-        return self._eval_jit(state, batch)
+        with profiling.span("trainer:eval_step", step=self._calls):
+            return self._eval_jit(state, batch)
 
     def _eval_step(self, state: TrainState, batch: jax.Array):
         tokens, segs = _split_packed(batch)
@@ -997,16 +1036,19 @@ class Trainer:
             else:
                 loss_sum = aux
         else:
-            zero_grads = jax.tree_util.tree_map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), state.params
-            )
+            with jax.named_scope("grad_accum"):
+                zero_grads = jax.tree_util.tree_map(
+                    lambda p: jnp.zeros(p.shape, jnp.float32), state.params
+                )
 
             def micro_step(carry, micro):
                 grads_acc, loss_acc, rng = carry
                 rng, sub = jax.random.split(rng)
                 (_, aux), grads = grad_fn(state.params, micro, sub, state.loss_scale)
                 loss = aux[0] if telemetry_on else aux
-                grads_acc = jax.tree_util.tree_map(jnp.add, grads_acc, grads)
+                with jax.named_scope("grad_accum"):
+                    grads_acc = jax.tree_util.tree_map(
+                        jnp.add, grads_acc, grads)
                 ys = aux[1] if telemetry_on else None
                 return (grads_acc, loss_acc + loss, rng), ys
 
@@ -1018,18 +1060,19 @@ class Trainer:
                 fwd_stats = telemetry.reduce_micro(fwd_stack)
         # Mean over micro-steps and undo the loss scale; then pin the grads to
         # their ZeRO sharding (the reduce-scatter point under zero2/zero3).
-        denom = accum * state.loss_scale
-        grads = jax.tree_util.tree_map(lambda g: g / denom, grads)
-        grads = shard_lib.constrain(grads, self._grad_shardings)
+        with jax.named_scope("grad_finalize"):
+            denom = accum * state.loss_scale
+            grads = jax.tree_util.tree_map(lambda g: g / denom, grads)
+            grads = shard_lib.constrain(grads, self._grad_shardings)
+            grad_norm = optax.global_norm(grads)
         loss = loss_sum / accum
-
-        grad_norm = optax.global_norm(grads)
 
         # Schedule applied here, as a pure function of state.step (fixes b1;
         # also keeps logged LR == applied LR across fp16 overflow skips, where
         # the optimizer chain's internal count freezes but the schedule ticks).
         lr = cfg.lr_at(state.step)
 
+        @jax.named_scope("optimizer")
         def apply_update(_):
             opt_in = state.opt_state
             if self.cpu_offload:

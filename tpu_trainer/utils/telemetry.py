@@ -48,6 +48,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpu_trainer.utils import profiling
+
 # --- trace-time capture ------------------------------------------------------
 #
 # A plain Python stack of dict containers. ``capture()`` is entered while the
@@ -342,9 +344,12 @@ class GoodputLedger:
 
     @contextlib.contextmanager
     def track(self, category: str):
+        # Also a `goodput:<category>` span in any open profiler trace
+        # (utils/profiling.py); the arithmetic below does not change.
         t = self._clock()
         try:
-            yield
+            with profiling.span("goodput:" + category):
+                yield
         finally:
             self.add(category, self._clock() - t)
 
