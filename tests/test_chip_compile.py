@@ -63,22 +63,36 @@ def _compile(fn, *shapes) -> str:
 
 # --- training kernels ------------------------------------------------------
 
-@pytest.mark.parametrize("b,s,h,d", [(8, 1024, 12, 64), (2, 4096, 12, 64),
-                                     (4, 2048, 16, 128)])
-def test_flash_attention_fwd_and_grad(chip, b, s, h, d):
+@pytest.mark.parametrize("b,s,h,kvh,d,rope", [
+    (8, 1024, 12, 12, 64, False), (2, 4096, 12, 12, 64, False),
+    (4, 2048, 16, 16, 128, False),
+    (4, 2048, 15, 5, 64, True), (4, 2048, 32, 32, 64, True),
+    (2, 2048, 8, 2, 128, True)])
+def test_flash_attention_fwd_and_grad(chip, b, s, h, kvh, d, rope):
     # s=1024: fused backward (the `small` preset's training shape);
     # s=4096: the split dkv/dq backward; d=128: the queued configurations.
-    x = chip((b, s, h, d), jnp.bfloat16)
+    # With RoPE fused: the benchmark's two cells (the 360M's 15 heads over
+    # 5 K/V heads, padded and expanded to 16; the 1.7B's 32) and a d=128
+    # GQA, all through the streaming forward at 512 x 512 blocks: lane
+    # rolls, [block_q, 128] state, K read back from the rotated-K output.
+    q = chip((b, s, h, d), jnp.bfloat16)
+    kv = chip((b, s, kvh, d), jnp.bfloat16)
+    tabs = (chip((s, d), jnp.float32),) * 2 if rope else ()
 
-    def loss(q, k, v):
-        return flash_attention(q, k, v).astype(jnp.float32).sum()
+    def fwd(q, k, v, *tabs):
+        return flash_attention(q, k, v, rope=tabs or None)
 
-    _compile(flash_attention, x, x, x)
-    _compile(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+    def loss(q, k, v, *tabs):
+        return fwd(q, k, v, *tabs).astype(jnp.float32).sum()
+
+    _compile(fwd, q, kv, kv, *tabs)
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, *tabs)
 
 
-def test_flash_attention_rope_dropout_fused(chip):
-    b, s, h, d = 8, 1024, 12, 64
+@pytest.mark.parametrize("s", [1024, 2048])
+def test_flash_attention_rope_dropout_fused(chip, s):
+    # s=2048 streams: the hardware-PRNG mask beside the full-width state.
+    b, h, d = 8, 12, 64
     x = chip((b, s, h, d), jnp.bfloat16)
     tab = chip((s, d), jnp.float32)
     key = chip((2,), jnp.uint32)

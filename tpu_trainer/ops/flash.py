@@ -5,9 +5,9 @@ the reference's call into torch's fused ``scaled_dot_product_attention``
 (``/root/reference/src/models/gpt.py:199-206``) — except implemented here as a
 blockwise-streaming kernel rather than a library call.
 
-Design (flash-attention-2 structure, written for the TPU memory hierarchy;
-every structural choice below is trace-measured on v5e — see
-benchmarks/results.md "Round-3 kernel push"):
+Design (flash-attention-2 structure, written for the TPU memory hierarchy).
+What runs where, as measured on a v5e (PERF.md has the runs; the tables of
+benchmarks/results.md predate this runtime and are no evidence):
 
 - Grid ``(batch, heads/hp, seq // block_q)`` with ``hp`` heads per program
   (2 for head_dim 64 so the block lane width is 128; 1 for d%128==0).
@@ -18,8 +18,23 @@ benchmarks/results.md "Round-3 kernel push"):
 - Block loops are STATIC Python unrolls with ``pl.when``-predicated bodies
   (softmax state in VMEM scratch), not ``fori_loop``s with data-dependent
   trip counts — Mosaic cannot schedule those, and causality's skipped
-  blocks measured as costing full price. At ``seq <= block`` a
-  single-block fast path drops the online softmax entirely.
+  blocks measured as costing full price. At ``seq <= block`` (s <= 1024)
+  a single-block fast path drops the online softmax entirely.
+- Longer sequences STREAM 512 x 512 blocks (the wrapper caps the default
+  1024 there: the 1024-block streaming forward does not fit the 16 MB
+  scope). That is what the benchmark's cells run at s=2048: 10 of 16
+  block pairs a head. The streaming forward works on whole ``[*, hp*d]``
+  slabs, full 128-lane registers for a d=64 head pair: heads are told
+  apart by zeroed q lanes in the score contraction and a lane select on
+  the accumulator, never by slicing 64 lanes out; the running max and sum
+  are kept replicated over 128 lanes (``[block_q, 128]``), so nothing is
+  broadcast out of a one-lane column; and each K block is rotated ONCE,
+  by the first program that needs it, then read back from the rotated-K
+  output block, which stays in VMEM along the (sequential) query-block
+  axis. PR 27, forward alone, bf16, causal, fused RoPE: ``[4, 2048, 16,
+  64]`` 0.87 ms a call (1.36 us a block pair and head) where per-head
+  64-lane halves, ``[block_q, 1]`` state and a rotation at every visit
+  took 1.53 ms (2.39 us); ``[4, 2048, 32, 64]`` 1.73 ms against 3.05.
 - Operands are the model's FOLDED ``[b, s, h*d]`` layout, sliced per
   head(-pair) by the BlockSpecs: no BSHD transpose ever exists in HBM.
 - Backward DISPATCHES on sequence length. At s <= 2048 it is one fused
@@ -43,7 +58,9 @@ benchmarks/results.md "Round-3 kernel push"):
   bit-identical masks under its different block shape.
 - RoPE fuses in: q/k rotate in VMEM, and the forward *emits* the rotated
   (+ 1/sqrt(d)-scaled) q/k as outputs that replace the raw projections in
-  the autodiff residuals — the backward never re-rotates per block.
+  the autodiff residuals — the backward never re-rotates per block, and
+  the streaming forward reads its own rotated K back instead of rotating
+  a block again for every query block that meets it.
 - All accumulation in float32 regardless of input dtype (bf16 in, bf16 out).
 
 The public API is BSHD ``[batch, seq, heads, head_dim]`` (the model's
@@ -64,17 +81,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# 1024-blocks won the v5e sweep: at s=1024 the whole head fits one block
-# (no online-softmax rescaling at all — the kernel's single-block fast
-# path, ~33% faster than 512-block streaming), and for longer sequences
-# the [1024, 1024] score block still amortizes the per-block VPU work
-# best. 128-blocks measure ~2.3x slower end to end (pipeline bubbles
-# dominate the small dots). The wrapper clamps to the sequence length.
+# The largest block a program may take. At s <= 1024 the whole head is one
+# block (no online-softmax rescaling at all: the kernel's single-block
+# fast path). Longer sequences do NOT run 1024-blocks: `flash_attention`
+# caps streaming at 512 x 512 (the 1024-block streaming forward needs
+# 18.9 MB of the 16 MB scope) unless the caller raised the scoped-VMEM
+# limit, so s=2048 — the benchmark's cells — streams 4 x 4 blocks of 512.
+# The wrapper also clamps to the sequence length.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 # The backward is FLOP-bound (5 dots/block, no online rescan): causal
 # block-skipping at 512 measured faster than the single-block layout.
 _BWD_BLOCK = 512
+_LANES = 128  # lane width of a vector register
 _NEG_INF = float("-inf")
 # Mask value for SEGMENTED kernel instances. With segment skipping a
 # q-row's first *processed* k-block can be fully masked (every column in
@@ -214,6 +233,38 @@ def _rotate(x, cos, sin, out_dtype, scale=1.0):
     return out.astype(out_dtype)
 
 
+def _rotate_heads(x, cos, sin, d, out_dtype, scale=1.0):
+    """``_rotate`` of every head of a ``[n, hp*d]`` slab at once (``cos/sin``
+    tiled to ``[n, hp*d]``): the streaming forward's rotation, on full
+    128-lane registers when two d=64 heads share a program. ``rotate_half``
+    inside each ``d``-lane head is a lane roll by ``d/2`` either way, picked
+    by lane; the arithmetic and its order are ``_rotate``'s, so the result
+    is bit-identical to rotating head by head."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    w, half = x.shape[-1], d // 2
+    x32 = x.astype(jnp.float32)
+    up = pltpu.roll(x32, w - half, 1)                    # up[j] = x[j + d/2]
+    down = up if w == d else pltpu.roll(x32, half, 1)    # down[j] = x[j - d/2]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x32.shape, 1)
+    out = x32 * cos + jnp.where(lane % d < half, -up, down) * sin
+    if scale != 1.0:
+        out = out * scale
+    return out.astype(out_dtype)
+
+
+def _lane_tile(x, n):
+    """A lane-replicated ``[rows, 128]`` value as ``[rows, n]``: whole
+    registers repeated or a prefix of one, never a one-lane broadcast (the
+    last case is only met by block widths no compiled kernel uses)."""
+    w = x.shape[1]
+    if n % w == 0:
+        return x if n == w else jnp.concatenate([x] * (n // w), axis=1)
+    if n < w:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
 def _unrotate_grad(g, cos, sin):
     """VJP of ``_rotate`` w.r.t. x applied to cotangent ``g`` (f32):
     ``g*cos + rotate_half^T(g*sin)`` where ``rotate_half^T([a,b]) = [b,-a]``."""
@@ -256,11 +307,13 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest,
     # the number of heads per program — 2 for d=64 so the block's lane
     # width is 128 (Mosaic requires the last block dim to be a multiple
     # of 128 or the full array width), 1 for d a multiple of 128. Heads
-    # within a program run as a static Python loop over static column
-    # slices. No BSHD transpose ever happens in HBM — round 2 transposed
-    # to [b, h, s, d] around every pallas call, costing a layout copy per
-    # operand per layer. lse_ref: [1, hp, 1, seq] (full rows, written
-    # blockwise). With fuse_rope, cos/sin [seq, d] ride along and q/k
+    # within a program run as a static Python loop: over static column
+    # slices in the single-block path, over the whole slab in the
+    # streaming path (see there). No BSHD transpose ever happens in HBM —
+    # round 2 transposed to [b, h, s, d] around every pallas call, costing
+    # a layout copy per operand per layer. lse_ref: [1, hp, 1, seq] (full
+    # rows, written blockwise). With fuse_rope, cos/sin ride along
+    # ([seq, d]; tiled to [seq, hp*d] for the streaming path) and q/k
     # rotate in VMEM — no rotated copies hit HBM.
     #
     # The k loop is a STATIC Python unroll with `pl.when`-predicated block
@@ -270,8 +323,9 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest,
     # ran no faster than computing every block — causality's 2x FLOP
     # saving bought zero time. Static unroll + predication makes skipped
     # blocks actually free (a branch), and lets the scheduler software-
-    # pipeline across block bodies. Softmax state (m, l, acc) lives in
-    # per-head VMEM scratch across the predicated regions.
+    # pipeline across block bodies. Softmax state (m, l per head, one acc
+    # for the program's heads) lives in VMEM scratch across the predicated
+    # regions.
     # Under fuse_rope the kernel additionally WRITES the rotated
     # (and, for q, pre-scaled) projections as outputs: the backward then
     # consumes them directly instead of re-rotating q/k per block — the
@@ -291,7 +345,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest,
     else:
         o_ref, lse_ref, *scrs = rest
         qr_ref = kr_ref = None
-    m_scrs, l_scrs, acc_scrs = scrs[:hp], scrs[hp:2 * hp], scrs[2 * hp:]
+    m_scrs, l_scrs, acc_scr = scrs[:hp], scrs[hp:2 * hp], scrs[2 * hp]
     block_q = q_ref.shape[1]
     d = q_ref.shape[2] // hp
     seq = k_ref.shape[1]
@@ -370,10 +424,65 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest,
             lse_ref[0, t, 0, :] = m[:, 0] + jnp.log(l[:, 0])
         return
 
+    # ---- streaming (multi-block) path. Everything below works on the
+    # program's whole ``[*, hp*d]`` slab — full 128-lane registers when two
+    # d=64 heads share it — and never slices a head out of the lanes:
+    # - head t's scores contract the slab with a copy of q whose other
+    #   heads' lanes are zero (a 128-deep contraction costs the MXU what a
+    #   64-deep one does, and the zeros add nothing);
+    # - ``p_t @ v`` runs against the whole V slab and head t keeps its own
+    #   ``d`` columns of the result, so the accumulator, its rescale and
+    #   the final store are full-width;
+    # - the running max and sum are kept replicated across 128 lanes
+    #   (``[block_q, 128]``, as the upstream Pallas TPU kernel keeps them):
+    #   ``s - m``, ``exp(m - m_new)`` and ``acc * alpha`` then need no
+    #   broadcast out of a one-lane column and the state no masked store.
+    # The module docstring has what each of these measured on a v5e.
+    width = hp * d
+    if hp > 1:
+        head_of_lane = jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, width), 1) // d
+
+    if fuse_rope:
+        q_all = _rotate_heads(q_ref[0], cos_ref[pl.ds(q_start, block_q), :],
+                              sin_ref[pl.ds(q_start, block_q), :], d,
+                              q_ref.dtype, scale=scale)
+        qr_ref[0] = q_all
+        # Each K block is rotated ONCE, by the first program that needs
+        # it, into the rotated-K output: that block's index does not
+        # depend on ``iq`` and the ``iq`` axis runs in order, so it stays
+        # in VMEM for the later programs of this (batch, head group), which
+        # read it back (as the fused backward reads ``dq_ref``). Under
+        # ``causal`` the first program to need K block ``ik`` is the one
+        # whose query rows hold the block's first row — it always computes
+        # that block, segments or not, since a row attends to itself — and
+        # every earlier block was written by an earlier program; without
+        # causality program 0 rotates them all. 4 rotations a head group at
+        # s=2048 where rotating at every visit made 10.
+        for ik in range(seq // block_k):
+            k_start = ik * block_k
+            if causal:
+                first = ((k_start >= q_start)
+                         & (k_start < q_start + block_q))
+            else:
+                first = iq == 0
+
+            @pl.when(first)
+            def _rotate_k(k_start=k_start):
+                rows = pl.ds(k_start, block_k)
+                kr_ref[0, rows, :] = _rotate_heads(
+                    k_ref[0, rows, :], cos_ref[rows, :], sin_ref[rows, :],
+                    d, k_ref.dtype)
+    else:
+        q_all = (q_ref[0].astype(jnp.float32) * scale).astype(q_ref.dtype)
+    qs = [q_all if hp == 1
+          else jnp.where(head_of_lane == t, q_all, jnp.zeros_like(q_all))
+          for t in range(hp)]
+
     for t in range(hp):
-        m_scrs[t][...] = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
-        l_scrs[t][...] = jnp.zeros((block_q, 1), jnp.float32)
-        acc_scrs[t][...] = jnp.zeros((block_q, d), jnp.float32)
+        m_scrs[t][...] = jnp.full((block_q, _LANES), _NEG_INF, jnp.float32)
+        l_scrs[t][...] = jnp.zeros((block_q, _LANES), jnp.float32)
+    acc_scr[...] = jnp.zeros((block_q, width), jnp.float32)
 
     if causal:
         # Row-minus-column iota difference, hoisted out of the block loop:
@@ -383,33 +492,29 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest,
         diff = (jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
                 - jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1))
 
-    qs = [load_q(t) for t in range(hp)]
-
     def body(ik: int, masked: bool):
         k_start = ik * block_k  # static
+        rows = pl.ds(k_start, block_k)
+        k = (kr_ref if fuse_rope else k_ref)[0, rows, :]   # [bk, hp*d]
+        v = v_ref[0, rows, :]
+        if masked:
+            valid = None
+            if causal:
+                valid = diff >= k_start - q_start
+            if segmented:
+                # k_start is a static unroll index: plain value slice.
+                same = qseg == kseg_row[k_start:k_start + block_k][None, :]
+                valid = same if valid is None else valid & same
         for t in range(hp):
-            m, l, acc = m_scrs[t][...], l_scrs[t][...], acc_scrs[t][...]
-            k = k_ref[0, pl.ds(k_start, block_k), pl.ds(t * d, d)]
-            v = v_ref[0, pl.ds(k_start, block_k), pl.ds(t * d, d)]
-            if fuse_rope:
-                k = _rotate(k, cos_ref[pl.ds(k_start, block_k), :],
-                            sin_ref[pl.ds(k_start, block_k), :], k_ref.dtype)
-                kr_ref[0, pl.ds(k_start, block_k), pl.ds(t * d, d)] = k
+            m, l = m_scrs[t][...], l_scrs[t][...]     # [bq, 128] replicated
             s = jax.lax.dot_general(
                 qs[t], k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # [bq, bk] f32 (already scaled via q)
             if masked:
-                valid = None
-                if causal:
-                    valid = diff >= k_start - q_start
-                if segmented:
-                    # k_start is a static unroll index: plain value slice.
-                    same = qseg == kseg_row[k_start:k_start + block_k][None, :]
-                    valid = same if valid is None else valid & same
                 s = jnp.where(valid, s, mask_val)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
+            p = jnp.exp(s - _lane_tile(m_new, block_k))
             alpha = jnp.exp(m - m_new)
             # The softmax normalizer sums the *undropped* weights (dropout
             # acts on normalized weights in the reference, gpt.py:230-234
@@ -418,16 +523,19 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest,
             if dropout_rate > 0.0:
                 # Survivors keep their raw weight here; the 1/(1-rate)
                 # inverted-dropout scale folds into the final acc/l division
-                # (one [bq, 1] multiply) instead of a per-element multiply
-                # per block.
+                # instead of a per-element multiply per block.
                 keep = _keep(seed, head_salt(t), q_start, k_start,
                              block_q, block_k, seq, dropout_rate, hw_prng)
                 p = jnp.where(keep, p, 0.0)
             m_scrs[t][...] = m_new
             l_scrs[t][...] = l_new
-            acc_scrs[t][...] = acc * alpha + jnp.dot(
-                p.astype(v.dtype), v, preferred_element_type=jnp.float32
-            )
+            # [bq, hp*d]: head t's own d columns are p_t @ v_t, the rest
+            # (p_t against the other heads' V) is dropped by the select.
+            acc = acc_scr[...]
+            new = acc * _lane_tile(alpha, width) + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            acc_scr[...] = (new if hp == 1
+                            else jnp.where(head_of_lane == t, new, acc))
 
     for ik in range(seq // block_k):
         if not causal and not segmented:
@@ -458,10 +566,14 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest,
         pl.when(run_masked)(functools.partial(body, ik, True))
 
     for t in range(hp):
-        m, l, acc = m_scrs[t][...], l_scrs[t][...], acc_scrs[t][...]
-        denom = l * (1.0 - dropout_rate) if dropout_rate > 0.0 else l
-        o_ref[0, :, pl.ds(t * d, d)] = (acc / denom).astype(o_ref.dtype)
+        m, l = m_scrs[t][...], l_scrs[t][...]
         lse_ref[0, t, 0, pl.ds(q_start, block_q)] = m[:, 0] + jnp.log(l[:, 0])
+        # Each head's sum on its own lanes of the accumulator.
+        l = _lane_tile(l, width)
+        denom = l if t == 0 else jnp.where(head_of_lane == t, l, denom)
+    if dropout_rate > 0.0:
+        denom = denom * (1.0 - dropout_rate)
+    o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def _seed_spec():
@@ -526,6 +638,10 @@ def _flash_forward(q3, k3, v3, seed_f, seg_f, rope, *, num_heads, head_dim,
     row_spec = pl.BlockSpec((1, hp, 1, s), lambda ib, ip, iq: (ib, ip, 0, 0))
     fuse_rope = rope is not None
     rope_args = tuple(rope) if fuse_rope else ()
+    if fuse_rope and hp > 1 and not (s == block_q == block_k):
+        # The streaming path (not the single block) rotates a program's
+        # heads together: one table column per lane of the [*, hp*d] slab.
+        rope_args = tuple(jnp.tile(t, (1, hp)) for t in rope_args)
     seg_args = ()
     seg_specs = []
     if segmented:
@@ -548,7 +664,8 @@ def _flash_forward(q3, k3, v3, seed_f, seg_f, rope, *, num_heads, head_dim,
         ),
         grid=grid,
         in_specs=[_seed_spec(), q_spec, kv_spec, kv_spec]
-        + (_rope_specs(s, d) if fuse_rope else []) + seg_specs,
+        + (_rope_specs(s, rope_args[0].shape[1]) if fuse_rope else [])
+        + seg_specs,
         out_specs=[q_spec, row_spec]
         + ([q_spec, kv_spec] if fuse_rope else []),
         out_shape=[
@@ -558,9 +675,14 @@ def _flash_forward(q3, k3, v3, seed_f, seg_f, rope, *, num_heads, head_dim,
         + ([jax.ShapeDtypeStruct((b, s, h * d), q3.dtype),
             jax.ShapeDtypeStruct(k3.shape, k3.dtype)] if fuse_rope else []),
         scratch_shapes=(
-            [pltpu.VMEM((block_q, 1), jnp.float32)] * (2 * hp)
-            + [pltpu.VMEM((block_q, d), jnp.float32)] * hp
+            [pltpu.VMEM((block_q, _LANES), jnp.float32)] * (2 * hp)
+            + [pltpu.VMEM((block_q, hp * d), jnp.float32)]
         ),
+        # The query-block axis must run in order: the lse row, the rotated
+        # K and (under GQA by index map, across the heads of a group too)
+        # their output blocks stay in VMEM from one program to the next.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(seed_f, q3, k3, v3, *rope_args, *seg_args)
     if fuse_rope:
@@ -1582,8 +1704,6 @@ def flash_attention(
 # ``paged_attention_reference`` is the pure-jnp path: identical math via
 # a full-table gather, used as the CPU serving path and the parity oracle
 # tier-1 pins the kernel against (interpret=True).
-
-_LANES = 128
 
 
 def _decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *rest,

@@ -17,7 +17,9 @@ without a TPU — nothing here can pass by being skipped):
 1-9.  The flash-kernel checks from round 3 (hw-PRNG determinism/variation,
       dropout unbiasedness, mask equality across tilings and iteration
       orders, linear-in-v gradient identity under mixed fwd/bwd tiling,
-      odd-head-count outputs + grads, GQA vs repeated-KV oracle).
+      odd-head-count outputs + grads, GQA vs repeated-KV oracle), the
+      head+CE kernel, and the streaming forward with fused RoPE at the
+      360M benchmark cell's attention shape against the dense reference.
 10.   Offload bitwise: the ``pinned_host``-offloaded train step produces
       bit-identical losses to the on-device step over 5 steps (f32
       storage), on the real chip's memory spaces.
@@ -191,6 +193,45 @@ def _kernel_checks():
     check("fused head+CE kernel vs XLA loss",
           dl < 1e-4 and de < 1e-4 and dx < 1e-4,
           f"dloss={dl:.1e} dE={de:.1e} dx={dx:.1e}")
+
+    # 9. The streaming forward (512 x 512 blocks, RoPE fused, two heads a
+    # program) and the fused backward at the 360M benchmark cell's shape —
+    # 15 heads over 5 K/V heads, so the zero head and the K/V expansion are
+    # in — against the dense f32 reference with RoPE applied outside.
+    from tpu_trainer.ops.attention import reference_attention
+    from tpu_trainer.ops.rope import apply_rotary_pos_emb, rope_tables
+
+    bc, sc, hc, kvc = 4, 2048, 15, 5
+    kc = jax.random.split(jax.random.PRNGKey(27), 4)
+    qc = jax.random.normal(kc[0], (bc, sc, hc, d), jnp.bfloat16)
+    kc_ = jax.random.normal(kc[1], (bc, sc, kvc, d), jnp.bfloat16)
+    vc = jax.random.normal(kc[2], (bc, sc, kvc, d), jnp.bfloat16)
+    probe_c = jax.random.normal(kc[3], qc.shape, jnp.float32)
+    tabs = rope_tables(sc, d)
+
+    def kernel_loss(q_, k_, v_):
+        out = flash_attention(q_, k_, v_, rope=tabs)
+        return jnp.sum(out.astype(jnp.float32) * probe_c), out
+
+    def dense_loss(q_, k_, v_):
+        qr_, kr_ = apply_rotary_pos_emb(q_, k_, *tabs)
+        out = reference_attention(qr_, kr_, v_)
+        return jnp.sum(out * probe_c), out
+
+    def grads_and_out(f, *xs):
+        return jax.jit(jax.grad(f, argnums=(0, 1, 2), has_aux=True))(*xs)
+
+    got_g, got_o = grads_and_out(kernel_loss, qc, kc_, vc)
+    with jax.default_matmul_precision("highest"):
+        want_g, want_o = grads_and_out(
+            dense_loss, *(x.astype(jnp.float32) for x in (qc, kc_, vc)))
+    errs = [float(jnp.max(jnp.abs(g.astype(jnp.float32) - w)))
+            for g, w in zip((got_o, *got_g), (want_o, *want_g))]
+    check("streaming forward + backward vs dense reference "
+          f"[{bc}, {sc}, {hc}/{kvc}, {d}]",
+          errs[0] < 3e-2 and max(errs[1:]) < 1e-1,
+          "max|kernel - reference|: out={:.2e} dq={:.2e} dk={:.2e} "
+          "dv={:.2e}".format(*errs))
 
 
 def _tiny_trainer(offload=False, offload_dtype="float32",
