@@ -132,6 +132,29 @@ def test_grouped_matmul_fwd_wgrad_and_grad(chip):
     _compile(jax.grad(loss, argnums=(0, 1)), lhs, rhs, sizes)
 
 
+def test_grouped_matmul_with_a_share_of_the_experts_held(chip):
+    # The LFM2 cell's shapes (4 x 4096 tokens x 4 choices in the buffer, 8
+    # experts of 2048 x 1536 held, group sizes summing to about an eighth
+    # of it): 512 x 512 tiles, whose wgrad block needs more than the
+    # default 16 MiB of scoped VMEM.
+    g, hid, n, e = 65536, 2048, 1536, 8
+    lhs = chip((g, hid), jnp.bfloat16)
+    mid = chip((g, n), jnp.bfloat16)
+    up = chip((e, hid, n), jnp.bfloat16)
+    down = chip((e, n, hid), jnp.bfloat16)
+    sizes = chip((e,), jnp.int32)
+    kernel = dict(use_kernel=True, interpret=False)
+
+    def loss(lhs, up, down, sizes):
+        out = gmm(gmm(lhs, up, sizes, **kernel), down, sizes, **kernel)
+        return out.astype(jnp.float32).sum()
+
+    _compile(functools.partial(tgmm, **kernel), lhs, mid, sizes)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), lhs, up, down, sizes)
+    # The second forward is dead code under a sum: gmm, 2 dgrad, 2 wgrad.
+    assert text.count('custom_call_target="tpu_custom_call"') == 5
+
+
 # --- serving kernel --------------------------------------------------------
 
 # (heads, kv_heads, head_dim): GPT-2-small serving geometry, then the d=128

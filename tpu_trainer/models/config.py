@@ -29,6 +29,11 @@ _DTYPES = {
 }
 
 
+# Taps of the gated short convolution (``conv`` layers): the current and the
+# two earlier positions. One value in use (``conv_L_cache`` 3), so a constant.
+CONV_TAPS = 3
+
+
 def dtype_of(name: str):
     """Map a dtype name ('float32' | 'bfloat16' | 'float16') to a jnp dtype."""
     return _DTYPES[name]
@@ -103,6 +108,37 @@ class GPTConfig:
     moe_impl: str = "capacity"
     moe_aux_weight: float = 0.01
     router_z_weight: float = 0.0
+
+    # --- Layers that differ (hybrid conv / attention stacks) -------------
+    # What a published architecture states, never a tuning knob; with all
+    # of them at their defaults the model is the uniform stack above, with
+    # its parameter tree and compiled step unchanged.
+    # Per-layer sequence operator, in published order: "full_attention"
+    # (CausalSelfAttention) or "conv" (the gated short convolution,
+    # models/gpt.py ShortConv). None = attention in every layer. Layers of
+    # one (operator, ffn) kind are stacked together
+    # (``layers_<operator>_<ffn>`` leaves, [count, ...]).
+    layer_types: Optional[Tuple[str, ...]] = None
+    # With experts on: how many leading layers keep the dense SwiGLU.
+    num_dense_layers: int = 0
+    # Expert width (None = intermediate_size, the dense width).
+    moe_intermediate_size: Optional[int] = None
+    # Router: "softmax" (Switch/GShard, above) or "sigmoid": scores
+    # s = sigmoid(x W_r) in f32, the top-k of s + b chosen (b: a
+    # selection-bias buffer that no gradient reaches, held at zero: no
+    # update rule is published), gates s[sel] / (sum s[sel] + 1e-6).
+    # Dropless only.
+    moe_router: str = "softmax"
+    # The experts this chip holds, (first id, count), of num_experts (the
+    # router's width): the layer routes over all of them, computes its own
+    # experts' part of the result and leaves the rest out, which is what
+    # expert parallelism asks of it; on one chip it runs without the
+    # exchange. None = all.
+    moe_experts_held: Optional[Tuple[int, int]] = None
+    # RMSNorm over each head's q and k before RoPE ([head_dim] weights).
+    qk_norm: bool = False
+    # RMSNorm epsilon, every norm of the model.
+    norm_eps: float = 1e-6
 
     # Optimization flags (reference config.py:30-32)
     use_flash_attention: bool = False
@@ -342,6 +378,41 @@ class GPTConfig:
             from tpu_trainer.serving.sharding import validate_tp
 
             validate_tp(self.num_heads, self.kv_heads, self.paged_tp)
+        if self.layer_types is not None:
+            object.__setattr__(
+                self, "layer_types", tuple(self.layer_types))
+            if len(self.layer_types) != self.num_layers or any(
+                    t not in ("conv", "full_attention")
+                    for t in self.layer_types):
+                raise ValueError(
+                    f"layer_types must name 'conv' or 'full_attention' for "
+                    f"each of the {self.num_layers} layers; got "
+                    f"{self.layer_types!r}")
+        if not 0 <= self.num_dense_layers <= self.num_layers:
+            raise ValueError(
+                f"num_dense_layers ({self.num_dense_layers}) must be in "
+                f"[0, num_layers={self.num_layers}]")
+        if self.moe_router not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"unknown moe_router {self.moe_router!r}; "
+                f"choose softmax or sigmoid")
+        if self.moe_experts_held is not None:
+            object.__setattr__(
+                self, "moe_experts_held",
+                tuple(int(v) for v in self.moe_experts_held))
+            first, count = self.moe_experts_held
+            if not (0 <= first and 1 <= count
+                    and first + count <= self.num_experts):
+                raise ValueError(
+                    f"moe_experts_held (first, count) = "
+                    f"{self.moe_experts_held!r} is not a range of the "
+                    f"{self.num_experts} experts")
+        if (self.moe_router == "sigmoid"
+                or self.moe_experts_held is not None) and (
+                    self.num_experts > 0 and self.moe_impl != "dropless"):
+            raise ValueError(
+                "the sigmoid router and a held subset of experts run "
+                "through moe_impl='dropless' only")
         if self.remat_policy not in ("full", "dots"):
             raise ValueError(
                 f"unknown remat_policy {self.remat_policy!r}; "
@@ -357,6 +428,34 @@ class GPTConfig:
         """Resolved K/V head count (num_kv_heads, defaulting to num_heads)."""
         return (self.num_kv_heads if self.num_kv_heads is not None
                 else self.num_heads)
+
+    @property
+    def expert_width(self) -> int:
+        return (self.moe_intermediate_size
+                if self.moe_intermediate_size is not None
+                else self.intermediate_size)
+
+    @property
+    def experts_held(self) -> Tuple[int, int]:
+        """(first id, count) of the experts whose weights live here."""
+        return (self.moe_experts_held if self.moe_experts_held is not None
+                else (0, self.num_experts))
+
+    @property
+    def uniform_layers(self) -> bool:
+        """Every layer is the same block: today's single ``layers`` stack."""
+        return self.layer_types is None and (
+            self.num_dense_layers == 0 or self.num_experts <= 0)
+
+    def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
+        """(operator, ffn) of each layer in published order: operator
+        "attention" | "conv", ffn "dense" | "moe"."""
+        ops = self.layer_types or ("full_attention",) * self.num_layers
+        return tuple(
+            ("conv" if op == "conv" else "attention",
+             "moe" if self.num_experts > 0 and i >= self.num_dense_layers
+             else "dense")
+            for i, op in enumerate(ops))
 
     @property
     def compute_dtype(self):
@@ -404,35 +503,45 @@ class GPTConfig:
             raise ValueError(f"unknown model size {name!r}; choose from {sorted(presets)}")
         return presets[name](**overrides)
 
+    def _parameter_count(self, experts_counted: int) -> int:
+        h, i = self.hidden_size, self.intermediate_size
+        d = self.head_dim
+        attn = 2 * h * h + 2 * h * self.kv_heads * d  # q/o full, k/v grouped
+        if self.qk_norm:
+            attn += 2 * d
+        operator = {"attention": attn,
+                    "conv": 3 * h * h + h * CONV_TAPS + h * h}
+        router = h * self.num_experts
+        if self.moe_router == "sigmoid":
+            router += self.num_experts  # the selection bias (a buffer)
+        ffn = {"dense": 3 * h * i,
+               "moe": experts_counted * 3 * h * self.expert_width + router}
+        layers = sum(operator[op] + ffn[f] + 2 * h
+                     for op, f in self.layer_kinds())
+        return self.vocab_size * h + layers + h
+
     def num_parameters(self) -> int:
-        """Exact parameter count of the actual model.
+        """Exact parameter count of the actual model (what lives here: with
+        ``moe_experts_held`` the held experts only).
 
         embed (tied with lm_head): V*H
-        per layer: attention 2*H^2 (q/o) + 2*H*(kv_heads*head_dim) (k/v —
-                   equals 4*H^2 total without GQA), no bias
-                   + FFN: SwiGLU 3*H*I (dense) or E*3*H*I + H*E router (MoE)
+        per layer: operator — attention 2*H^2 (q/o) + 2*H*(kv_heads*head_dim)
+                   (k/v), + 2*head_dim with qk_norm; or the gated short
+                   conv 3*H^2 (in) + H*L (taps) + H^2 (out); no bias
+                   + FFN: SwiGLU 3*H*I (dense) or E_held*3*H*I_e + H*E router
+                   (+ E selection bias under the sigmoid router)
                    + 2 RMSNorm weight vectors (2*H)
         final RMSNorm: H
         """
-        h, i = self.hidden_size, self.intermediate_size
-        kv = self.kv_heads * self.head_dim
-        embed = self.vocab_size * h
-        if self.num_experts > 0:
-            ffn = self.num_experts * 3 * h * i + h * self.num_experts
-        else:
-            ffn = 3 * h * i
-        attn = 2 * h * h + 2 * h * kv  # q/o full, k/v grouped
-        per_layer = attn + ffn + 2 * h
-        return embed + self.num_layers * per_layer + h
+        return self._parameter_count(self.experts_held[1])
 
     def num_active_parameters(self) -> int:
         """Parameters a single token actually flows through: for MoE, only
         the ``moe_top_k`` routed experts' FFNs count (plus the router);
         dense models: == ``num_parameters()``. This is the N that belongs
         in the 6N FLOPs/token estimate — total-parameter MFU overstates
-        MoE utilization by ~E/top_k on the FFN share."""
+        MoE utilization by ~E/top_k on the FFN share. (Of the whole
+        layer's experts: a held subset computes its share of them.)"""
         if self.num_experts <= 0:
             return self.num_parameters()
-        h, i = self.hidden_size, self.intermediate_size
-        inactive_ffn = (self.num_experts - self.moe_top_k) * 3 * h * i
-        return self.num_parameters() - self.num_layers * inactive_ffn
+        return self._parameter_count(self.moe_top_k)

@@ -30,6 +30,7 @@ Architectural choices that are TPU-first rather than translations:
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Optional, Tuple
 
@@ -38,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tpu_trainer.models.config import GPTConfig
+from tpu_trainer.models.config import CONV_TAPS, GPTConfig
 from tpu_trainer.ops import ring
 from tpu_trainer.ops.attention import flash_attention, reference_attention
 from tpu_trainer.ops.dropout import hash_dropout
@@ -189,6 +190,13 @@ class CausalSelfAttention(nn.Module):
         q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
         k = k.reshape(b, s, cfg.kv_heads, cfg.head_dim)
         v = v.reshape(b, s, cfg.kv_heads, cfg.head_dim)
+        if cfg.qk_norm:
+            # Per head, over head_dim, BEFORE RoPE (so ahead of the kernel
+            # that fuses the rotation).
+            qk_norm = functools.partial(
+                RMSNorm, eps=cfg.norm_eps, dtype=cfg.compute_dtype)
+            q = qk_norm(name="q_layernorm")(q)
+            k = qk_norm(name="k_layernorm")(k)
 
         if decode:
             out = self._decode_attention(q, k, v)
@@ -683,6 +691,50 @@ class MLP(nn.Module):
         return _residual_dropout(cfg, self, x, deterministic)
 
 
+class ShortConv(nn.Module):
+    """Gated short convolution, the sequence operator of a ``conv`` layer:
+    ``[B, C, u] = split3(x W_in)``, ``z = B * u``, a depthwise causal
+    convolution over the current and ``CONV_TAPS - 1`` earlier positions
+    ``v_t = sum_j w[:, j] * z_{t-(L-1-j)}`` (zeros before the sequence, and
+    before a packed document's first token), then ``(C * v) W_out``. No
+    bias, no activation. Plain ``jax.numpy``: L - 1 shifted multiply-adds
+    beside two matmuls."""
+
+    config: GPTConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array, deterministic: bool = True,
+                 segment_ids: Optional[jax.Array] = None) -> jax.Array:
+        cfg = self.config
+        hidden, taps = cfg.hidden_size, CONV_TAPS
+        dense = functools.partial(
+            nn.Dense, use_bias=False, dtype=cfg.compute_dtype,
+            param_dtype=cfg.params_dtype,
+            kernel_init=nn.initializers.normal(cfg.initializer_range))
+        gate_in, gate_out, u = jnp.split(
+            dense(3 * hidden, name="in_proj")(x), 3, axis=-1)
+        z = gate_in * u
+        # [channels, taps], the last tap on the current position (torch's
+        # depthwise Conv1d layout and its default init, +-1/sqrt(taps)).
+        bound = taps ** -0.5
+        weight = self.param(
+            "conv_weight",
+            lambda key, shape, dtype: jax.random.uniform(
+                key, shape, dtype, -bound, bound),
+            (hidden, taps), cfg.params_dtype).astype(cfg.compute_dtype)
+        v = z * weight[:, taps - 1]
+        for back in range(1, taps):
+            shifted = jnp.pad(z, ((0, 0), (back, 0), (0, 0)))[:, :-back]
+            if segment_ids is not None:
+                same = segment_ids == jnp.pad(
+                    segment_ids, ((0, 0), (back, 0)),
+                    constant_values=-1)[:, :-back]
+                shifted = jnp.where(same[..., None], shifted, 0)
+            v = v + shifted * weight[:, taps - 1 - back]
+        out = dense(hidden, name="out_proj")(gate_out * v)
+        return _residual_dropout(cfg, self, out, deterministic)
+
+
 class TransformerBlock(nn.Module):
     """Pre-norm block with two residuals (reference ``gpt.py:286-316``).
 
@@ -700,25 +752,45 @@ class TransformerBlock(nn.Module):
     config: GPTConfig
     deterministic: bool = True
     decode: bool = False
+    # The layer's kind where layers differ (GPTConfig.layer_kinds); None =
+    # the uniform stack's block: attention, and experts iff the model has
+    # them.
+    kind: Optional[Tuple[str, str]] = None
 
     @nn.compact
     def __call__(self, carry, segment_ids=None):
         cfg = self.config
+        operator, ffn = self.kind or (
+            "attention", "moe" if cfg.num_experts > 0 else "dense")
+        norm_names = (("input_layernorm", "post_attention_layernorm")
+                      if self.kind is None else ("operator_norm", "ffn_norm"))
+        norm = functools.partial(
+            RMSNorm, eps=cfg.norm_eps, dtype=cfg.compute_dtype)
         x, aux = carry
         residual = x
-        h = RMSNorm(dtype=cfg.compute_dtype, name="input_layernorm")(x)
-        h = CausalSelfAttention(cfg, name="attention")(
-            h, self.deterministic, self.decode, segment_ids
-        )
+        h = norm(name=norm_names[0])(x)
+        if operator == "conv":
+            h = ShortConv(cfg, name="conv")(h, self.deterministic, segment_ids)
+        else:
+            h = CausalSelfAttention(cfg, name="attention")(
+                h, self.deterministic, self.decode, segment_ids
+            )
         attn_out = h
         x = residual + h
 
         residual = x
-        h = RMSNorm(dtype=cfg.compute_dtype, name="post_attention_layernorm")(x)
-        if cfg.num_experts > 0:
+        h = norm(name=norm_names[1])(x)
+        counts = None
+        if ffn == "moe":
             from tpu_trainer.models.moe import MoEMLP
 
-            h, layer_aux = MoEMLP(cfg, name="moe_mlp")(h, self.deterministic)
+            # The expert layer's step counters (models/moe.py) leave the
+            # layer loop beside the telemetry, under their own key, whether
+            # or not telemetry is being captured.
+            with (telemetry.counters() if telemetry.counting()
+                  else contextlib.nullcontext()) as counts:
+                h, layer_aux = MoEMLP(cfg, name="moe_mlp")(
+                    h, self.deterministic)
             aux = aux + layer_aux
         else:
             h = MLP(cfg, name="mlp")(h, self.deterministic)
@@ -739,7 +811,27 @@ class TransformerBlock(nn.Module):
                 telem.update(
                     {f"router_{k}": v for k, v in router.items()}
                 )
+        if counts:
+            telem = {**(telem or {}), telemetry.LAYER_COUNTS: counts}
         return (x, aux), telem
+
+
+def stack_name(kind: Tuple[str, str]) -> str:
+    """Parameter-tree key of the stacked layers of one (operator, ffn) kind."""
+    return "layers_" + "_".join(kind)
+
+
+def _publish_layer_stats(ys, key: str = "layers") -> None:
+    """What the layer loop stacked (``[layers, ...]`` leaves, or None): the
+    layers' counters go to the step's, reduced over the layers; the rest is
+    the telemetry capture's."""
+    if ys is None:
+        return
+    stats = dict(ys)
+    telemetry.count_all(telemetry.reduce_counts(
+        stats.pop(telemetry.LAYER_COUNTS, {})))
+    if stats:
+        telemetry.record(key, stats)
 
 
 @jax.custom_vjp
@@ -824,7 +916,8 @@ class GPT(nn.Module):
         ctx_mesh = ctx_lib.current_mesh()
         stage_n = ctx_mesh.shape.get("stage", 1) if ctx_mesh is not None else 1
         manual_apply = not decode and not self.is_initializing()
-        if manual_apply and (stage_n > 1 or cfg.scan_unroll):
+        if cfg.uniform_layers and manual_apply and (
+                stage_n > 1 or cfg.scan_unroll):
             # Shared setup for the two manual apply paths (pipeline and
             # unrolled): one detached block module, dropout-rng gating, and
             # optional remat wrapping.
@@ -851,7 +944,10 @@ class GPT(nn.Module):
             raise NotImplementedError(
                 "segment_ids are not supported under pipeline parallelism"
             )
-        if manual_apply and stage_n > 1:
+        if not cfg.uniform_layers:
+            x, moe_aux = self._mixed_layers(
+                carry0, train, decode, segment_ids, policies, ctx_mesh)
+        elif manual_apply and stage_n > 1:
             # Pipeline parallelism: the stacked layers (sharded over `stage`
             # by parallel/sharding.py) run through the GPipe schedule
             # (parallel/pipeline.py). Embedding / final norm / loss stay
@@ -910,7 +1006,7 @@ class GPT(nn.Module):
             x, moe_aux = carry
             if telems:
                 # Same [num_layers, ...] stacking nn.scan's ys would give.
-                telemetry.record("layers", jax.tree_util.tree_map(
+                _publish_layer_stats(jax.tree_util.tree_map(
                     lambda *xs: jnp.stack(xs), *telems
                 ))
         else:
@@ -932,10 +1028,9 @@ class GPT(nn.Module):
             (x, moe_aux), layer_telem = layers(
                 cfg, deterministic=not train, decode=decode, name="layers"
             )(carry0, segment_ids)
-            if layer_telem is not None:
-                telemetry.record("layers", layer_telem)
+            _publish_layer_stats(layer_telem)
 
-        x = RMSNorm(dtype=cfg.compute_dtype, name="norm")(x)
+        x = RMSNorm(eps=cfg.norm_eps, dtype=cfg.compute_dtype, name="norm")(x)
         if telemetry.capturing():
             telemetry.record("final_norm", {
                 "rms": telemetry.rms(x), "absmax": telemetry.absmax(x),
@@ -1001,6 +1096,100 @@ class GPT(nn.Module):
                     # router_z_weight * z-loss (models/moe.py).
                     loss = loss + moe_aux / cfg.num_layers
         return logits, loss
+
+    def _mixed_layers(self, carry, train, decode, segment_ids, policies,
+                      mesh):
+        """The layer loop of a model whose layers differ
+        (``GPTConfig.layer_kinds``): layers run in published order, the
+        parameters of each (operator, ffn) kind stacked under
+        ``layers_<operator>_<ffn>`` as ``nn.scan`` lays them out. Unrolled
+        (``scan_unroll``), each layer is straight-line code on a static
+        slice of its kind's stack, as in the uniform model; rolled, each run
+        of consecutive layers of one kind is one ``lax.scan`` over its
+        slice. Training and evaluation only."""
+        cfg = self.config
+        if decode:
+            raise NotImplementedError(
+                "decode is not supported for a model whose layers differ: "
+                "a conv layer needs the two previous positions of its gated "
+                "input as state, which no cache holds")
+        for axis in ("stage", ring.SEQ_AXIS):
+            if mesh is not None and mesh.shape.get(axis, 1) > 1:
+                raise NotImplementedError(
+                    f"a model whose layers differ does not run under a "
+                    f"{axis!r} mesh axis > 1: the pipeline and the ring "
+                    f"schedule one stacked block")
+        kinds = cfg.layer_kinds()
+        counts = {k: kinds.count(k) for k in dict.fromkeys(kinds)}
+        if self.is_initializing():
+            # Creates the stacks (one nn.scan a kind); the order the kinds
+            # run in does not matter to the parameters made.
+            for kind, n in counts.items():
+                carry, _ = nn.scan(
+                    TransformerBlock,
+                    variable_axes={"params": 0},
+                    split_rngs={"params": True, "dropout": True},
+                    length=n, in_axes=nn.broadcast,
+                )(cfg, deterministic=not train, kind=kind,
+                  name=stack_name(kind))(carry, segment_ids)
+            return carry
+        needs_rng = train and (cfg.dropout > 0.0 or cfg.attention_dropout > 0.0)
+        rng = self.make_rng("dropout") if needs_rng else None
+
+        def runner(kind):
+            block = TransformerBlock(cfg, deterministic=not train, kind=kind)
+
+            def run(p, carry, key):
+                rngs = {} if key is None else {"dropout": key}
+                return block.apply({"params": p}, carry, segment_ids,
+                                   rngs=rngs)
+
+            if cfg.gradient_checkpointing:
+                run = jax.checkpoint(run, prevent_cse=False,
+                                     policy=policies[cfg.remat_policy])
+            return run
+
+        run = {kind: runner(kind) for kind in counts}
+        stacks = {kind: self.variables["params"][stack_name(kind)]
+                  for kind in counts}
+        keys = (None if rng is None
+                else list(jax.random.split(rng, len(kinds))))
+        seen = dict.fromkeys(counts, 0)
+        ys = {kind: [] for kind in counts}
+        if cfg.scan_unroll:
+            per_layer = {k: _unstack_layers(v) for k, v in stacks.items()}
+            for i, kind in enumerate(kinds):
+                carry, y = run[kind](per_layer[kind][seen[kind]], carry,
+                                     None if keys is None else keys[i])
+                seen[kind] += 1
+                if y is not None:
+                    ys[kind].append(jax.tree_util.tree_map(
+                        lambda a: a[None], y))
+        else:
+            i = 0
+            while i < len(kinds):
+                kind, n = kinds[i], 1
+                while i + n < len(kinds) and kinds[i + n] == kind:
+                    n += 1
+                lo = seen[kind]
+                part = jax.tree_util.tree_map(
+                    lambda a: a[lo:lo + n], stacks[kind])
+                part_keys = (None if keys is None
+                             else jnp.stack(keys[i:i + n]))
+                carry, y = jax.lax.scan(
+                    lambda c, xs, kind=kind: run[kind](xs[0], c, xs[1]),
+                    carry, (part, part_keys))
+                seen[kind] += n
+                i += n
+                if y is not None:
+                    ys[kind].append(y)
+        for kind, parts in ys.items():
+            if parts:
+                _publish_layer_stats(
+                    jax.tree_util.tree_map(
+                        lambda *a: jnp.concatenate(a), *parts),
+                    key=stack_name(kind))
+        return carry
 
 
 def _masked_shifted_mean(ce: jax.Array, segment_ids) -> jax.Array:
@@ -1415,7 +1604,7 @@ def pipeline_1f1b_value_and_grad(model: "GPT", mesh, num_microbatches: int):
     with_aux = cfg.num_experts > 0
     needs_rng = cfg.dropout > 0.0 or cfg.attention_dropout > 0.0
     block_mod = TransformerBlock(cfg, deterministic=False)
-    norm_mod = RMSNorm(dtype=cfg.compute_dtype)
+    norm_mod = RMSNorm(eps=cfg.norm_eps, dtype=cfg.compute_dtype)
     policies = {
         "full": None,
         "dots": jax.checkpoint_policies.dots_saveable,

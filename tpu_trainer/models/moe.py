@@ -171,6 +171,57 @@ def _combine_rows_bwd(res, dout):
 _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
+# --- dropless row movement (custom_vjp) ----------------------------------------
+#
+# The sort is a PERMUTATION of the T*k token-choice rows, so the transpose of
+# each gather is a gather through the inverse permutation. AD does not know
+# that and emits row scatter-adds (see the measurements above: an order of
+# magnitude slower than the matching gathers on the chip).
+
+
+@jax.custom_vjp
+def _sorted_rows(x, perm, inv_perm, mine):
+    """Token rows into sorted order: ``x [T, H] -> [k*T, H]``, row ``r`` is
+    token ``perm[r] % T`` (token-choices are numbered CHOICE-MAJOR, ``j*T +
+    t``, so that ``[k*T, H] <-> [k, T, H]`` splits the leading dim: the
+    token-major ``[T, k, H]`` costs a relayout of the whole buffer on the
+    chip). ``mine [k, T]`` marks the choices whose expert lives here; the
+    gradient of the others' rows is left out (those rows are past every
+    group: what a grouped matmul returns for them is not a result)."""
+    return x[perm % x.shape[0]]
+
+
+def _sorted_rows_fwd(x, perm, inv_perm, mine):
+    return _sorted_rows(x, perm, inv_perm, mine), (inv_perm, mine)
+
+
+def _sorted_rows_bwd(res, g):
+    inv_perm, mine = res
+    rows = g[inv_perm].reshape(*mine.shape, g.shape[-1])        # [k, T, H]
+    dx = jnp.sum(jnp.where(mine[..., None], rows, 0), axis=0)
+    return dx, None, None, None
+
+
+_sorted_rows.defvjp(_sorted_rows_fwd, _sorted_rows_bwd)
+
+
+@jax.custom_vjp
+def _unsorted_rows(y, perm, inv_perm):
+    """Sorted rows back into token-choice order: ``y[inv_perm]``."""
+    return y[inv_perm]
+
+
+def _unsorted_rows_fwd(y, perm, inv_perm):
+    return y[inv_perm], perm
+
+
+def _unsorted_rows_bwd(perm, g):
+    return g[perm], None, None
+
+
+_unsorted_rows.defvjp(_unsorted_rows_fwd, _unsorted_rows_bwd)
+
+
 class MoEMLP(nn.Module):
     """Top-k routed expert SwiGLU (replaces ``MLP`` when experts are on)."""
 
@@ -185,30 +236,47 @@ class MoEMLP(nn.Module):
         k = cfg.moe_top_k
         b, s, H = x.shape
         T = b * s
-        I = cfg.intermediate_size
+        I = cfg.expert_width
+        held = cfg.experts_held[1]
 
         xt = x.reshape(T, H)
 
-        # Router in f32 (standard for stability).
-        router_logits = nn.Dense(
-            E, use_bias=False, dtype=jnp.float32, param_dtype=jnp.float32,
-            kernel_init=nn.initializers.normal(cfg.initializer_range),
-            name="router",
-        )(xt.astype(jnp.float32))
-        probs = jax.nn.softmax(router_logits, axis=-1)          # [T, E]
-        gate_vals, gate_idx = jax.lax.top_k(probs, k)           # [T, k]
+        with jax.named_scope("route"):
+            # Router in f32 (standard for stability).
+            router_logits = nn.Dense(
+                E, use_bias=False, dtype=jnp.float32, param_dtype=jnp.float32,
+                kernel_init=nn.initializers.normal(cfg.initializer_range),
+                name="router",
+            )(xt.astype(jnp.float32))
+            if cfg.moe_router == "sigmoid":
+                # Scores are independent sigmoids; the bias only SELECTS
+                # (a buffer: no gradient reaches it, and with no published
+                # update rule it stays at its zeros); the chosen scores are
+                # normalised over the choice.
+                scores = jax.nn.sigmoid(router_logits)
+                bias = jax.lax.stop_gradient(self.param(
+                    "expert_bias", nn.initializers.zeros, (E,), jnp.float32))
+                _, gate_idx = jax.lax.top_k(scores + bias, k)
+                gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
+                gates = gate_vals / (jnp.sum(gate_vals, axis=-1,
+                                             keepdims=True) + 1e-6)
+                probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+            else:
+                probs = jax.nn.softmax(router_logits, axis=-1)      # [T, E]
+                gate_vals, gate_idx = jax.lax.top_k(probs, k)       # [T, k]
+                # Gates: Switch keeps the raw router prob at k=1; at k>1
+                # the chosen probs renormalize to sum 1 (GShard/Mixtral).
+                gates = gate_vals if k == 1 else (
+                    gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+                )
         assign_k = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)  # [T,k,E]
-
-        # Gates: Switch keeps the raw router prob at k=1; at k>1 the chosen
-        # probs renormalize to sum 1 (GShard/Mixtral semantics).
-        gates = gate_vals if k == 1 else (
-            gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
-        )
 
         # Aux load-balance loss uses pre-capacity FIRST-choice fractions.
         frac = jnp.mean(assign_k[:, 0], axis=0)                 # [E]
         mean_prob = jnp.mean(probs, axis=0)                     # [E]
-        aux = cfg.moe_aux_weight * E * jnp.sum(frac * mean_prob)
+        aux = jnp.zeros((), jnp.float32)
+        if cfg.moe_aux_weight > 0.0:
+            aux = cfg.moe_aux_weight * E * jnp.sum(frac * mean_prob)
         if cfg.router_z_weight > 0.0:
             z = jax.nn.logsumexp(router_logits, axis=-1)        # [T]
             aux = aux + cfg.router_z_weight * jnp.mean(z * z)
@@ -222,9 +290,10 @@ class MoEMLP(nn.Module):
                 cfg.params_dtype,
             ).astype(dtype)
 
-        w_gate = ffn_param("experts_gate", (E, H, I))
-        w_up = ffn_param("experts_up", (E, H, I))
-        w_down = ffn_param("experts_down", (E, I, H))
+        # Only the experts held here have weights here.
+        w_gate = ffn_param("experts_gate", (held, H, I))
+        w_up = ffn_param("experts_up", (held, H, I))
+        w_down = ffn_param("experts_down", (held, I, H))
         act = {"silu": nn.silu, "gelu": nn.gelu}[cfg.activation]
 
         if cfg.moe_impl == "dropless":
@@ -354,9 +423,18 @@ class MoEMLP(nn.Module):
         function of the routing — exact-resume replays it bit-identically);
         ``bincount`` gives the true per-expert group sizes. Each SwiGLU
         projection is one ``gmm`` whose compute is exactly
-        ``sum(counts) = k*T`` rows — no capacity padding, no drops. The
+        ``sum(counts)`` rows — no capacity padding, no drops. The
         inverse permutation is a second argsort (of the first), and the
         gates weight the per-choice rows back into token order.
+
+        With a share of the experts held (``GPTConfig.moe_experts_held``)
+        the choices of experts that live elsewhere sort behind the held
+        experts' rows: the ``[k*T, H]`` buffers keep their worst-case size
+        (every choice of every token may be a held expert, so no row that
+        chose one is ever dropped, whatever the imbalance), ``sum(counts)``
+        is the rows held, the kernels' schedule skips the rest, and the
+        combine leaves the other choices out. What the absent experts would
+        add is left out: the result is this chip's part of the layer's sum.
 
         Mesh composition: on a multi-device mesh the jnp twin runs
         (``use_kernel=False``) so GSPMD partitions the ragged dot like any
@@ -365,29 +443,55 @@ class MoEMLP(nn.Module):
         follow-up (ROADMAP item 4).
         """
         cfg = self.config
-        E = cfg.num_experts
         k = cfg.moe_top_k
         T = xt.shape[0]
         dtype = cfg.compute_dtype
-
-        flat_expert = gate_idx.astype(jnp.int32).reshape(-1)    # [T*k]
-        counts = jnp.bincount(flat_expert, length=E)            # [E]
-        perm = jnp.argsort(flat_expert)                         # stable
-        inv_perm = jnp.argsort(perm)
+        first, held = cfg.experts_held
+        subset = held < cfg.num_experts
 
         from tpu_trainer.parallel import context as ctx_lib
 
         mesh = ctx_lib.current_mesh()
+        if subset and mesh is not None and mesh.shape.get("expert", 1) > 1:
+            raise NotImplementedError(
+                "moe_experts_held names this chip's share of the experts; "
+                "an 'expert' mesh axis > 1 would share them a second time")
         use_kernel = False if (mesh is not None and mesh.size > 1) else None
+
+        with jax.named_scope("route"):
+            local = gate_idx.astype(jnp.int32) - first
+            # A choice of an expert that lives elsewhere goes to a trailing
+            # group that is nobody's: its rows sort behind the held
+            # experts' rows, no grouped matmul visits them, and the
+            # combine leaves them out.
+            mine = ((local >= 0) & (local < held)).T            # [k, T]
+            flat_expert = (jnp.where(mine, local.T, held) if subset
+                           else local.T).reshape(-1)            # [k*T]
+            counts = jnp.bincount(flat_expert, length=held + 1)[:held]
+            perm = jnp.argsort(flat_expert)                     # stable
+            inv_perm = jnp.argsort(perm)
+            grouped_in = _sorted_rows(
+                xt.astype(dtype), perm, inv_perm, mine)         # [k*T, H]
 
         def grouped(lhs, w):
             return gmm(lhs, w, counts, use_kernel=use_kernel)
 
-        grouped_in = xt.astype(dtype)[perm // k]                # [T*k, H]
-        mid = act(grouped(grouped_in, w_gate)) * grouped(grouped_in, w_up)
-        grouped_out = grouped(mid, w_down)                      # [T*k, H]
-        rows = grouped_out[inv_perm].reshape(T, k, -1)
-        out = jnp.sum(rows * gates[..., None].astype(dtype), axis=1)
+        with jax.named_scope("experts"):
+            mid = act(grouped(grouped_in, w_gate)) * grouped(grouped_in, w_up)
+            grouped_out = grouped(mid, w_down)                  # [k*T, H]
+        with jax.named_scope("route"):
+            rows = _unsorted_rows(
+                grouped_out, perm, inv_perm).reshape(k, T, -1)
+            weighted = rows * gates.T[..., None].astype(dtype)
+            if subset:
+                weighted = jnp.where(mine[..., None], weighted, 0)
+            out = jnp.sum(weighted, axis=0)
+
+        # Rows the experts here computed, and the busiest one's share.
+        rows_held = jnp.sum(counts).astype(jnp.float32)
+        telemetry.count("moe_rows_held", rows_held)
+        telemetry.count("moe_max_load", jnp.max(counts).astype(jnp.float32)
+                        / jnp.maximum(rows_held, 1.0), reduce="max")
 
         if telemetry.capturing():
             # True post-routing load (the bincount — what each expert
@@ -397,11 +501,17 @@ class MoEMLP(nn.Module):
             # is structurally zero — the analyzer FAILs a dropless run
             # that ever reports otherwise.
             load = counts.astype(jnp.float32) / float(k * T)
-            telemetry.record("router", {
+            router = {
                 "load": load,
                 "entropy": entropy,
                 "drop_frac": jnp.zeros((), jnp.float32),
                 "max_group_frac": jnp.max(load),
                 "dropless": jnp.ones((), jnp.float32),
-            })
+            }
+            if telemetry.capturing(deep=True):
+                # Which experts each token chose, for whoever compares the
+                # routing with a reference's (never a periodic telemetry
+                # step: [T, k] integers a layer).
+                router["choice"] = gate_idx.astype(jnp.int32)
+            telemetry.record("router", router)
         return out

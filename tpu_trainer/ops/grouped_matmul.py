@@ -5,9 +5,9 @@ each contiguous row-group of ``lhs`` by its own expert weight block:
 rows ``[offsets[e], offsets[e+1])`` (``offsets = cumsum(group_sizes)``)
 hit ``rhs[e]``. This is the MegaBlocks grouped-GEMM primitive (arXiv:
 2211.15841): expert FFN compute scales with the tokens actually routed
-(``sum(group_sizes) == G``), not with a capacity-padded ``[E, C]``
-buffer, so no token is ever dropped and no expert pays for an empty
-queue.
+(``sum(group_sizes) <= G``: rows past the sum are nobody's), not with a
+capacity-padded ``[E, C]`` buffer, so no token is ever dropped and no
+expert pays for an empty queue.
 
 Kernel shape (same dispatch contract as ``ops/flash.py`` — compiled on
 TPU, the ``jnp`` reference twin off-TPU, ``interpret=True`` under
@@ -76,7 +76,16 @@ class _GmmOpts(NamedTuple):
     tile_cols: int
 
 
-def _resolve_opts(use_kernel, interpret, tile_tokens, tile_cols) -> _GmmOpts:
+def _resolve_opts(use_kernel, interpret, tile_tokens, tile_cols,
+                  rows: int, cols: int) -> _GmmOpts:
+    # Tiles from the shapes, where the caller names none: every grid step
+    # costs its overhead whether it is live or padding, and the static step
+    # bound grows with rows / tile_tokens, so a large buffer takes large
+    # tiles (a [tile, H] x [H, tile] step also reads less per FLOP).
+    if tile_tokens is None:
+        tile_tokens = 512 if rows >= 4096 else 128
+    if tile_cols is None:
+        tile_cols = 512 if cols % 512 == 0 else 128
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if use_kernel is None:
@@ -223,26 +232,38 @@ def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
 
 # --- kernels ----------------------------------------------------------------
 
-def _gmm_kernel(tiles, gids, lives, offs, lhs_ref, rhs_ref, out_ref, *,
-                tile_tokens):
+def _gmm_kernel(tiles, gids, lives, offs, lhs_ref, rhs_ref, out_ref, acc_ref,
+                *, tile_tokens):
     s = pl.program_id(1)
     g = gids[s]
     rows = (tiles[s] * tile_tokens
             + jax.lax.broadcasted_iota(jnp.int32, (tile_tokens, 1), 0))
     mask = ((rows >= offs[g]) & (rows < offs[g + 1])
             & (lives[s] > 0))
-    x = jnp.where(mask, lhs_ref[...], jnp.zeros_like(lhs_ref[...]))
-    contrib = jnp.dot(x, rhs_ref[0],
-                      preferred_element_type=jnp.float32)
     first = jnp.logical_or(s == 0, tiles[s] != tiles[jnp.maximum(s - 1, 0)])
 
-    @pl.when(first)
-    def _init():
-        out_ref[...] = contrib
+    # A padding step computes nothing: with ``sum(group_sizes) < G`` most of
+    # the static step bound is padding, and compute follows the rows that
+    # belong to a group. The tiles no live step visits are never written;
+    # the wrapper zeroes their rows.
+    @pl.when(lives[s] > 0)
+    def _live():
+        x = jnp.where(mask, lhs_ref[...], jnp.zeros_like(lhs_ref[...]))
+        contrib = jnp.dot(x, rhs_ref[0],
+                          preferred_element_type=jnp.float32)
 
-    @pl.when(jnp.logical_not(first))
-    def _acc():
-        out_ref[...] = out_ref[...] + contrib
+        # f32 accumulation across the visits of one tile in scratch; the
+        # result leaves in the operands' type (no f32 [G, N] round trip
+        # through HBM and no cast pass outside the kernel).
+        @pl.when(first)
+        def _init():
+            acc_ref[...] = contrib
+
+        @pl.when(jnp.logical_not(first))
+        def _acc():
+            acc_ref[...] = acc_ref[...] + contrib
+
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
 
 def _tgmm_kernel(tiles, gids, lives, offs, lhs_ref, dout_ref, out_ref, *,
@@ -253,20 +274,32 @@ def _tgmm_kernel(tiles, gids, lives, offs, lhs_ref, dout_ref, out_ref, *,
             + jax.lax.broadcasted_iota(jnp.int32, (tile_tokens, 1), 0))
     mask = ((rows >= offs[g]) & (rows < offs[g + 1])
             & (lives[s] > 0))
-    x = jnp.where(mask, lhs_ref[...], jnp.zeros_like(lhs_ref[...]))
-    # Contract the token dim: [tile, H]^T @ [tile, N] -> [H, N].
-    contrib = jax.lax.dot_general(
-        x, dout_ref[...], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
     first = jnp.logical_or(s == 0, g != gids[jnp.maximum(s - 1, 0)])
 
-    @pl.when(first)
-    def _init():
-        out_ref[0] = contrib
+    @pl.when(lives[s] > 0)      # padding steps compute nothing (see gmm)
+    def _live():
+        x = jnp.where(mask, lhs_ref[...], jnp.zeros_like(lhs_ref[...]))
+        # Contract the token dim: [tile, H]^T @ [tile, N] -> [H, N].
+        contrib = jax.lax.dot_general(
+            x, dout_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(jnp.logical_not(first))
-    def _acc():
-        out_ref[0] = out_ref[0] + contrib
+        @pl.when(first)
+        def _init():
+            out_ref[0] = contrib
+
+        @pl.when(jnp.logical_not(first))
+        def _acc():
+            out_ref[0] = out_ref[0] + contrib
+
+
+# Column blocks are independent; the steps of one column block accumulate in
+# order. 512-wide tiles of a [tile, 2048] x [2048, tile] step, double
+# buffered, pass the 16 MiB default scope (the wgrad's f32 [2048, 512] result
+# block: 17.9 MiB); the chip has 128 MiB.
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"),
+    vmem_limit_bytes=48 * 1024 * 1024) if _PALLAS_OK else None
 
 
 def _gmm_pallas(lhs, rhs, group_sizes, opts: _GmmOpts):
@@ -296,11 +329,17 @@ def _gmm_pallas(lhs, rhs, group_sizes, opts: _GmmOpts):
             out_specs=pl.BlockSpec((tm, tn),
                                    lambda n, s, tiles, gids, lives, offs:
                                    (tiles[s], n)),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((Gp, Np), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((Gp, Np), lhs.dtype),
+        compiler_params=_COMPILER_PARAMS,
         interpret=opts.interpret,
     )(tiles, gids, lives, offs, lhs_p, rhs_p)
-    return out[:G, :N].astype(lhs.dtype)
+    # Rows past sum(group_sizes) belong to no group: tiles wholly past it
+    # were never visited, so what the buffer holds there is not a result.
+    out = jnp.where(jnp.arange(Gp)[:, None] < offs[-1], out,
+                    jnp.zeros((), out.dtype))
+    return out[:G, :N]
 
 
 def _tgmm_pallas(lhs, dout, group_sizes, opts: _GmmOpts):
@@ -333,6 +372,7 @@ def _tgmm_pallas(lhs, dout, group_sizes, opts: _GmmOpts):
                                    (gids[s], 0, n)),
         ),
         out_shape=jax.ShapeDtypeStruct((E, Hp, Np), jnp.float32),
+        compiler_params=_COMPILER_PARAMS,
         interpret=opts.interpret,
     )(tiles, gids, lives, offs, lhs_p, dout_p)
     # Empty groups own no grid step, so their output blocks are never
@@ -381,14 +421,18 @@ _gmm.defvjp(_gmm_fwd, _gmm_bwd)
 def gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
         use_kernel: Optional[bool] = None,
         interpret: Optional[bool] = None,
-        tile_tokens: int = 128, tile_cols: int = 128) -> jax.Array:
+        tile_tokens: Optional[int] = None,
+        tile_cols: Optional[int] = None) -> jax.Array:
     """Grouped matmul: row-groups of ``lhs`` times per-group weights.
 
     - ``lhs``: ``[G, H]`` rows SORTED by group (group e's rows are the
       contiguous slice ``[offsets[e], offsets[e+1])``).
     - ``rhs``: ``[E, H, N]`` stacked per-group weight blocks.
-    - ``group_sizes``: ``[E]`` int, ``sum == G`` (enforced only by the
-      caller — trailing rows past the sum produce zeros).
+    - ``group_sizes``: ``[E]`` int, ``sum <= G``. Rows past the sum
+      belong to no group (an expert layer that holds a share of the experts
+      sorts the other choices there): they produce zeros, get a zero
+      gradient, and cost no matmul — the kernel's padding steps skip their
+      compute and its schedule never visits a tile wholly past the sum.
 
     Returns ``[G, N]`` in ``lhs.dtype`` (f32 accumulation either path).
     Differentiable via ``custom_vjp``: d(lhs) is a ``gmm`` against
@@ -398,7 +442,8 @@ def gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
     reference twin elsewhere); ``interpret=True`` runs the kernel
     under the Pallas interpreter (the CPU test path).
     """
-    opts = _resolve_opts(use_kernel, interpret, tile_tokens, tile_cols)
+    opts = _resolve_opts(use_kernel, interpret, tile_tokens, tile_cols,
+                         lhs.shape[0], rhs.shape[2])
     if lhs.shape[0] == 0:
         return jnp.zeros((0, rhs.shape[2]), lhs.dtype)
     return _gmm(opts, lhs, rhs, group_sizes)
@@ -407,11 +452,13 @@ def gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
 def tgmm(lhs: jax.Array, dout: jax.Array, group_sizes: jax.Array, *,
          use_kernel: Optional[bool] = None,
          interpret: Optional[bool] = None,
-         tile_tokens: int = 128, tile_cols: int = 128) -> jax.Array:
+         tile_tokens: Optional[int] = None,
+         tile_cols: Optional[int] = None) -> jax.Array:
     """Transposed grouped matmul (the wgrad): per-group
     ``lhs[slice]^T @ dout[slice]`` stacked to ``[E, H, N]`` f32.
     """
-    opts = _resolve_opts(use_kernel, interpret, tile_tokens, tile_cols)
+    opts = _resolve_opts(use_kernel, interpret, tile_tokens, tile_cols,
+                         lhs.shape[0], dout.shape[1])
     if lhs.shape[0] == 0:
         return jnp.zeros(
             (group_sizes.shape[0], lhs.shape[1], dout.shape[1]),

@@ -120,6 +120,12 @@ class ServingEngine:
         kv_link_gbps: float = 16.0,
         role: Optional[str] = None,
     ):
+        if not config.uniform_layers:
+            raise NotImplementedError(
+                "the serving engine cannot run a model whose layers differ "
+                "(GPTConfig.layer_types / num_dense_layers): a conv layer "
+                "needs the two previous positions of its gated input as "
+                "state, and the cache managers hold K/V blocks only")
         if spec not in ("off", "ngram", "draft"):
             raise ValueError(f"spec={spec!r} (off | ngram | draft)")
         if max_blocks_per_request is None:

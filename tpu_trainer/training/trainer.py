@@ -274,6 +274,19 @@ class Trainer:
                     f"num_experts {self.model_config.num_experts} not "
                     f"divisible by expert axis size {self.ep_size}"
                 )
+            if self.model_config.moe_experts_held is not None:
+                raise ValueError(
+                    "moe_experts_held names this chip's share of the "
+                    "experts; it does not compose with an expert mesh "
+                    f"axis of {self.ep_size}")
+        if not self.model_config.uniform_layers:
+            for axis in (mesh_lib.STAGE_AXIS, mesh_lib.SEQUENCE_AXIS):
+                if self.mesh.shape.get(axis, 1) > 1:
+                    raise ValueError(
+                        f"a model whose layers differ (layer_types / "
+                        f"num_dense_layers) does not run under a {axis!r} "
+                        f"mesh axis > 1: the pipeline and the ring "
+                        f"schedule one stacked block")
         self.tp_size = self.mesh.shape[mesh_lib.TENSOR_AXIS]
         if self.tp_size > 1:
             if self.model_config.num_heads % self.tp_size != 0:
@@ -650,11 +663,15 @@ class Trainer:
     def _cast_params(self, params):
         """Compute-dtype copy of the >=2-D param leaves (exactly the cast
         the modules apply: Dense/Embed promote their matrices to the
-        module dtype; 1-D leaves — RMSNorm weights — stay f32)."""
+        module dtype; 1-D leaves — RMSNorm weights — stay f32, and so does
+        an expert layer's router, which its module computes in f32: with
+        its kernel rounded, tokens near a tie chose other experts in the
+        step than the same parameters choose anywhere else)."""
         cd = self.model_config.compute_dtype
-        return jax.tree_util.tree_map(
-            lambda p: p.astype(cd) if p.ndim >= 2 else p, params
-        )
+        return jax.tree_util.tree_map_with_path(
+            lambda path, p: p.astype(cd)
+            if p.ndim >= 2 and "router" not in _path_keys(path) else p,
+            params)
 
     def _apply_params(self, state: TrainState):
         """The param tree the model forward should consume: the carried
@@ -981,7 +998,9 @@ class Trainer:
             # trace (telemetry_on=False) is byte-identical to before.
             cap_cm = (telemetry.capture() if telemetry_on
                       else contextlib.nullcontext())
-            with cap_cm as cap:
+            # The model's step counters (the expert layers' rows and load;
+            # empty for a model that counts nothing) ride the aux too.
+            with cap_cm as cap, telemetry.counters() as counts:
                 with self._sp_context():
                     _, loss = self.model.apply(
                         {"params": params},
@@ -992,8 +1011,9 @@ class Trainer:
                         segment_ids=segs,
                     )
             if telemetry_on:
-                return loss * scale, (loss, telemetry.assemble(cap.stats))
-            return loss * scale, loss
+                return loss * scale, (loss, counts,
+                                      telemetry.assemble(cap.stats))
+            return loss * scale, (loss, counts)
 
         if (self.stage_size > 1
                 and self.model_config.pipeline_schedule in (
@@ -1016,11 +1036,10 @@ class Trainer:
                 # batch replication, the memory cliff 1F1B exists to avoid.
                 with self._sp_context():
                     (scaled, loss_v), g = _raw_1f1b(p, micro, rng_, scale_)
-                if telemetry_on:
-                    # 1f1b bypasses normal AD — no forward capture here;
-                    # grad/param/update norms below still apply.
-                    return (scaled, (loss_v, {})), g
-                return (scaled, loss_v), g
+                # 1f1b bypasses normal AD — no forward capture here;
+                # grad/param/update norms below still apply.
+                aux = (loss_v, {}, {}) if telemetry_on else (loss_v, {})
+                return (scaled, aux), g
         else:
             grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
@@ -1031,10 +1050,9 @@ class Trainer:
             (_, aux), grads = grad_fn(
                 state.params, batch[0], sub, state.loss_scale
             )
+            loss_sum, counts = aux[:2]
             if telemetry_on:
-                loss_sum, fwd_stats = aux
-            else:
-                loss_sum = aux
+                fwd_stats = aux[2]
         else:
             with jax.named_scope("grad_accum"):
                 zero_grads = jax.tree_util.tree_map(
@@ -1045,19 +1063,18 @@ class Trainer:
                 grads_acc, loss_acc, rng = carry
                 rng, sub = jax.random.split(rng)
                 (_, aux), grads = grad_fn(state.params, micro, sub, state.loss_scale)
-                loss = aux[0] if telemetry_on else aux
                 with jax.named_scope("grad_accum"):
                     grads_acc = jax.tree_util.tree_map(
                         jnp.add, grads_acc, grads)
-                ys = aux[1] if telemetry_on else None
-                return (grads_acc, loss_acc + loss, rng), ys
+                return (grads_acc, loss_acc + aux[0], rng), aux[1:]
 
-            (grads, loss_sum, new_rng), fwd_stack = jax.lax.scan(
+            (grads, loss_sum, new_rng), stacked = jax.lax.scan(
                 micro_step, (zero_grads, jnp.zeros((), jnp.float32), state.rng), batch
             )
+            counts = telemetry.reduce_counts(stacked[0])
             if telemetry_on:
                 # [accum, ...]-stacked forward stats → mean (max for absmax).
-                fwd_stats = telemetry.reduce_micro(fwd_stack)
+                fwd_stats = telemetry.reduce_micro(stacked[1])
         # Mean over micro-steps and undo the loss scale; then pin the grads to
         # their ZeRO sharding (the reduce-scatter point under zero2/zero3).
         with jax.named_scope("grad_finalize"):
@@ -1115,6 +1132,7 @@ class Trainer:
             "lr": lr,
             "grad_norm": grad_norm,
             "loss_scale": state.loss_scale,
+            **telemetry.flat_counts(counts),
         }
         if telemetry_on:
             telem = dict(fwd_stats or {})

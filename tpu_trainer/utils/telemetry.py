@@ -111,6 +111,68 @@ def pop(name: str):
     return None
 
 
+# --- step counters ------------------------------------------------------------
+#
+# The same trace-time hand-off for the few device scalars the model counts in
+# EVERY step (the expert layers' rows and load): ``Trainer._train_step`` opens
+# ``counters()`` round ``model.apply`` and puts what was counted into the
+# step's metrics. A model that counts nothing leaves the dict empty and the
+# compiled step as it was.
+
+_COUNTS: List[Dict[str, Dict[str, object]]] = []
+# Key of a layer's own counters among what the layer loop stacks.
+LAYER_COUNTS = "layer_counts"
+_FOLD = {"sum": jnp.add, "max": jnp.maximum}
+_OVER = {"sum": jnp.sum, "max": jnp.max}
+
+
+@contextlib.contextmanager
+def counters():
+    """Open a set of counters, ``{kind: {name: value}}``: how a counter
+    folds (``"sum"`` or ``"max"``) is said where it is counted and travels
+    as the tree's own key, through ``lax.scan`` as anything else does."""
+    counts: Dict[str, Dict[str, object]] = {}
+    _COUNTS.append(counts)
+    try:
+        yield counts
+    finally:
+        _COUNTS.pop()
+
+
+def counting() -> bool:
+    return bool(_COUNTS)
+
+
+def count(name: str, value, reduce: str = "sum") -> None:
+    """Fold ``value`` into the open counter ``name`` (or set it). A no-op
+    outside ``counters()``."""
+    if not _COUNTS:
+        return
+    kind = _COUNTS[-1].setdefault(reduce, {})
+    kind[name] = _FOLD[reduce](kind[name], value) if name in kind else value
+
+
+def count_all(counts: Dict[str, Dict[str, object]]) -> None:
+    """Fold a whole set (an inner ``counters()``'s, reduced) into the open
+    one."""
+    for reduce, kind in counts.items():
+        for name, value in kind.items():
+            count(name, value, reduce)
+
+
+def reduce_counts(stacked):
+    """Counters stacked over a leading axis (the layers of a stack, the
+    micro-batches of a step), each reduced by its kind."""
+    return {reduce: {name: _OVER[reduce](v, axis=0)
+                     for name, v in kind.items()}
+            for reduce, kind in stacked.items()}
+
+
+def flat_counts(counts) -> Dict[str, object]:
+    """``{name: value}`` of a set of counters, for the step's metrics."""
+    return {name: v for kind in counts.values() for name, v in kind.items()}
+
+
 # --- on-device stat helpers --------------------------------------------------
 
 
@@ -151,13 +213,15 @@ def group_norms(tree, stacked_key: str = "layers") -> Dict[str, jax.Array]:
     """
     out: Dict[str, jax.Array] = {}
     for key in tree:
-        if key == stacked_key:
+        if key.startswith(stacked_key):
+            # "layers", or one stack a layer kind ("layers_conv_moe" ->
+            # "per_layer_conv_moe") where a model's layers differ.
             per = None
             for leaf in jax.tree_util.tree_leaves(tree[key]):
                 s = _sq_tail(leaf)
                 per = s if per is None else per + s
             if per is not None:
-                out["per_layer"] = jnp.sqrt(per)
+                out["per_layer" + key[len(stacked_key):]] = jnp.sqrt(per)
         else:
             out[key] = _tree_norm(tree[key])
     return out
@@ -184,13 +248,22 @@ def assemble(stats: Dict[str, object]) -> Dict[str, dict]:
         if d:
             for k, v in d.items():
                 act[f"{site}_{k}"] = v
-    layers = stats.get("layers")
-    if layers:
+    for key, layers in stats.items():
+        if not key.startswith("layers") or not layers:
+            continue
+        # One stack a layer kind where layers differ: "layers_conv_moe"
+        # lands under act["conv_moe"] / router["conv_moe"].
+        kind = key[len("layers_"):]
+        to_act = act.setdefault(kind, {}) if kind else act
+        to_router = router.setdefault(kind, {}) if kind else router
         for k, v in layers.items():
             if k.startswith("router_"):
-                router[k[len("router_"):]] = v
+                to_router[k[len("router_"):]] = v
             else:
-                act[k] = v
+                to_act[k] = v
+    act = {k: v for k, v in act.items() if not (isinstance(v, dict) and not v)}
+    router = {k: v for k, v in router.items()
+              if not (isinstance(v, dict) and not v)}
     out: Dict[str, dict] = {}
     if act:
         out["act"] = act
