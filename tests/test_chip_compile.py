@@ -155,6 +155,47 @@ def test_grouped_matmul_with_a_share_of_the_experts_held(chip):
     assert text.count('custom_call_target="tpu_custom_call"') == 5
 
 
+def test_expert_layer_with_row_buffers_sized_by_the_share_held(chip):
+    """The LFM2 cell's expert layer, forward and gradient, for the chip:
+    16,384 tokens x 4 choices with 8 of 64 experts held, so row buffers of
+    16,384 rows. The Pallas grouped matmuls are the bounded path's alone, as
+    many as the worst case has (3 forward, 6 backward); the overflow, under
+    ``lax.cond`` in both passes, is the compiler's own ragged dots."""
+    from perf import registry
+    from perf.families import lfm2_moe as family
+    from tpu_trainer.models import moe
+
+    cfg = family.gpt_config(
+        registry.workload("train-lfm2-24b-ep8-1chip")["config_file"])
+    assert moe._receive_rows(4 * 16384, *cfg.experts_held[1:],
+                             cfg.num_experts) == 16384
+    layer = moe.MoEMLP(cfg)
+    h = chip((4, 4096, cfg.hidden_size), jnp.bfloat16)
+    params = jax.tree_util.tree_map(
+        lambda s: chip(s.shape, s.dtype),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), h)["params"])
+
+    def loss(params, h):
+        out, aux = layer.apply({"params": params}, h)
+        return out.astype(jnp.float32).sum() + aux
+
+    patch = pytest.MonkeyPatch()
+    # The kernel dispatch asks for the backend; the test steers that.
+    patch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        text = _compile(
+            jax.value_and_grad(loss, argnums=(0, 1)), params, h)
+    finally:
+        patch.undo()
+    kernels = [name for name in re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*?op_name="([^"]*)"', text)
+        if "pallas_call" in name]
+    assert len(kernels) == 9 and all("branch_1_fun" in k for k in kernels)
+    assert len(re.findall(r" conditional\(", text)) == 2
+    # No buffer of 4 x tokens rows of activations is left, on either side.
+    assert not re.search(r"(bf16|f32)\[(65536|4,16384),\d+\]", text)
+
+
 # --- serving kernel --------------------------------------------------------
 
 # (heads, kv_heads, head_dim): GPT-2-small serving geometry, then the d=128

@@ -66,6 +66,18 @@ def _kernel_gmm(lhs, rhs, sizes, use_kernel=None):
     return gmm(lhs, rhs, sizes, use_kernel=True, interpret=True)
 
 
+def _kernel_tgmm(lhs, dout, sizes, use_kernel=None):
+    return tgmm(lhs, dout, sizes, use_kernel=True, interpret=True)
+
+
+def _through_the_kernels(monkeypatch):
+    """The expert layer's grouped matmuls (``gmm`` both ways, and the
+    ``tgmm`` its bounded backward calls itself) through the Pallas kernels
+    under the interpreter."""
+    monkeypatch.setattr(moe, "gmm", _kernel_gmm)
+    monkeypatch.setattr(moe, "tgmm", _kernel_tgmm)
+
+
 # f32: only the order of additions differs. bf16: the program rounds every
 # activation to 8 bits of mantissa where the reference keeps 24, and a
 # choice of expert flips near a tie; measured here over four seeds 0.052-0.076
@@ -93,7 +105,7 @@ def test_program_matches_the_reference(tokens, dtype, unroll, kernel,
                                        monkeypatch):
     """Logits, loss and the gradient of every parameter leaf."""
     if kernel:
-        monkeypatch.setattr(moe, "gmm", _kernel_gmm)
+        _through_the_kernels(monkeypatch)
     model, params = init(TINY, dtype, scan_unroll=unroll, fused_loss=False)
     tol = TOL[dtype]
 
@@ -230,6 +242,130 @@ def test_the_shares_summed_at_each_layer_give_the_uncut_loss(tokens):
     # And a single share's loss is another number: the cut shows.
     assert abs(float(family.loss(_share(params, 4, 4), tokens, TINY, 2))
                - float(want)) > 1e-3
+
+
+# --- row buffers sized by the share held, and the exact path past them ---
+
+# One expert layer of TINY: 96 tokens x 4 choices = 384 rows, 4 of 16 experts
+# held (ids 4-7), so the sorted buffers take R = 256 rows (twice the even
+# share of 96, in row tiles of 128). Each load names, for groups of tokens,
+# the four experts they choose; ``None`` leaves the routing to the seeded
+# weights (an even load).
+_LOADS = {
+    "even": (None, None, 0),
+    # 64 tokens choose the four held experts, 32 none: sum(counts) == R.
+    "exactly_R": ([(64, (4, 5, 6, 7)), (32, (0, 1, 2, 3))], 256, 0),
+    # Every choice of every token falls on a held expert: 384 > R.
+    "overflow": ([(96, (4, 5, 6, 7))], 384, 1),
+    # Expert 5 takes 96 of the 144 held rows, expert 7 none.
+    "ragged": ([(72, (5, 0, 1, 2)), (24, (5, 4, 6, 3))], 144, 0),
+}
+
+
+def _layer_with_a_load(groups):
+    """``(layer, params, h)``: the expert layer of TINY in float32 and an
+    input whose first 16 features are the router's logits (its kernel is the
+    identity on them), so that ``groups`` decides every token's choice."""
+    cfg = family.gpt_config(TINY, dtype="float32")
+    layer = moe.MoEMLP(cfg)
+    h = jax.random.normal(jax.random.PRNGKey(3), (2, 48, 64))
+    params = layer.init(jax.random.PRNGKey(0), h)["params"]
+    params = {k: v * 6.0 if k.startswith("experts_") else v
+              for k, v in params.items()}
+    if groups is None:
+        return layer, dict(params, router={
+            "kernel": params["router"]["kernel"] * 30.0}), h
+    logits = []
+    for tokens, chosen in groups:
+        row = -1.0 + 0.02 * np.arange(16)
+        row[list(chosen)] = 1.0 + 0.05 * np.arange(4)
+        logits.append(np.tile(row, (tokens, 1)))
+    logits = np.random.default_rng(0).permutation(np.concatenate(logits))
+    h = h.at[..., :16].set(jnp.asarray(
+        logits, jnp.float32).reshape(2, 48, 16))
+    return layer, dict(params, router={"kernel": jnp.eye(64, 16)}), h
+
+
+def _layer_outputs(layer, params, h):
+    """Output, its gradients by ``x``, the router's kernel and the three
+    expert leaves, and the layer's step counters."""
+    from tpu_trainer.utils import telemetry
+
+    weights = jnp.cos(jnp.arange(h.size, dtype=jnp.float32)).reshape(h.shape)
+
+    def scalar(p, h):
+        out, _ = layer.apply({"params": p}, h)
+        return jnp.sum(out * weights), out
+
+    @jax.jit
+    def run(p, h):
+        with telemetry.counters() as counts:
+            (_, out), (dp, dh) = jax.value_and_grad(
+                scalar, argnums=(0, 1), has_aux=True)(p, h)
+        return out, dh, dp, telemetry.flat_counts(counts)
+
+    with jax.default_matmul_precision("highest"):
+        out, dh, dp, counts = run(params, h)
+    leaves = {"out": out, "x": dh, "router": dp["router"]["kernel"],
+              **{k: dp[k] for k in ("experts_gate", "experts_up",
+                                    "experts_down")}}
+    return leaves, {k: float(v) for k, v in counts.items()}
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["twin", "gmm"])
+@pytest.mark.parametrize("load", list(_LOADS))
+def test_the_bounded_buffers_give_the_worst_case_result(load, kernel,
+                                                        monkeypatch):
+    """Output and every gradient of the layer with ``[R, .]`` row buffers
+    against the same layer with all ``k*T`` rows (the formulation a layer
+    that holds every expert keeps): equal whatever the load. Past ``R``
+    rows the pass is counted and goes through all of them a chunk at a
+    time, the grouped matmuls as ``lax.ragged_dot`` (six chunks of 64)."""
+    groups, rows_held, overflowed = _LOADS[load]
+    if kernel:
+        _through_the_kernels(monkeypatch)
+    layer, params, h = _layer_with_a_load(groups)
+    assert moe._receive_rows(4 * 96, 4, 16) == 256
+    got, counts = _layer_outputs(layer, params, h)
+    with monkeypatch.context() as worst:
+        worst.setattr(moe, "_receive_rows", lambda choices, held, e: choices)
+        want, want_counts = _layer_outputs(layer, params, h)
+    assert "moe_overflow_passes" not in want_counts
+    assert counts["moe_overflow_passes"] == overflowed
+    assert counts["moe_rows_held"] == want_counts["moe_rows_held"]
+    if rows_held is None:
+        assert 48 < counts["moe_rows_held"] < 192       # near 96, under R
+    else:
+        assert counts["moe_rows_held"] == rows_held
+    if load == "ragged":
+        assert abs(counts["moe_max_load"] - 96 / 144) < 1e-6
+    for name, value in want.items():
+        scale = float(jnp.max(jnp.abs(value)))
+        assert scale > 1e-4, name
+        assert float(jnp.max(jnp.abs(got[name] - value))) <= 1e-6 * scale, name
+
+
+def test_only_a_layer_that_holds_a_share_traces_the_cond():
+    """All experts held (every uniform MoE model): ``R == k*T``, no ``cond``
+    is traced and the layer's program is the one it was. A share under half:
+    one ``cond`` forward, one in the backward."""
+    h = jnp.zeros((2, 48, 64))
+
+    def program(cfg_file):
+        layer = moe.MoEMLP(family.gpt_config(cfg_file, dtype="float32"))
+        params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), h)["params"]
+
+        def scalar(p, h):
+            return jnp.sum(layer.apply({"params": p}, h)[0])
+
+        return str(jax.make_jaxpr(jax.grad(scalar))(params, h))
+
+    assert moe._receive_rows(384, 16, 16) == 384        # all held
+    assert moe._receive_rows(384, 8, 16) == 384         # half of them
+    assert program(UNCUT).count("cond[") == 0
+    assert program(TINY).count("cond[") == 2
+    # The cell's: 4 x 16,384 choices, 8 of 64 held.
+    assert moe._receive_rows(65536, 8, 64) == 16384
 
 
 # --- the grouped matmul with rows that are nobody's ---------------------------
@@ -445,6 +581,9 @@ def test_the_step_counts_rows_and_telemetry_stacks_per_kind():
     rows = float(metrics["moe_rows_held"])
     assert 0.1 * routed < rows < 0.5 * routed and rows == int(rows)
     assert 0.25 <= float(metrics["moe_max_load"]) <= 1.0
+    # 64 tokens x 4 choices a layer and micro-batch, a quarter of the experts
+    # held: row buffers of 128 rows, and at this load no pass outgrew them.
+    assert float(metrics["moe_overflow_passes"]) == 0
     state, metrics = trainer.train_step(state, batch, telemetry=True)
     telem = metrics["telemetry"]
     assert telem["act"]["conv_moe"]["attn_rms"].shape == (6,)
@@ -454,3 +593,16 @@ def test_the_step_counts_rows_and_telemetry_stacks_per_kind():
     assert "conv_dense" not in telem["router"]
     assert telem["grad_norm"]["per_layer_conv_moe"].shape == (6,)
     assert float(metrics["moe_rows_held"]) > 0
+    assert float(metrics["moe_overflow_passes"]) == 0
+    # A selection bias that puts every choice on the held experts (ids 4-7):
+    # each of the 8 expert layers x 2 micro-batches outgrows its buffers,
+    # counted by sum, and every routed row is computed here.
+    biased = jax.tree_util.tree_map_with_path(
+        lambda path, p: p.at[..., 4:8].set(10.0)
+        if "expert_bias" in str(path) else p, state.params)
+    state = trainer.with_params_c(
+        state.replace(params=biased, params_c=None))
+    state, metrics = trainer.train_step(state, batch)
+    assert float(metrics["moe_overflow_passes"]) == 8 * 2
+    assert float(metrics["moe_rows_held"]) == routed
+    assert np.isfinite(float(metrics["loss"]))

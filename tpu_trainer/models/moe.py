@@ -50,19 +50,33 @@ the top-k gates weight the combine. Router, gates, and aux/z losses are
 shared with the capacity path; telemetry reports the TRUE post-routing
 load (the bincount) rather than pre-capacity first-choice fractions,
 plus a ``max_group_frac`` collapse indicator.
+
+A layer that holds a share of the experts (``GPTConfig.moe_experts_held``:
+one chip of an expert-parallel deployment) sizes every row buffer of the
+dropless path by that share: ``R`` rows, twice what an even load sends it,
+as the exchange would size its receive buffer (``_receive_rows``). The sort
+stays over the ``k*T`` integers; rows are gathered, multiplied, activated
+and brought back over ``[R, .]`` buffers. A pass in which more than ``R``
+rows chose a held expert goes through ALL the sorted rows instead, a chunk
+at a time (``lax.cond``, counted as ``moe_overflow_passes``), so no row is
+dropped at any load and the result is the same either way; only the bounded
+path runs the Pallas kernels, the overflow the compiler's own ragged dots.
+Holding half the experts or more, or all of them as every uniform model
+does, gives ``R == k*T``: one formulation over all rows, no ``cond``.
 """
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from tpu_trainer.models.config import GPTConfig
-from tpu_trainer.ops.grouped_matmul import gmm
+from tpu_trainer.ops.grouped_matmul import gmm, gmm_reference, tgmm
 from tpu_trainer.utils import telemetry
 
 
@@ -220,6 +234,252 @@ def _unsorted_rows_bwd(perm, g):
 
 
 _unsorted_rows.defvjp(_unsorted_rows_fwd, _unsorted_rows_bwd)
+
+
+# --- the dropless expert FFN, and its row buffers ---------------------------
+#
+# A layer that holds ``held`` of ``E`` experts receives, at an even load,
+# ``k*T * held / E`` of the ``k*T`` token-choice rows. Its sorted buffers are
+# sized as an expert-parallel exchange sizes its receive buffer: twice that
+# share. Whatever the load, no row is dropped: a pass in which more rows
+# chose a held expert visits them all, a buffer's worth at a time
+# (``lax.cond`` in ``_bounded_ffn``).
+
+_RECEIVE_OVER_EVEN_SHARE = 2
+
+
+def _receive_rows(choices: int, held: int, experts: int) -> int:
+    """Rows of the sorted buffers, ``R``: twice the even share of the
+    ``choices`` (``k*T``) rows, a multiple of the row tile ``gmm`` takes at
+    that size (``ops/grouped_matmul._resolve_opts``: 512 from 4,096 rows on,
+    128 below), at most all of them. Holding half the experts or more gives
+    ``choices``: one formulation, the worst case."""
+    share = _RECEIVE_OVER_EVEN_SHARE * choices * held / experts
+    tile = 512 if share >= 4096 else 128
+    return min(choices, tile * math.ceil(share / tile))
+
+
+class _FFN(NamedTuple):
+    """What is static about one expert FFN (hashable: it rides the
+    ``custom_vjp`` as a non-differentiable argument)."""
+
+    act: Callable
+    use_kernel: Optional[bool]
+    subset: bool        # a share of the experts is held
+    rows: int           # R, the receive bound on the sorted buffers
+    # The grouped matmuls as one ``lax.ragged_dot`` each (the overflow).
+    plain: bool = False
+
+
+def _worst_case_ffn(how: _FFN, xt, gates, w_gate, w_up, w_down,
+                    perm, inv_perm, mine, counts):
+    """The expert FFN over ALL ``k*T`` sorted rows in one set of buffers:
+    what a layer runs whose receive bound is ``k*T`` (every expert held, or
+    half of them and more). The grouped matmuls' schedule skips the rows
+    past ``sum(counts)``, the row movement and the elementwise passes do
+    not."""
+    k, T = mine.shape
+    with jax.named_scope("route"):
+        grouped_in = _sorted_rows(xt, perm, inv_perm, mine)     # [k*T, H]
+
+    def grouped(lhs, w):
+        return gmm(lhs, w, counts, use_kernel=how.use_kernel)
+
+    with jax.named_scope("experts"):
+        mid = how.act(grouped(grouped_in, w_gate)) * grouped(grouped_in, w_up)
+        grouped_out = grouped(mid, w_down)                      # [k*T, H]
+    with jax.named_scope("route"):
+        rows = _unsorted_rows(grouped_out, perm, inv_perm).reshape(k, T, -1)
+        weighted = rows * gates.T[..., None].astype(xt.dtype)
+        if how.subset:
+            weighted = jnp.where(mine[..., None], weighted, 0)
+        return jnp.sum(weighted, axis=0)
+
+
+def _chunk(how: _FFN, perm, inv_perm, mine, counts, chunk):
+    """Sorted rows ``[chunk*R, (chunk+1)*R)`` as one ``[R, .]`` buffer sees
+    them: the token-choice of each row (``[R]``, choice-major ``j*T + t``),
+    the group sizes inside the chunk, and for each token-choice its row in
+    the chunk with whether it is there at all (``[k, T]`` both). ``chunk``
+    is 0 on the bounded path (its rows start where the whole sort's do, so
+    its ``gmm`` tiles are the worst case's) and a loop's index past it."""
+    rows, start = how.rows, chunk * how.rows
+    ends = jnp.cumsum(counts)
+    sizes = (jnp.clip(ends - start, 0, rows)
+             - jnp.clip(ends - counts - start, 0, rows))
+    # The last chunk may pass the end of the sort: rows of no group.
+    first = jax.lax.dynamic_slice(
+        jnp.pad(perm, (0, -perm.shape[0] % rows)), (start,), (rows,))
+    local = inv_perm.reshape(mine.shape) - start
+    here = mine & (local >= 0) & (local < rows)
+    return first, sizes, jnp.clip(local, 0, rows - 1), here
+
+
+def _to_tokens(source, slots, here, scale=None):
+    """The way back from an ``[R, H]`` buffer of sorted rows to the tokens:
+    ``sum_j source[slots[j]] (* scale[j])`` over the choices that are
+    ``here``, ``[T, H]`` in f32. The token side still has ``k*T`` choices,
+    but their source is the small buffer and each choice is its own
+    ``[T, H]`` gather, so nothing of ``k*T`` rows is written: on the chip
+    the k gathers and the masked sum took 0.93 ms where one scatter-add of
+    the ``R`` gate-scaled rows into ``[T, H]`` took 2.57 in f32 and 1.93 in
+    bf16 (PERF.md, PR 29)."""
+    out = 0.0
+    for j in range(slots.shape[0]):
+        rows = source[slots[j]]
+        if scale is not None:
+            rows = rows * scale[j][:, None].astype(rows.dtype)
+        out = out + jnp.where(here[j][:, None], rows, 0).astype(jnp.float32)
+    return out
+
+
+def _bounded_forward(how: _FFN, xt, gates, w_gate, w_up, w_down,
+                     perm, inv_perm, mine, counts, chunk):
+    """The FFN over one chunk of ``how.rows`` sorted rows. With
+    ``sum(counts) <= R`` chunk 0 holds every row that chose a held expert,
+    in the worst case's order, so the groups and the ``gmm`` tiling over
+    them are the worst case's. Returns the chunk's part of the layer's sum
+    (f32) and what the backward needs of the ``[R, .]`` intermediates."""
+    first, sizes, slots, here = _chunk(
+        how, perm, inv_perm, mine, counts, chunk)
+
+    def grouped(lhs, w):
+        if how.plain:
+            return gmm_reference(lhs, w, sizes)
+        return gmm(lhs, w, sizes, use_kernel=how.use_kernel)
+
+    with jax.named_scope("route"):
+        grouped_in = xt[first % xt.shape[0]]                    # [R, H]
+    with jax.named_scope("experts"):
+        gate_out = grouped(grouped_in, w_gate)
+        up_out = grouped(grouped_in, w_up)
+        grouped_out = grouped(how.act(gate_out) * up_out, w_down)
+    with jax.named_scope("route"):
+        out = _to_tokens(grouped_out, slots, here, gates.T)
+    return out, (gate_out, up_out, grouped_out)
+
+
+def _bounded_backward(how: _FFN, args, saved, g, chunk):
+    """Transpose of ``_bounded_forward``, with every row buffer ``[R, .]``:
+    the gate-scaled output gradient is gathered in sorted order (``R`` rows
+    of ``g``), the grouped matmuls' transposes are ``gmm`` against the
+    transposed weights and ``tgmm``, and the way back to the tokens is the
+    forward's. All five gradients in f32: the caller rounds them."""
+    xt, gates, w_gate, w_up, w_down, perm, inv_perm, mine, counts = args
+    gate_out, up_out, grouped_out = saved
+    k, T = mine.shape
+    first, sizes, slots, here = _chunk(
+        how, perm, inv_perm, mine, counts, chunk)
+    kernel = dict(use_kernel=how.use_kernel)
+
+    def dgrad(dout, w):
+        return gmm(dout, jnp.swapaxes(w, 1, 2), sizes, **kernel)
+
+    with jax.named_scope("route"):
+        tok = first % T
+        grouped_in = xt[tok]            # gathered again: cheaper than kept
+        g_rows = g[tok].astype(jnp.float32)                     # [R, H]
+        scale = gates.T.reshape(-1)[first]                      # [R]
+        # Past the chunk's groups grouped_out is zero, so the gate's
+        # gradient is; d_out is not, and no grouped matmul reads it there.
+        d_scale = jnp.sum(g_rows * grouped_out.astype(jnp.float32), axis=-1)
+        d_out = (g_rows * scale[:, None]).astype(grouped_out.dtype)
+    with jax.named_scope("experts"):
+        mid, act_vjp = jax.vjp(lambda a, b: how.act(a) * b, gate_out, up_out)
+        d_gate_out, d_up_out = act_vjp(dgrad(d_out, w_down))
+        d_in = dgrad(d_gate_out, w_gate) + dgrad(d_up_out, w_up)
+        d_weights = (tgmm(grouped_in, d_gate_out, sizes, **kernel),
+                     tgmm(grouped_in, d_up_out, sizes, **kernel),
+                     tgmm(mid, d_out, sizes, **kernel))
+    with jax.named_scope("route"):
+        dx = _to_tokens(d_in, slots, here)
+        d_gates = jnp.zeros((k * T,), jnp.float32).at[first].add(
+            d_scale).reshape(k, T).T
+    return (dx, d_gates, *d_weights)
+
+
+def _sum_over_chunks(how: _FFN, choices: int, chunk_fn, like):
+    """``sum(chunk_fn(c))`` over every chunk of the ``choices`` sorted rows
+    (f32 trees shaped as ``like``), one chunk's buffers at a time."""
+    zeros = jax.tree_util.tree_map(
+        lambda x: jnp.zeros(x.shape, jnp.float32), like)
+    return jax.lax.fori_loop(
+        0, -(-choices // how.rows),
+        lambda c, acc: jax.tree_util.tree_map(jnp.add, acc, chunk_fn(c)),
+        zeros)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _bounded_ffn(how: _FFN, *args):
+    """The expert FFN with row buffers of ``how.rows`` rows; ``args`` as
+    ``_worst_case_ffn`` takes them. ``sum(counts) <= R``: one pass over the
+    first ``R`` sorted rows (``_bounded_forward`` / ``_bounded_backward``).
+    More: every chunk of the sorted rows in turn (``_overflow_part``), which
+    visits all ``k*T`` rows, so the result is exact either way. One
+    ``custom_vjp`` round both so that what lives between the passes is the
+    bounded path's ``[R, .]`` intermediates and nothing of the overflow (AD
+    of ``lax.cond`` keeps both branches' residuals and copies every operand
+    a branch saved): the backward's overflow branch starts again from the
+    layer's inputs."""
+    return _bounded_ffn_fwd(how, *args)[0]
+
+
+def _plain(how: _FFN) -> _FFN:
+    """The overflow's side of ``how``. A branch costs its tracing, lowering,
+    compiling and its room in the step's memory plan whether or not it ever
+    runs, and in the cell this one never does: the worst-case formulation
+    there kept the plan rematerialising elsewhere (23 ms a step), the Pallas
+    kernels there added 10 s to 60 s of set-up (PERF.md, PR 29). So: a
+    quarter of ``R`` rows a chunk, and each grouped matmul one
+    ``lax.ragged_dot``, which plain AD differentiates."""
+    return how._replace(plain=True, rows=max(1, how.rows // 4))
+
+
+def _overflow_part(how: _FFN, floats, ints, chunk):
+    """One chunk's part of the layer's sum (f32) on the overflow's side."""
+    return _bounded_forward(_plain(how), *floats, *ints, chunk)[0]
+
+
+def _bounded_ffn_fwd(how: _FFN, *args):
+    xt, *_, mine, counts = args
+
+    def bounded():
+        out, saved = _bounded_forward(how, *args, 0)
+        return out.astype(xt.dtype), saved
+
+    def overflow():
+        out = _sum_over_chunks(
+            _plain(how), mine.size,
+            lambda c: _overflow_part(how, args[:5], args[5:], c), xt)
+        saved = jax.eval_shape(bounded)[1]
+        return out.astype(xt.dtype), tuple(
+            jnp.zeros(s.shape, s.dtype) for s in saved)
+
+    out, saved = jax.lax.cond(
+        jnp.sum(counts) <= how.rows, bounded, overflow)
+    return out, (args, saved)
+
+
+def _bounded_ffn_bwd(how: _FFN, res, g):
+    args, saved = res
+    floats, ints = args[:5], args[5:]       # ints: perm ... mine, counts
+    *_, mine, counts = ints
+
+    def chunk_grads(c):
+        _, vjp = jax.vjp(
+            lambda *fl: _overflow_part(how, fl, ints, c), *floats)
+        return tuple(d.astype(jnp.float32)
+                     for d in vjp(g.astype(jnp.float32)))
+
+    grads = jax.lax.cond(
+        jnp.sum(counts) <= how.rows,
+        lambda: _bounded_backward(how, args, saved, g, 0),
+        lambda: _sum_over_chunks(_plain(how), mine.size, chunk_grads, floats))
+    return (*(d.astype(x.dtype) for d, x in zip(grads, floats)),
+            *[None] * len(ints))
+
+
+_bounded_ffn.defvjp(_bounded_ffn_fwd, _bounded_ffn_bwd)
 
 
 class MoEMLP(nn.Module):
@@ -429,12 +689,24 @@ class MoEMLP(nn.Module):
 
         With a share of the experts held (``GPTConfig.moe_experts_held``)
         the choices of experts that live elsewhere sort behind the held
-        experts' rows: the ``[k*T, H]`` buffers keep their worst-case size
-        (every choice of every token may be a held expert, so no row that
-        chose one is ever dropped, whatever the imbalance), ``sum(counts)``
-        is the rows held, the kernels' schedule skips the rest, and the
-        combine leaves the other choices out. What the absent experts would
-        add is left out: the result is this chip's part of the layer's sum.
+        experts' rows, ``sum(counts)`` is the rows held, and the row
+        buffers are sized by the share, not by the worst case: ``R =
+        _receive_rows(k*T, held, E)`` rows, twice the even share
+        (``_bounded_ffn``). The first ``R`` sorted rows are gathered, the
+        three ``gmm`` and the activation see ``[R, .]`` operands, and each
+        token gathers its held choices back from the ``[R, H]`` result.
+        Every choice of every token may still be a held expert: a pass
+        with ``sum(counts) > R`` runs under ``lax.cond`` over all ``k*T``
+        sorted rows, a chunk at a time, the same computation with the
+        compiler's ragged dots for the kernels. Either way every row that
+        chose a held expert goes through its expert, in f32 accumulation:
+        none is ever dropped, whatever the imbalance, and no capacity or
+        approximation enters; an overflow costs more time than the bound
+        saves, and ``moe_overflow_passes`` counts it. With ``held == E``
+        (or half of them and more) ``R == k*T`` and ``_worst_case_ffn``
+        over all rows is the only formulation traced. What the absent
+        experts would add is left out: the result is this chip's part of
+        the layer's sum.
 
         Mesh composition: on a multi-device mesh the jnp twin runs
         (``use_kernel=False``) so GSPMD partitions the ragged dot like any
@@ -470,28 +742,23 @@ class MoEMLP(nn.Module):
             counts = jnp.bincount(flat_expert, length=held + 1)[:held]
             perm = jnp.argsort(flat_expert)                     # stable
             inv_perm = jnp.argsort(perm)
-            grouped_in = _sorted_rows(
-                xt.astype(dtype), perm, inv_perm, mine)         # [k*T, H]
 
-        def grouped(lhs, w):
-            return gmm(lhs, w, counts, use_kernel=use_kernel)
+        how = _FFN(act, use_kernel, subset,
+                   _receive_rows(k * T, held, cfg.num_experts))
+        args = (xt.astype(dtype), gates, w_gate, w_up, w_down,
+                perm, inv_perm, mine, counts)
+        bounded = how.rows < k * T
+        out = (_bounded_ffn if bounded else _worst_case_ffn)(how, *args)
 
-        with jax.named_scope("experts"):
-            mid = act(grouped(grouped_in, w_gate)) * grouped(grouped_in, w_up)
-            grouped_out = grouped(mid, w_down)                  # [k*T, H]
-        with jax.named_scope("route"):
-            rows = _unsorted_rows(
-                grouped_out, perm, inv_perm).reshape(k, T, -1)
-            weighted = rows * gates.T[..., None].astype(dtype)
-            if subset:
-                weighted = jnp.where(mine[..., None], weighted, 0)
-            out = jnp.sum(weighted, axis=0)
-
-        # Rows the experts here computed, and the busiest one's share.
+        # Rows the experts here computed (whichever way), the busiest one's
+        # share, and whether this pass outgrew the bounded buffers.
         rows_held = jnp.sum(counts).astype(jnp.float32)
         telemetry.count("moe_rows_held", rows_held)
         telemetry.count("moe_max_load", jnp.max(counts).astype(jnp.float32)
                         / jnp.maximum(rows_held, 1.0), reduce="max")
+        if bounded:
+            telemetry.count("moe_overflow_passes",
+                            (rows_held > how.rows).astype(jnp.float32))
 
         if telemetry.capturing():
             # True post-routing load (the bincount — what each expert
