@@ -34,8 +34,7 @@ recording the smoke gate replays.
 
 ``--ab`` runs the workload twice as an A/B pair — unchunked vs chunked
 for ``adversarial``, prefix cache off vs on for ``shared_prefix``, spec
-decode off vs on when ``--spec`` is set — and ``--update-md`` splices
-the lane table into ``benchmarks/results.md``.
+decode off vs on when ``--spec`` is set.
 
 ``--replicas N`` routes the trace through the multi-replica front-end
 (``serving/frontend.py``) instead of a single engine: ``--routing``
@@ -64,7 +63,7 @@ lane gate, ``--profile-trace DIR`` captures a ``jax.profiler`` trace of
 the serve loop, and ``--no-trace`` is the bit-identity A/B.
 
     python benchmarks/serve_bench.py [--requests 32] [--concurrency 8]
-    python benchmarks/serve_bench.py --workload adversarial --ab --update-md
+    python benchmarks/serve_bench.py --workload adversarial --ab
     python benchmarks/serve_bench.py --workload repetitive --spec ngram --ab
     python benchmarks/serve_bench.py --workload shared_prefix --replicas 3 --ab
     python benchmarks/serve_bench.py --trace benchmarks/traces/sample_trace.jsonl
@@ -89,9 +88,6 @@ import time
 import zlib
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-_RESULTS_MD = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                           "results.md")
 
 
 def _load_trace_file(path, *, vocab_size, max_seq_len, default_max_new,
@@ -315,9 +311,6 @@ def main(argv=None) -> int:
                    help="run the workload as an A/B lane pair: unchunked "
                         "vs chunked (adversarial), prefix off vs on "
                         "(shared_prefix); implies --no-baseline")
-    p.add_argument("--update-md", action="store_true",
-                   help="with --ab: splice the lane table into "
-                        "benchmarks/results.md")
     p.add_argument("--no-baseline", action="store_true",
                    help="skip the sequential generate_kv comparison")
     p.add_argument("--out", default=None,
@@ -762,8 +755,6 @@ def main(argv=None) -> int:
             line += (f", {b['spec_accept_mean']:.2f} accepted drafts/step "
                      f"(rate {b['spec_accept_rate']:.2f})")
         print(line, flush=True)
-        if args.update_md:
-            update_serving_md(workload, records)
 
     if args.out:
         with open(args.out, "a") as fh:
@@ -1144,8 +1135,6 @@ def _run_mesh_lanes(args, params, cfg, make_trace, workload) -> int:
           f"{rec_b['tp_token_match']}, wire/worker "
           f"{rec_b['wire_bytes_per_worker']} B "
           f"({rec_b['wire_ratio']:.2f}x full/tp)", flush=True)
-    if args.update_md:
-        update_mesh_md(workload, records, args)
 
     for rec in records:
         if rec.get("span_conservation_ok") is False:
@@ -1181,68 +1170,6 @@ def _print_record_mesh(r) -> None:
               f"{r['param_bytes_full']} B full tree "
               f"({r['wire_ratio']:.2f}x full/tp), worker stream match "
               f"{r['shard_stream_token_match']}", flush=True)
-
-
-def update_mesh_md(workload, records, args) -> None:
-    """Splice the sharded-decode A/B table into benchmarks/results.md
-    (marker block ``serving-mesh``, its own section)."""
-    start = "<!-- serving-mesh:start -->"
-    end = "<!-- serving-mesh:end -->"
-    m = records[0]["model"]
-    tp = max(r["tp"] for r in records)
-    header = (
-        f"`XLA_FLAGS=--xla_force_host_platform_device_count=8 "
-        f"python benchmarks/serve_bench.py --workload {workload} "
-        f"--mesh-tensor {tp}` — hidden {m['hidden']}, layers "
-        f"{m['layers']}, heads {m['heads']}, "
-        f"{records[0]['n_requests']} reqs @ concurrency "
-        f"{records[0]['concurrency']}, block {records[0]['block_size']} "
-        f"({time.strftime('%Y-%m-%d')}). Both lanes hold the same total "
-        f"pool; the sharded lane spreads it over {tp} devices, so a "
-        f"peak past the per-device budget is served only by the mesh. "
-        f"Wire/worker is the measured host-shard npz each worker of a "
-        f"tp={tp} fleet downloads vs the full tree.\n\n"
-    )
-    lines = [
-        "| Lane | tp | blocks/device | total | peak | tok/s "
-        "| TTFT p99 (ms) | token match | wire/worker |",
-        "|---|---|---|---|---|---|---|---|---|",
-    ]
-    for r in records:
-        wire = "-"
-        if r.get("wire_bytes_per_worker") is not None:
-            wire = (f"{r['wire_bytes_per_worker'] / 1024:.0f} KiB "
-                    f"({r['wire_ratio']:.2f}x full/tp)")
-        peak = str(r["peak_pool_blocks"])
-        if r["exceeds_device_budget"]:
-            peak += " (> device)"
-        match = ("bit-exact" if r.get("tp_token_match")
-                 else "-" if r.get("tp_token_match") is None else "DIVERGED")
-        lines.append(
-            f"| {r['lane']} | {r['tp']} | {r['device_pool_blocks']} "
-            f"| {r['total_pool_blocks']} | {peak} "
-            f"| {r['tokens_per_s']:,.0f} "
-            f"| {(r.get('ttft_p99_s') or 0) * 1e3:.1f} "
-            f"| {match} | {wire} |"
-        )
-    block = f"{start}\n{header}" + "\n".join(lines) + f"\n{end}"
-    section_head = "## Sharded decode"
-    with open(_RESULTS_MD) as f:
-        text = f.read()
-    if start in text:
-        text = text.split(start)[0] + block + text.split(end)[1]
-    elif section_head in text:
-        text = text.replace(f"{section_head}\n",
-                            f"{section_head}\n\n{block}\n", 1)
-    elif "\n## Multi-replica serving" in text:
-        text = text.replace(
-            "\n## Multi-replica serving",
-            f"\n{section_head}\n\n{block}\n\n## Multi-replica serving", 1)
-    else:
-        text += f"\n{section_head}\n\n{block}\n"
-    with open(_RESULTS_MD, "w") as f:
-        f.write(text)
-    print(f"wrote sharded-decode table to {_RESULTS_MD}", file=sys.stderr)
 
 
 def _run_frontend_lanes(args, params, cfg, make_trace, workload) -> int:
@@ -1795,8 +1722,6 @@ def _run_frontend_lanes(args, params, cfg, make_trace, workload) -> int:
               f"tokens {dis['store_hit_tokens']}, stream match "
               f"{'bit-exact' if dis['disagg_token_match'] else 'DIVERGED'}",
               flush=True)
-        if args.update_md:
-            update_disagg_md(workload, records, args)
     elif workers_mode:
         if args.ab and len(records) >= 2:
             b = next(r for r in records if r["transport"] == "rpc")
@@ -1805,15 +1730,11 @@ def _run_frontend_lanes(args, params, cfg, make_trace, workload) -> int:
                   f"{(b.get('rpc_overhead_p50_s') or 0) * 1e3:.1f} ms "
                   f"p99 {(b.get('rpc_overhead_p99_s') or 0) * 1e3:.1f} ms",
                   flush=True)
-        if args.update_md:
-            update_workers_md(workload, records, args)
     elif args.ab and len(records) >= 2:
         a, b = records[0], records[1]
         print(f"A/B     {b['lane']} vs random routing: prefix hit rate "
               f"{b['prefix_hit_rate']:.2f} vs {a['prefix_hit_rate']:.2f}, "
               f"tok/s x{b['tok_s_vs_random']:.2f}", flush=True)
-        if args.update_md:
-            update_frontend_md(workload, records, args)
 
     if args.out:
         with open(args.out, "a") as fh:
@@ -1899,182 +1820,6 @@ def _print_frontend_record(r) -> None:
           flush=True)
 
 
-def update_frontend_md(workload, records, args) -> None:
-    """Splice the multi-replica lane table into benchmarks/results.md
-    (marker block ``serving-replicas``, its own section)."""
-    start = "<!-- serving-replicas:start -->"
-    end = "<!-- serving-replicas:end -->"
-    m = records[0]["model"]
-    header = (
-        f"`python benchmarks/serve_bench.py --workload {workload} "
-        f"--replicas {records[0]['replicas']} --ab"
-        + (f" --replica-kill {args.replica_kill}"
-           if args.replica_kill else "")
-        + f"` — hidden {m['hidden']}, layers {m['layers']}, "
-        f"{records[0]['n_requests']} reqs @ concurrency "
-        f"{records[0]['concurrency']} per replica, "
-        f"{records[0]['prefix_groups'] or 'auto'} prefix groups, block "
-        f"{records[0]['block_size']} ({time.strftime('%Y-%m-%d')}).\n\n"
-    )
-    lines = [
-        "| Lane | routing | replicas | tok/s | TTFT p99 (ms) | hit rate "
-        "| per-replica hit | reject rate | failovers |",
-        "|---|---|---|---|---|---|---|---|---|",
-    ]
-    for r in records:
-        per = " / ".join(
-            f"{p['prefix_hit_rate']:.2f}" for p in r["per_replica"])
-        lines.append(
-            f"| {r['lane']} | {r['routing']} "
-            f"| {r['replicas_live']}/{r['replicas']} "
-            f"| {r['tokens_per_s']:,.0f} "
-            f"| {(r.get('ttft_p99_s') or 0) * 1e3:.1f} "
-            f"| {r['prefix_hit_rate']:.2f} | {per} "
-            f"| {r['reject_rate']:.3f} | {r['failover_events']} |"
-        )
-    block = f"{start}\n{header}" + "\n".join(lines) + f"\n{end}"
-    section_head = "## Multi-replica serving"
-    with open(_RESULTS_MD) as f:
-        text = f.read()
-    if start in text:
-        text = text.split(start)[0] + block + text.split(end)[1]
-    elif section_head in text:
-        text = text.replace(f"{section_head}\n",
-                            f"{section_head}\n\n{block}\n", 1)
-    elif "\n## Dropless MoE" in text:
-        text = text.replace(
-            "\n## Dropless MoE",
-            f"\n{section_head}\n\n{block}\n\n## Dropless MoE", 1)
-    else:
-        text += f"\n{section_head}\n\n{block}\n"
-    with open(_RESULTS_MD, "w") as f:
-        f.write(text)
-    print(f"wrote multi-replica serving table to {_RESULTS_MD}",
-          file=sys.stderr)
-
-
-def update_disagg_md(workload, records, args) -> None:
-    """Splice the disaggregated-serving lane table into
-    benchmarks/results.md (marker block ``serving-disagg``)."""
-    start = "<!-- serving-disagg:start -->"
-    end = "<!-- serving-disagg:end -->"
-    m = records[0]["model"]
-    header = (
-        f"`python benchmarks/serve_bench.py --workload {workload} "
-        f"--disagg {args.disagg}"
-        + (f" --workers {args.workers}" if args.workers else "")
-        + f" --update-md` — hidden {m['hidden']}, layers {m['layers']}, "
-        f"{records[0]['n_requests']} reqs @ concurrency "
-        f"{records[0]['concurrency']} per replica, "
-        f"{records[0]['prefix_groups'] or 'auto'} prefix groups, block "
-        f"{records[0]['block_size']}, store {args.kv_store_mb} MiB "
-        f"({time.strftime('%Y-%m-%d')}). The baseline lane is the "
-        f"symmetric fleet with per-replica caches only; the store lanes "
-        f"share one digest-addressed KV block store; the disagg lane "
-        f"splits the fleet into prefill/decode roles and migrates "
-        f"finished prefills. Stream match is bit-exactness against a "
-        f"single undisturbed engine on the same trace.\n\n"
-    )
-    lines = [
-        "| Lane | roles | fleet hit | per-replica hit | store-hit tok "
-        "| migrations | migrated bytes | stream match |",
-        "|---|---|---|---|---|---|---|---|",
-    ]
-    for r in records:
-        per = " / ".join(
-            f"{p['prefix_hit_rate']:.2f}" for p in r["per_replica"])
-        role = args.disagg if r["lane"] == "disagg" else "symmetric"
-        match = ("bit-exact" if r.get("disagg_token_match")
-                 else "-" if r.get("disagg_token_match") is None
-                 else "DIVERGED")
-        lines.append(
-            f"| {r['lane']} | {role} "
-            f"| {r['fleet_prefix_hit_rate']:.2f} | {per} "
-            f"| {r['store_hit_tokens']} | {r['migrations']} "
-            f"| {r['migrated_bytes']} | {match} |")
-    block = f"{start}\n{header}" + "\n".join(lines) + f"\n{end}"
-    section_head = "## Disaggregated serving"
-    with open(_RESULTS_MD) as f:
-        text = f.read()
-    if start in text:
-        text = text.split(start)[0] + block + text.split(end)[1]
-    elif section_head in text:
-        text = text.replace(f"{section_head}\n",
-                            f"{section_head}\n\n{block}\n", 1)
-    elif "\n## Cross-process serving" in text:
-        text = text.replace(
-            "\n## Cross-process serving",
-            f"\n{section_head}\n\n{block}\n\n## Cross-process serving", 1)
-    elif "\n## Multi-replica serving" in text:
-        text = text.replace(
-            "\n## Multi-replica serving",
-            f"\n{section_head}\n\n{block}\n\n## Multi-replica serving", 1)
-    else:
-        text += f"\n{section_head}\n\n{block}\n"
-    with open(_RESULTS_MD, "w") as f:
-        f.write(text)
-    print(f"wrote disaggregated serving table to {_RESULTS_MD}",
-          file=sys.stderr)
-
-
-def update_workers_md(workload, records, args) -> None:
-    """Splice the cross-process (transport A/B) lane table into
-    benchmarks/results.md (marker block ``serving-workers``)."""
-    start = "<!-- serving-workers:start -->"
-    end = "<!-- serving-workers:end -->"
-    m = records[0]["model"]
-    header = (
-        f"`python benchmarks/serve_bench.py --workload {workload} "
-        f"--workers {records[0]['replicas']} --ab"
-        + (f" --worker-kill {args.worker_kill}" if args.worker_kill else "")
-        + f"` — hidden {m['hidden']}, layers {m['layers']}, "
-        f"{records[0]['n_requests']} reqs @ concurrency "
-        f"{records[0]['concurrency']} per replica, block "
-        f"{records[0]['block_size']} ({time.strftime('%Y-%m-%d')}). "
-        f"Lane A is the identical fleet in-process; RPC overhead is the "
-        f"per-request submit-to-first-token delta vs that lane on the "
-        f"same trace.\n\n"
-    )
-    lines = [
-        "| Lane | transport | workers | tok/s | TTFT p99 (ms) "
-        "| RPC overhead p50/p99 (ms) | worker deaths | failovers |",
-        "|---|---|---|---|---|---|---|---|",
-    ]
-    for r in records:
-        if r.get("rpc_overhead_p99_s") is not None:
-            ovh = (f"{r['rpc_overhead_p50_s'] * 1e3:.1f} / "
-                   f"{r['rpc_overhead_p99_s'] * 1e3:.1f}")
-        else:
-            ovh = "-"
-        n_workers = r["workers"] if r.get("transport") == "rpc" else 0
-        lines.append(
-            f"| {r['lane']} | {r.get('transport', 'inproc')} "
-            f"| {n_workers or '-'} "
-            f"| {r['tokens_per_s']:,.0f} "
-            f"| {(r.get('ttft_p99_s') or 0) * 1e3:.1f} "
-            f"| {ovh} | {r['worker_deaths']} | {r['failover_events']} |"
-        )
-    block = f"{start}\n{header}" + "\n".join(lines) + f"\n{end}"
-    section_head = "## Cross-process serving"
-    with open(_RESULTS_MD) as f:
-        text = f.read()
-    if start in text:
-        text = text.split(start)[0] + block + text.split(end)[1]
-    elif section_head in text:
-        text = text.replace(f"{section_head}\n",
-                            f"{section_head}\n\n{block}\n", 1)
-    elif "\n## Multi-replica serving" in text:
-        text = text.replace(
-            "\n## Multi-replica serving",
-            f"\n{section_head}\n\n{block}\n\n## Multi-replica serving", 1)
-    else:
-        text += f"\n{section_head}\n\n{block}\n"
-    with open(_RESULTS_MD, "w") as f:
-        f.write(text)
-    print(f"wrote cross-process serving table to {_RESULTS_MD}",
-          file=sys.stderr)
-
-
 def _print_record(record) -> None:
     tag = record["lane"]
     print(f"{tag:<8}{record['tokens_per_s']:10.1f} tok/s over "
@@ -2103,66 +1848,6 @@ def _print_record(record) -> None:
               f"{record['spec_accepted']}/{record['spec_drafted']} over "
               f"{record['spec_steps']} verify steps) "
               f"hist {record['spec_accept_hist']}", flush=True)
-
-
-def update_serving_md(workload, records) -> None:
-    """Splice an A/B lane table into benchmarks/results.md (one marker
-    block per workload, same mechanism as the scaling/packing tables)."""
-    start = f"<!-- serving-{workload}:start -->"
-    end = f"<!-- serving-{workload}:end -->"
-    m = records[0]["model"]
-    spec_flag = ""
-    for r in records:
-        if r.get("spec", "off") != "off":
-            spec_flag = f" --spec {r['spec']} --spec-k {r['spec_k']}"
-    header = (
-        f"`python benchmarks/serve_bench.py --workload {workload}"
-        f"{spec_flag} --ab` — "
-        f"hidden {m['hidden']}, layers {m['layers']}, "
-        f"{records[0]['n_requests']} reqs @ concurrency "
-        f"{records[0]['concurrency']}, block {records[0]['block_size']} "
-        f"({time.strftime('%Y-%m-%d')}).\n\n"
-    )
-    spec_ab = any(r.get("spec", "off") != "off" for r in records)
-    lines = [
-        "| Lane | chunk | prefix | spec | acc/step | tok/s "
-        "| TTFT p99 (ms) | TPOT p99 (ms) | hit rate | preemptions |",
-        "|---|---|---|---|---|---|---|---|---|---|",
-    ] if spec_ab else [
-        "| Lane | chunk | prefix | tok/s | TTFT p99 (ms) | TPOT p99 (ms) "
-        "| hit rate | preemptions |",
-        "|---|---|---|---|---|---|---|---|",
-    ]
-    for r in records:
-        spec_cols = ""
-        if spec_ab:
-            spec_cols = (
-                f"| {r.get('spec', 'off')} "
-                f"| {r['spec_accept_mean']:.2f} "
-                if r.get("spec", "off") != "off" else "| off | - ")
-        lines.append(
-            f"| {r['lane']} | {r['prefill_chunk'] or '-'} "
-            f"| {'on' if r['prefix_cache'] else 'off'} "
-            f"{spec_cols}"
-            f"| {r['tokens_per_s']:,.0f} "
-            f"| {(r.get('ttft_p99_s') or 0) * 1e3:.1f} "
-            f"| {(r.get('tpot_p99_s') or 0) * 1e3:.1f} "
-            f"| {r['prefix_hit_rate']:.2f} | {r['preemptions']} |"
-        )
-    block = f"{start}\n{header}" + "\n".join(lines) + f"\n{end}"
-    with open(_RESULTS_MD) as f:
-        text = f.read()
-    if start in text:
-        text = text.split(start)[0] + block + text.split(end)[1]
-    elif "## Serving fast path" in text:
-        text = text.replace("## Serving fast path\n",
-                            f"## Serving fast path\n\n{block}\n", 1)
-    else:
-        text += f"\n## Serving fast path\n\n{block}\n"
-    with open(_RESULTS_MD, "w") as f:
-        f.write(text)
-    print(f"wrote serving table ({workload}) to {_RESULTS_MD}",
-          file=sys.stderr)
 
 
 if __name__ == "__main__":

@@ -99,7 +99,7 @@ class TestConfigResolution:
 
     def test_offload_dtype_choices_reach_parallel_config(self, tiny_yaml):
         # VERDICT r4 weak #4: int8 (the 8-bit offloaded optimizer state)
-        # must be reachable from the production CLI, not just bench.py.
+        # must be reachable from the production CLI.
         for dt in ("float32", "bfloat16", "int8"):
             args = build_parser("fsdp").parse_args(
                 ["--config", tiny_yaml, "--cpu_offload",

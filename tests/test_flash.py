@@ -373,18 +373,6 @@ class TestSplitBackwardParity:
                     err_msg=f"d{name} auto != {expect} at s={s}",
                 )
 
-    def test_env_knob_overrides_auto(self, monkeypatch):
-        from tpu_trainer.ops import flash as flash_mod
-
-        monkeypatch.setenv("TPU_TRAINER_FLASH_BWD", "split")
-        g_env = self._grads(None, 1024)
-        monkeypatch.delenv("TPU_TRAINER_FLASH_BWD")
-        g_split = self._grads("split", 1024)
-        for got, expected in zip(g_env, g_split):
-            np.testing.assert_array_equal(np.asarray(got),
-                                          np.asarray(expected))
-        assert flash_mod._FUSED_BWD_MAX_SEQ == 2048
-
     def test_bad_backward_rejected(self):
         q, k, v = _rand_qkv(jax.random.PRNGKey(5), 1, 128, 1, 16)
         with pytest.raises(ValueError, match="backward"):

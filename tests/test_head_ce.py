@@ -153,7 +153,7 @@ class TestHeadCEKernel:
         assert not _pallas_head_ok(x, 0)
 
     def test_dispatch_gate_respects_memory_bounds(self):
-        # An explicit loss_chunk_size is a memory-bounding request, and
+        # An explicit chunk_size is a memory-bounding request, and
         # very large token counts grow the unchunked [V, T] residual
         # linearly — both must keep the chunked XLA path even where the
         # platform check would otherwise pass.

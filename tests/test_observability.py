@@ -237,6 +237,34 @@ class TestMetricLogger:
         logger.close()
         assert [r["kind"] for r in seen] == ["train", "eval", "custom"]
 
+    def test_deferred_entries_log_the_rate_of_their_dispatch(
+            self, monkeypatch):
+        # The CLI reads step metrics two steps late and drains the last
+        # ones in one burst: every line must still read the steady rate.
+        import time
+
+        from tpu_trainer.utils.telemetry import DeferredFetcher
+
+        clock = [100.0]
+        monkeypatch.setattr(time, "perf_counter", lambda: clock[0])
+        logger = MetricLogger(tokens_per_step=1000, stdout=False)
+        fetcher = DeferredFetcher(window=2)
+        records = []
+
+        def consume(entries):
+            for step, metrics, pushed_at in entries:
+                records.append(logger.log(step, metrics, at=pushed_at))
+
+        for step in range(5):
+            clock[0] += 0.5                    # one step every 0.5 s
+            consume(fetcher.push(step, {"loss": 1.0}))
+        assert len(records) == 3               # steps 3 and 4 in flight
+        clock[0] += 0.001                      # the run ends: one burst
+        consume(fetcher.drain())
+        logger.close()
+        assert [r["step"] for r in records] == [0, 1, 2, 3, 4]
+        assert [r["tokens_per_sec"] for r in records] == [2000.0] * 5
+
     def test_mfu_math(self):
         cfg = GPTConfig.gpt2_small()
         fpt = flops_per_token(cfg)

@@ -39,7 +39,7 @@ from tpu_trainer.models.config import GPTConfig
 from tpu_trainer.models.gpt import GPT
 from tpu_trainer.ops.flash import (
     paged_attention_reference, paged_attention_sharded)
-from tpu_trainer.serving import sharding as tp_lib
+from tpu_trainer.parallel.mesh import tp_mesh
 from tpu_trainer.serving.engine import ServingEngine, poisson_trace
 from tpu_trainer.utils.checkpoint import (
     _pick_export_axis, export_param_shards, load_param_shards)
@@ -70,7 +70,7 @@ class TestShardedKernel:
         # must be BIT-identical to the unsharded reference.
         args = _pool_case(kvh=kvh)
         want = paged_attention_reference(*args)
-        mesh = tp_lib.tp_mesh(tp, None)
+        mesh = tp_mesh(tp, None)
         got = paged_attention_sharded(*args, mesh=mesh, impl="reference")
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
@@ -80,7 +80,7 @@ class TestShardedKernel:
         # slices its one kv head (axis_index // (tp // kvh)).
         args = _pool_case(kvh=kvh)
         want = paged_attention_reference(*args)
-        mesh = tp_lib.tp_mesh(tp, None)
+        mesh = tp_mesh(tp, None)
         got = paged_attention_sharded(*args, mesh=mesh, impl="reference")
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
@@ -90,7 +90,7 @@ class TestShardedKernel:
         # kernel's online softmax reduces in a different order).
         args = _pool_case(kvh=8)
         want = paged_attention_reference(*args)
-        mesh = tp_lib.tp_mesh(2, None)
+        mesh = tp_mesh(2, None)
         got = paged_attention_sharded(
             *args, mesh=mesh, impl="kernel", interpret=True)
         np.testing.assert_allclose(
@@ -100,7 +100,7 @@ class TestShardedKernel:
         args = _pool_case(h=6, kvh=6)
         with pytest.raises(ValueError):
             paged_attention_sharded(
-                *args, mesh=tp_lib.tp_mesh(4, None), impl="reference")
+                *args, mesh=tp_mesh(4, None), impl="reference")
 
 
 # --- engine-level: sharded replica == single-device replica ----------------

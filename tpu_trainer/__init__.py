@@ -9,8 +9,8 @@ in-kernel dropout and RoPE, KV-cached generation, Orbax sharded
 checkpointing with auto-resume and preemption handling, host-offloaded
 optimizer state, and dummy/TinyStories/OpenWebText data with a native C
 tokenize fast path. See SURVEY.md at the repo root for the
-component-by-component parity map and benchmarks/results.md for measured
-numbers.
+component-by-component parity map and PERF.md / PERF_LEDGER.jsonl for
+measured numbers.
 """
 
 __version__ = "0.2.0"  # keep in sync with pyproject.toml

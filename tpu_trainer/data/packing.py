@@ -18,8 +18,8 @@ never 2).
 Packing efficiency: with mean document length m and first-fit into bins of
 size S, the expected non-pad fraction approaches 1 - O(m/S) (the only waste
 is the per-bin tail smaller than the shortest open document), versus m/S for
-pad-to-seq — the ratio S/m is the effective-throughput headroom bench.py's
-``--packed`` lane measures.
+pad-to-seq — the ratio S/m is the effective-throughput headroom packing
+buys.
 """
 
 from __future__ import annotations
@@ -70,8 +70,7 @@ def pack_documents(
     "decreasing" possible on a stream), and when a bin must flush to keep
     memory bounded, top it off with the largest windowed pieces that
     still fit its tail. BFD trades a small reorder buffer for fewer
-    stranded bin tails, squeezing the last few non-pad points —
-    ``bench.py --packed`` carries an A/B row of both.
+    stranded bin tails, squeezing the last few non-pad points.
 
     Either way a full bin is emitted immediately, and when more than
     ``max_open_bins`` bins are open the oldest is flushed (bounded memory,
@@ -189,7 +188,7 @@ def pad_documents(
 ) -> Iterator[np.ndarray]:
     """One document per row, padded to ``seq_len`` — the baseline packing
     replaces. Same ``[seq_len, 2]`` row format (doc = segment 1, pad = 0) so
-    both lanes of ``bench.py --packed`` run the identical trainer path."""
+    a padded and a packed run take the identical trainer path."""
     for doc in docs:
         for piece in _split_long(list(doc), seq_len):
             if not piece:

@@ -151,14 +151,6 @@ class GPTConfig:
     #           and cuts the per-layer activation stores that dominate HBM
     #           write traffic in the unremated step.
     remat_policy: str = "full"
-    # Rematerialize the LM head + cross entropy in the backward pass:
-    # nothing of the [batch, seq, vocab] softmax survives the forward (the
-    # single biggest activation — 1.6 GB f32 at bs=8/seq=1024/V=50257);
-    # backward recomputes one vocab matmul instead. Independent of
-    # gradient_checkpointing. Off by default (a memory knob: costs ~4.5%
-    # step time on v5e, measured). Subsumed by fused_loss (below), which is
-    # both faster *and* lighter; this flag only matters with fused_loss off.
-    remat_lm_head: bool = False
     # Compute the training loss via the blockwise fused LM-head + cross
     # entropy (ops/loss.py): full [batch, seq, vocab] logits never
     # materialize in either pass. Identical math to the reference's
@@ -167,8 +159,6 @@ class GPTConfig:
     # HBM traffic was 28% of the step. Affects the loss only — the logits
     # output of __call__ is unchanged.
     fused_loss: bool = True
-    # Sequence-chunk length for fused_loss; 0 = auto (~8k tokens per chunk).
-    loss_chunk_size: int = 0
     # On compiled TPU, compute the fused loss with the Pallas head kernel
     # (ops/head_ce.py): the softmax statistics ride through the head matmul
     # online (flash-attention-style), deleting the separate logsumexp HBM
@@ -199,12 +189,6 @@ class GPTConfig:
     # Requires num_layers % (stages * v) == 0 and microbatches % stages
     # == 0.
     pipeline_virtual_stages: int = 2
-    # Counter-based dropout masks (ops/dropout.py) instead of threefry
-    # bernoulli: same Bernoulli semantics, ~5x cheaper mask generation
-    # (threefry masks measured ~9% of the headline step). Applies to the
-    # residual/MLP dropout; attention-weight dropout inside the flash kernel
-    # is always counter-based.
-    fast_dropout: bool = True
     # Run the layer stack as an unrolled per-layer loop at apply time.
     # Parameters stay stacked [num_layers, ...] (checkpoint/sharding layout
     # unchanged — nn.scan still creates them), but each layer executes as
@@ -288,14 +272,6 @@ class GPTConfig:
     # shape already does.
     decode_window: int = 0
 
-    # REPRODUCIBILITY NOTE: fused_loss, fast_dropout, and scan_unroll
-    # default on as of v0.2, and the dropout-hash gained a second mix round
-    # in v0.3. Each changes the dropout RNG stream and/or loss reduction
-    # numerics relative to v0.1 — the same seed no longer reproduces a
-    # v0.1 run bit-for-bit (checkpoint/param layout is unchanged). To
-    # compare training curves against old runs, pin fused_loss=False,
-    # fast_dropout=False, scan_unroll=False deliberately.
-
     # TPU dtype policy: compute dtype for activations/matmuls; params and the
     # softmax/loss accumulations stay float32.
     dtype: str = "bfloat16"
@@ -375,7 +351,7 @@ class GPTConfig:
                 self, "paged_tp_devices",
                 tuple(int(d) for d in self.paged_tp_devices))
         if self.paged_tp != 1:
-            from tpu_trainer.serving.sharding import validate_tp
+            from tpu_trainer.parallel.mesh import validate_tp
 
             validate_tp(self.num_heads, self.kv_heads, self.paged_tp)
         if self.layer_types is not None:

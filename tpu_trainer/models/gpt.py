@@ -42,7 +42,7 @@ import numpy as np
 from tpu_trainer.models.config import CONV_TAPS, GPTConfig
 from tpu_trainer.ops import ring
 from tpu_trainer.ops.attention import flash_attention, reference_attention
-from tpu_trainer.ops.dropout import hash_dropout
+from tpu_trainer.ops.dropout import residual_dropout
 from tpu_trainer.ops.loss import (
     fused_shifted_cross_entropy,
     vocab_sharded_shifted_cross_entropy,
@@ -272,7 +272,7 @@ class CausalSelfAttention(nn.Module):
 
         out = out.reshape(b, s, cfg.hidden_size)
         out = dense(features=cfg.hidden_size, name="o_proj")(out)
-        out = _residual_dropout(cfg, self, out, deterministic)
+        out = residual_dropout(self, out, cfg.dropout, deterministic)
         return out
 
     def _decode_attention(self, q, k, v) -> jax.Array:
@@ -589,24 +589,24 @@ class CausalSelfAttention(nn.Module):
             if tp > 1:
                 from jax.sharding import PartitionSpec as P
 
-                from tpu_trainer.serving import sharding as tp_lib
+                from tpu_trainer.parallel.mesh import TP_AXIS, tp_mesh
                 from tpu_trainer.utils.jax_compat import shard_map
 
-                mesh = tp_lib.tp_mesh(tp, cfg.paged_tp_devices)
+                mesh = tp_mesh(tp, cfg.paged_tp_devices)
                 hl = h // tp
-                head = P(None, None, tp_lib.TP_AXIS, None)
+                head = P(None, None, TP_AXIS, None)
                 in_specs = [head, head, head, P(), P()]
                 in_specs += [head] * len(hist)
 
                 def body(q_l, kf_l, vf_l, ln_l, of_l, *hist_l):
-                    i = jax.lax.axis_index(tp_lib.TP_AXIS)
+                    i = jax.lax.axis_index(TP_AXIS)
                     out_l = attend(q_l, kf_l, vf_l, ln_l, of_l, *hist_l)
                     # Disjoint head slices: the psum is an exact concat
                     # (one non-zero contributor per element).
                     full = jnp.zeros((b, s, h, d), out_l.dtype)
                     full = jax.lax.dynamic_update_slice(
                         full, out_l, (0, 0, i * hl, 0))
-                    return jax.lax.psum(full, tp_lib.TP_AXIS)
+                    return jax.lax.psum(full, TP_AXIS)
 
                 out = shard_map(
                     body, mesh=mesh, in_specs=tuple(in_specs),
@@ -624,11 +624,11 @@ class CausalSelfAttention(nn.Module):
                 impl = ("kernel" if jax.default_backend() == "tpu"
                         else "reference")
             if cfg.paged_tp > 1:
-                from tpu_trainer.serving import sharding as tp_lib
+                from tpu_trainer.parallel.mesh import tp_mesh
 
                 out = flash_lib.paged_attention_sharded(
                     q[:, 0], pool_k, pool_v, tables, new_len,
-                    mesh=tp_lib.tp_mesh(cfg.paged_tp, cfg.paged_tp_devices),
+                    mesh=tp_mesh(cfg.paged_tp, cfg.paged_tp_devices),
                     k_scale=scale_k, v_scale=scale_v, impl=impl,
                 ).astype(q.dtype)[:, None]                # [b, 1, h, d]
             else:
@@ -647,16 +647,6 @@ class CausalSelfAttention(nn.Module):
                 sv.value = scale_v
             ln.value = new_len
         return out
-
-
-def _residual_dropout(cfg, module, x, deterministic):
-    """Residual-stream dropout (reference ``gpt.py:241,282``): counter-based
-    masks when ``cfg.fast_dropout`` (see ops/dropout.py), threefry otherwise."""
-    if deterministic or cfg.dropout <= 0.0:
-        return x
-    if cfg.fast_dropout:
-        return hash_dropout(x, cfg.dropout, module.make_rng("dropout"))
-    return nn.Dropout(rate=cfg.dropout)(x, deterministic=False)
 
 
 class MLP(nn.Module):
@@ -688,7 +678,7 @@ class MLP(nn.Module):
         act = {"silu": nn.silu, "gelu": nn.gelu}[cfg.activation]
         x = act(gate) * up
         x = dense(cfg.hidden_size, name="down_proj")(x)
-        return _residual_dropout(cfg, self, x, deterministic)
+        return residual_dropout(self, x, cfg.dropout, deterministic)
 
 
 class ShortConv(nn.Module):
@@ -732,7 +722,7 @@ class ShortConv(nn.Module):
                 shifted = jnp.where(same[..., None], shifted, 0)
             v = v + shifted * weight[:, taps - 1 - back]
         out = dense(hidden, name="out_proj")(gate_out * v)
-        return _residual_dropout(cfg, self, out, deterministic)
+        return residual_dropout(self, out, cfg.dropout, deterministic)
 
 
 class TransformerBlock(nn.Module):
@@ -1043,7 +1033,7 @@ class GPT(nn.Module):
             logits = embed.attend(x).astype(jnp.float32)
             if telemetry.capturing(deep=True):
                 # Between final_norm and the loss, nan-scan only: making the
-                # logits live here would defeat the fused/remat loss heads'
+                # logits live here would defeat the fused loss head's
                 # memory savings on periodic telemetry steps, but without this
                 # site a NaN entering in the head matmul is indistinguishable
                 # from one entering in the loss math (the seq x tensor repro,
@@ -1062,29 +1052,9 @@ class GPT(nn.Module):
                     # in the training graph, which only consumes the loss).
                     loss = fused_shifted_cross_entropy(
                         embed.embedding, x, labels,
-                        chunk_size=cfg.loss_chunk_size,
                         allow_pallas=cfg.fused_loss_pallas,
                         segment_ids=segment_ids,
                     )
-                elif cfg.remat_lm_head:
-                    # Nothing of the [b, s, vocab] softmax survives forward; the
-                    # backward recomputes one vocab matmul instead of re-reading
-                    # a ~bytes(b*s*V*4) buffer. (The unused `logits` above is
-                    # dead-code-eliminated in the training graph, which only
-                    # consumes the loss.)
-                    def head_loss(xf):
-                        lg = embed.attend(xf).astype(jnp.float32)
-                        return _masked_shifted_mean(
-                            optax_softmax_cross_entropy(
-                                lg[:, :-1, :], labels[:, 1:]
-                            ),
-                            segment_ids,
-                        )
-
-                    loss = jax.checkpoint(
-                        head_loss,
-                        policy=jax.checkpoint_policies.nothing_saveable,
-                    )(x)
                 else:
                     loss = _masked_shifted_mean(
                         optax_softmax_cross_entropy(logits[:, :-1, :], labels[:, 1:]),
@@ -1665,7 +1635,7 @@ def pipeline_1f1b_value_and_grad(model: "GPT", mesh, num_microbatches: int):
                 xn = norm_mod.apply({"params": nw_}, yy)
                 return vocab_sharded_shifted_cross_entropy(
                     e_, xn, labels_mb, vocab=vocab, axis_name="stage",
-                    chunk_size=cfg.loss_chunk_size, seq_axis=manual_seq,
+                    seq_axis=manual_seq,
                 )
 
             loss_m, pull = jax.vjp(f, y_bc, e_slice, params["norm"])

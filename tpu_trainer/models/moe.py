@@ -76,6 +76,7 @@ import jax
 import jax.numpy as jnp
 
 from tpu_trainer.models.config import GPTConfig
+from tpu_trainer.ops.dropout import residual_dropout
 from tpu_trainer.ops.grouped_matmul import gmm, gmm_reference, tgmm
 from tpu_trainer.utils import telemetry
 
@@ -560,9 +561,7 @@ class MoEMLP(nn.Module):
             out = self._dropless_ffn(
                 xt, gate_idx, gates, entropy, w_gate, w_up, w_down, act,
             ).reshape(b, s, H)
-            from tpu_trainer.models.gpt import _residual_dropout
-
-            out = _residual_dropout(cfg, self, out, deterministic)
+            out = residual_dropout(self, out, cfg.dropout, deterministic)
             return out, aux.astype(jnp.float32)
 
         if T <= 2 * E:
@@ -669,9 +668,7 @@ class MoEMLP(nn.Module):
             out = jnp.einsum(
                 "tec,ech->th", combine.astype(dtype), expert_out
             ).reshape(b, s, H)
-        from tpu_trainer.models.gpt import _residual_dropout
-
-        out = _residual_dropout(cfg, self, out, deterministic)
+        out = residual_dropout(self, out, cfg.dropout, deterministic)
         return out, aux.astype(jnp.float32)
 
     def _dropless_ffn(self, xt, gate_idx, gates, entropy,

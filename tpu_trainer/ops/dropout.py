@@ -57,3 +57,13 @@ def hash_dropout(
     threshold = jnp.uint32(min(int(rate * 2**32), 2**32 - 1))
     keep = h >= threshold
     return jnp.where(keep, x / (1.0 - rate), jnp.zeros_like(x))
+
+
+def residual_dropout(module, x: jax.Array, rate: float,
+                     deterministic: bool) -> jax.Array:
+    """Residual-stream dropout of a flax ``module`` (reference
+    ``gpt.py:241,282``): :func:`hash_dropout` keyed by the module's
+    ``"dropout"`` RNG stream, which is drawn from only when a mask is."""
+    if deterministic or rate <= 0.0:
+        return x
+    return hash_dropout(x, rate, module.make_rng("dropout"))

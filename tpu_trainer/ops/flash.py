@@ -6,8 +6,7 @@ the reference's call into torch's fused ``scaled_dot_product_attention``
 blockwise-streaming kernel rather than a library call.
 
 Design (flash-attention-2 structure, written for the TPU memory hierarchy).
-What runs where, as measured on a v5e (PERF.md has the runs; the tables of
-benchmarks/results.md predate this runtime and are no evidence):
+What runs where, as measured on a v5e (PERF.md has the runs):
 
 - Grid ``(batch, heads/hp, seq // block_q)`` with ``hp`` heads per program
   (2 for head_dim 64 so the block lane width is 128; 1 for d%128==0).
@@ -50,8 +49,8 @@ benchmarks/results.md predate this runtime and are no evidence):
   blocks stream through an extra grid dimension) and a dq kernel gridded
   over query blocks (dq accumulates while k/v blocks stream). Nothing
   resident scales with s, at the cost of a second score evaluation
-  (7 dots per block pair vs 5). ``backward="fused"|"split"|"auto"`` /
-  ``TPU_TRAINER_FLASH_BWD`` override the dispatch.
+  (7 dots per block pair vs 5). ``backward="fused"|"split"`` addresses
+  one kernel directly (tests); the default picks from the sequence length.
 - Attention-weight dropout runs in-kernel from the core's hardware PRNG
   (compiled) or a counter-based hash (interpret), generated in fixed
   512x512 tiles keyed by absolute position so the backward regenerates
@@ -74,7 +73,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 from typing import Optional
 
 import jax
@@ -85,8 +83,8 @@ from jax.experimental import pallas as pl
 # block (no online-softmax rescaling at all: the kernel's single-block
 # fast path). Longer sequences do NOT run 1024-blocks: `flash_attention`
 # caps streaming at 512 x 512 (the 1024-block streaming forward needs
-# 18.9 MB of the 16 MB scope) unless the caller raised the scoped-VMEM
-# limit, so s=2048 — the benchmark's cells — streams 4 x 4 blocks of 512.
+# 18.9 MB of the 16 MB scope), so s=2048 — the benchmark's cells —
+# streams 4 x 4 blocks of 512.
 # The wrapper also clamps to the sequence length.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
@@ -871,9 +869,9 @@ def _bwd_fused_kernel(
 
 # The fused kernel keeps full-sequence q/do/dq row blocks VMEM-resident,
 # so its footprint grows with s: measured on v5e it fits Mosaic's 16 MB
-# default scope through s=2048 and overflows at s=4096 (the old escape
-# hatch was raising --xla_tpu_scoped_vmem_limit_kib, which steals scope
-# from every other kernel in the step). Past this threshold the dispatch
+# default scope through s=2048 and overflows at s=4096 (raising the
+# compiler's scope limit for the whole process would steal scope from
+# every other kernel in the step). Past this threshold the dispatch
 # selects the two-kernel split backward, whose residency is per-block
 # only (s-independent). Below it the fused kernel wins: one score
 # evaluation feeds dk, dv, AND dq (the split path recomputes scores in
@@ -1178,7 +1176,7 @@ def _flash_backward(q3, k3, v3, o3, lse, do3, seed_f, seg_f, rope, *,
     # evaluation feeds dk, dv, and dq); past that it would overflow the
     # 16 MB default scope, so the split two-kernel path (s-independent
     # VMEM) takes over. ``backward`` in {"fused", "split"} overrides for
-    # the sweep (benchmarks/longseq_block_sweep.py) and the parity tests.
+    # the parity tests, which address each kernel at small s.
     # Segmented instances always take the split path — segments were only
     # taught to the split pair (the fused kernel's one-pass dq residency
     # buys nothing once segment skipping fragments the block walk).
@@ -1490,15 +1488,11 @@ def flash_attention(
     ``backward`` selects the backward kernel: ``"fused"`` (single pass,
     full-row dq residency), ``"split"`` (two-kernel dkv + dq passes,
     s-independent VMEM), or ``None``/``"auto"`` — fused for
-    s <= ``_FUSED_BWD_MAX_SEQ``, split beyond, overridable via the
-    ``TPU_TRAINER_FLASH_BWD`` env var (the sweep's knob).
+    s <= ``_FUSED_BWD_MAX_SEQ``, split beyond.
     """
     b, s, h, d = q.shape
     if dropout_rate > 0.0 and dropout_rng is None:
         raise ValueError("dropout_rate > 0 requires dropout_rng")
-    if backward is None:
-        backward = (os.environ.get("TPU_TRAINER_FLASH_BWD", "").lower()
-                    or None)
     if backward == "auto":
         backward = None
     if backward not in (None, "fused", "split"):
@@ -1546,29 +1540,12 @@ def flash_attention(
     # 16 MB scoped VMEM per software-pipelined iteration; 1024x1024 fits
     # only as the single-block layout (s == block — no pipelining across
     # k blocks). Measured on v5e at s=2048: the 1024-block streaming
-    # forward needs 18.9 MB and OOMs the scope, so DEFAULT streaming caps
-    # at the 512 shape (the round-2 default; the backward already runs
-    # 512s) — UNLESS the caller raised the scoped-VMEM limit via
-    # ``LIBTPU_INIT_ARGS=--xla_tpu_scoped_vmem_limit_kib=...``: under a
-    # raised scope the 1024 blocks fit and measure ~18% faster at s=4096
-    # (benchmarks/longseq_block_sweep.py). Nothing in this repo raises the
-    # flag anymore — the split backward made long sequences fit the
-    # default scope, and bench.py dropped its raise — but an explicit
-    # user raise is still honored. Explicitly-passed block sizes are
-    # always honored.
-    import re as _re
-
-    _m = _re.search(r"scoped_vmem_limit_kib=(\d+)",
-                    os.environ.get("LIBTPU_INIT_ARGS", ""))
-    # 1024-block streaming needs ~19 MB of scope: only an explicit limit
-    # comfortably above that counts as "raised" (a pinned 16 MB default
-    # must still get the 512 cap).
-    scope_raised = _m is not None and int(_m.group(1)) >= 20 * 1024
-    if (not explicit_q and not scope_raised and s > block_q
-            and block_q > 512 and s % 512 == 0):
+    # forward needs 18.9 MB and OOMs the scope, so default streaming caps
+    # at the 512 shape (the backward already runs 512s). Explicitly-passed
+    # block sizes are always honored.
+    if not explicit_q and s > block_q > 512 and s % 512 == 0:
         block_q = 512
-    if (not explicit_k and not scope_raised and s > block_k
-            and block_k > 512 and s % 512 == 0):
+    if not explicit_k and s > block_k > 512 and s % 512 == 0:
         block_k = 512
     # Compiled Mosaic lowering supports d=64 (two heads per program, lane
     # width 128) and d multiples of 128; other head dims take the XLA

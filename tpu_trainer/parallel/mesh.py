@@ -28,8 +28,9 @@ a real mode here.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
-from typing import Optional, Sequence
+from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -46,6 +47,10 @@ STAGE_AXIS = "stage"
 MESH_AXES = (
     DATA_AXIS, FSDP_AXIS, SEQUENCE_AXIS, TENSOR_AXIS, EXPERT_AXIS, STAGE_AXIS,
 )
+# The one axis of a serving replica's decode mesh (``tp_mesh`` below): the
+# paged model step and ``serving/sharding.py`` shard heads and KV pools over
+# it. Not one of the training mesh's axes.
+TP_AXIS = "tp"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -285,6 +290,54 @@ def attention_shard_coord(mesh: Mesh, b_spec, h_spec):
             TENSOR_AXIS
         )
     return coord
+
+
+def validate_tp(num_heads: int, kv_heads: int, tp: int) -> None:
+    """The head-sharding feasibility rule: Q heads split evenly over the
+    mesh, and KV heads either split evenly too or are replicated with
+    whole Q-head groups per device (``tp % kv_heads == 0``)."""
+    if tp < 1:
+        raise ValueError(f"paged_tp={tp} < 1")
+    if tp == 1:
+        return
+    if num_heads % tp:
+        raise ValueError(
+            f"paged_tp={tp} does not divide num_heads={num_heads}")
+    if kv_heads % tp and tp % kv_heads:
+        raise ValueError(
+            f"paged_tp={tp} vs kv_heads={kv_heads}: need kv_heads % tp "
+            f"== 0 (sharded KV) or tp % kv_heads == 0 (replicated KV, "
+            f"GQA)")
+
+
+def resolve_devices(tp: int,
+                    device_ids: Optional[Sequence[int]] = None) -> tuple:
+    """The device set backing a tp-way mesh: explicit ids when the worker
+    spec names them (one fleet, disjoint meshes), else the first ``tp``
+    visible devices."""
+    devs = jax.devices()
+    if device_ids:
+        by_id = {d.id: d for d in devs}
+        missing = [i for i in device_ids if i not in by_id]
+        if missing:
+            raise ValueError(
+                f"device ids {missing} not visible (have "
+                f"{sorted(by_id)}); is XLA_FLAGS="
+                f"--xla_force_host_platform_device_count set?)")
+        devs = [by_id[int(i)] for i in device_ids]
+    if len(devs) < tp:
+        raise ValueError(f"paged_tp={tp} > {len(devs)} visible devices")
+    return tuple(devs[:tp])
+
+
+@functools.lru_cache(maxsize=None)
+def tp_mesh(tp: int,
+            device_ids: Optional[Tuple[int, ...]] = None) -> Mesh:
+    """The (cached) single-axis decode mesh. Caching matters twice over:
+    mesh construction is not free, and the jitted-step memo keys on the
+    config's ``(paged_tp, paged_tp_devices)`` — one mesh object per key
+    keeps placements stable across steps."""
+    return Mesh(np.array(resolve_devices(tp, device_ids)), (TP_AXIS,))
 
 
 def barrier(name: str = "barrier") -> None:

@@ -471,12 +471,12 @@ def plan_single(
 ) -> Dict[str, Any]:
     """``mesh_plan`` record for ONE pinned mesh — no search.
 
-    The predicted-vs-measured validation path: ``bench.py`` scores the mesh
-    it actually ran (explicit ``--mesh-*`` splits, the DP/zero3 table
-    lanes) and writes the record with ``measured_step_ms`` filled, so
-    ``tools/analyze.py`` can gate prediction error on real lanes, not just
+    The predicted-vs-measured validation path: a caller scores the mesh it
+    actually ran and writes the record with ``measured_step_ms`` filled, so
+    ``tools/analyze.py`` can gate prediction error on real runs, not just
     on whatever ``auto`` happened to pick. Same record shape as
     :func:`plan` with a one-entry ranking (trivially its own argmin).
+    No program in the tree calls it today (ROADMAP D12).
     """
     strategy = shard_lib.canonical_strategy(strategy)
     sizes = {ax: axis_sizes.get(ax, 1) for ax in mesh_lib.MESH_AXES}

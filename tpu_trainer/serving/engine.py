@@ -238,9 +238,10 @@ class ServingEngine:
             # per device; the step gathers them back exactly — see
             # serving/sharding.py for why greedy streams stay
             # token-identical).
+            from tpu_trainer.parallel.mesh import tp_mesh
             from tpu_trainer.serving import sharding as tp_lib
 
-            mesh = tp_lib.tp_mesh(tp, self.config.paged_tp_devices)
+            mesh = tp_mesh(tp, self.config.paged_tp_devices)
             self.params = tp_lib.shard_params(self.params, mesh)
             self.device_cache = tp_lib.shard_cache(
                 self.device_cache, mesh, self.config.kv_heads)
@@ -1099,9 +1100,10 @@ def _engine_step(
         # dense compute below is bitwise the single-device compute, and
         # pin the output cache back to the pool layout so the scatter's
         # result never drifts off the committed sharding.
+        from tpu_trainer.parallel.mesh import tp_mesh
         from tpu_trainer.serving import sharding as tp_lib
 
-        mesh = tp_lib.tp_mesh(config.paged_tp, config.paged_tp_devices)
+        mesh = tp_mesh(config.paged_tp, config.paged_tp_devices)
         params = tp_lib.gather_params(params, mesh)
     (logits, _), vars_out = model.apply(
         {"params": params, "cache": cache}, ids, decode=True,

@@ -1,6 +1,7 @@
 """Tensor-parallel (head-sharded) layout helpers for the serving engine.
 
-One replica = one mesh: a single-axis ``("tp",)`` device mesh over which
+One replica = one mesh: a single-axis ``("tp",)`` device mesh (built by
+``parallel/mesh.tp_mesh``, which the model's paged step shares) over which
 the paged KV pools are sharded on their *kv-heads* axis with
 ``NamedSharding``, while block tables, lengths and offsets stay
 replicated host mirrors (scheduling never syncs the device — unchanged).
@@ -24,67 +25,18 @@ engine never pays for them.
 
 from __future__ import annotations
 
-import functools
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-TP_AXIS = "tp"
+from tpu_trainer.parallel.mesh import TP_AXIS
 
 # Cache-collection leaves sharded on their kv-heads axis (axis 2). Every
 # other cache leaf (tables / lengths / offsets) replicates — they are the
 # host-mirror scheduling state.
 _POOL_LEAVES = ("pool_k", "pool_v", "scale_k", "scale_v")
-
-
-def validate_tp(num_heads: int, kv_heads: int, tp: int) -> None:
-    """The head-sharding feasibility rule: Q heads split evenly over the
-    mesh, and KV heads either split evenly too or are replicated with
-    whole Q-head groups per device (``tp % kv_heads == 0``)."""
-    if tp < 1:
-        raise ValueError(f"paged_tp={tp} < 1")
-    if tp == 1:
-        return
-    if num_heads % tp:
-        raise ValueError(
-            f"paged_tp={tp} does not divide num_heads={num_heads}")
-    if kv_heads % tp and tp % kv_heads:
-        raise ValueError(
-            f"paged_tp={tp} vs kv_heads={kv_heads}: need kv_heads % tp "
-            f"== 0 (sharded KV) or tp % kv_heads == 0 (replicated KV, "
-            f"GQA)")
-
-
-def resolve_devices(tp: int,
-                    device_ids: Optional[Sequence[int]] = None) -> tuple:
-    """The device set backing a tp-way mesh: explicit ids when the worker
-    spec names them (one fleet, disjoint meshes), else the first ``tp``
-    visible devices."""
-    devs = jax.devices()
-    if device_ids:
-        by_id = {d.id: d for d in devs}
-        missing = [i for i in device_ids if i not in by_id]
-        if missing:
-            raise ValueError(
-                f"device ids {missing} not visible (have "
-                f"{sorted(by_id)}); is XLA_FLAGS="
-                f"--xla_force_host_platform_device_count set?)")
-        devs = [by_id[int(i)] for i in device_ids]
-    if len(devs) < tp:
-        raise ValueError(f"paged_tp={tp} > {len(devs)} visible devices")
-    return tuple(devs[:tp])
-
-
-@functools.lru_cache(maxsize=None)
-def tp_mesh(tp: int,
-            device_ids: Optional[Tuple[int, ...]] = None) -> Mesh:
-    """The (cached) single-axis decode mesh. Caching matters twice over:
-    mesh construction is not free, and the jitted-step memo keys on the
-    config's ``(paged_tp, paged_tp_devices)`` — one mesh object per key
-    keeps placements stable across steps."""
-    return Mesh(np.array(resolve_devices(tp, device_ids)), (TP_AXIS,))
 
 
 def kv_sharded(kv_heads: int, tp: int) -> bool:

@@ -409,9 +409,10 @@ def _verify_step(
     if config.paged_tp > 1:
         # Sharded replica: exact params all-gather in, pool-layout
         # constraint out — same contract as engine._engine_step.
+        from tpu_trainer.parallel.mesh import tp_mesh
         from tpu_trainer.serving import sharding as tp_lib
 
-        mesh = tp_lib.tp_mesh(config.paged_tp, config.paged_tp_devices)
+        mesh = tp_mesh(config.paged_tp, config.paged_tp_devices)
         params = tp_lib.gather_params(params, mesh)
     (logits, _), vars_out = model.apply(
         {"params": params, "cache": cache}, ids, decode=True,

@@ -3,15 +3,15 @@
     python -m tpu_trainer.tools.analyze run.jsonl
     python -m tpu_trainer.tools.analyze run.jsonl --compare base.jsonl
 
-Turns the stream a training run (or bench.py) emits —
+Turns the stream a training run emits —
 train/eval/goodput/telemetry/cost_analysis/comms_model/recompile/rollback
 records — into a human report: step-time percentiles, tok/s stability,
 the goodput table, spike/rollback/recompile events, and the comms share
-of the step. ``serve`` records (benchmarks/serve_bench.py) and ``decode``
-records (benchmarks/decode_bench.py) fold into the same report, so one
-file can carry a whole train+serve CI run. The elastic supervisor's
-``supervisor.jsonl`` (``host_death`` / ``recovery`` / ``world_grow`` /
-``elastic_summary`` records, see training/elastic.py) folds in too: the
+of the step. ``serve`` records (benchmarks/serve_bench.py) fold into the
+same report, so one file can carry a whole train+serve CI run. The elastic
+supervisor's ``supervisor.jsonl`` (``host_death`` / ``recovery`` /
+``world_grow`` / ``elastic_summary`` records, see training/elastic.py) folds
+in too: the
 report shows each restart's detection-to-first-step recovery time and
 each grow-back's grant-to-first-grown-step time. With ``--compare`` it
 renders PASS/FAIL verdicts for the new run against a baseline run on
@@ -31,6 +31,16 @@ a CI-usable gate over the bench trajectory (exit 0 clean, 1 regression,
 Every record must carry the ``schema_version`` stamp MetricLogger writes;
 unversioned or mismatched records abort with exit 2 so old runs fail
 loudly instead of misparsing.
+
+Who still writes what (PR 30): the training CLIs write the train-side
+kinds above and, under ``--mesh auto``, a ``mesh_plan`` record;
+``training/elastic.py`` the supervisor kinds; ``benchmarks/serve_bench.py``
+``serve`` / ``frontend`` / ``span`` / ``serve_ts`` / ``incident``. Two
+folds have no producer in the tree since the pre-chip harness left:
+``decode`` records, and a ``mesh_plan`` with ``measured_step_ms`` (or a
+train record with ``plan_error_frac``), so the plan gate SKIPs on every
+run a program here can emit; ``tests/test_analyze.py`` feeds both
+hand-made records (ROADMAP D12).
 """
 
 from __future__ import annotations
@@ -204,7 +214,7 @@ def summarize(records: List[dict]) -> dict:
 
     # Mesh auto-planner validation loop (parallel/planner.py): the
     # mesh_plan record carries the chosen split and its predicted step
-    # time; bench train records carry a per-window plan_error_frac, whose
+    # time; train records may carry a per-window plan_error_frac, whose
     # MEDIAN is the number the --plan-tol gate prices. A run with train
     # windows but no mesh_plan record (training CLI --mesh auto runs log
     # the plan but never a measured step-ms) still reports the plan.
@@ -1009,7 +1019,7 @@ def compare(base: dict, new: dict, *, tok_tol: float = 0.10,
 
     ``plan_error_frac`` is ABSOLUTE against a fixed budget, like the
     elastic gates: the mesh auto-planner's median predicted-vs-measured
-    step-time error (parallel/planner.py, bench.py's per-window
+    step-time error (parallel/planner.py, the per-window
     ``plan_error_frac``) must stay under ``plan_tol`` regardless of the
     baseline — a cost model that's 50% off misranks meshes whether or not
     it was 50% off last week. SKIP when the run carries no mesh_plan
@@ -1091,7 +1101,7 @@ def compare(base: dict, new: dict, *, tok_tol: float = 0.10,
         ("mfu_p50", ("train", "mfu", "p50"), "higher", mfu_tol),
         ("peak_mem_gb", ("train", "peak_mem_gb"), "lower", mem_tol),
         ("final_loss", ("train", "final_loss"), "lower", loss_tol),
-        # Serving (serve_bench.py) and decode (decode_bench.py) records:
+        # Serving (serve_bench.py) and decode records:
         # throughput gates share tok_tol; latency gets the looser
         # serve_lat_tol (tail latency is noisier than aggregate tok/s).
         ("serve_tok_per_sec", ("serve", "tokens_per_s"), "higher", tok_tol),
@@ -1171,7 +1181,7 @@ def compare(base: dict, new: dict, *, tok_tol: float = 0.10,
         })
 
     # Planner prediction-quality gate: only a run that actually measured
-    # (bench) carries measured_step_ms; a training CLI --mesh auto run
+    # carries measured_step_ms; a training CLI --mesh auto run
     # logs the plan without one and SKIPs.
     new_plan_err = (get(new, "plan", "plan_error_frac")
                     if get(new, "plan", "measured_step_ms") is not None

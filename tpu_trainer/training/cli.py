@@ -1436,11 +1436,12 @@ def run_training(argv=None, mode: str = "ddp") -> int:
     def consume(entries, check: bool = True):
         """Log, spike-check, and guard each matured metric entry.
         ``check=False`` (exit paths) logs without raising."""
-        for mstep, mmetrics in entries:
+        for mstep, mmetrics, pushed_at in entries:
             src = source_by_step.pop(mstep, None)
             rec = logger.log(
                 mstep, mmetrics,
-                extra=None if src is None else {"data_source": src})
+                extra=None if src is None else {"data_source": src},
+                at=pushed_at)
             if not check:
                 continue
             if spike is not None and rec is not None:

@@ -238,8 +238,15 @@ class MetricLogger:
         self._n_chips = jax.device_count()
         self._peak = device_peak_flops()
 
-    def log(self, step: int, metrics: dict, extra: Optional[dict] = None) -> Optional[dict]:
+    def log(self, step: int, metrics: dict, extra: Optional[dict] = None,
+            at: Optional[float] = None) -> Optional[dict]:
         """Record one step; emit (and return) a record every ``log_interval``.
+
+        ``at`` is the ``time.perf_counter()`` reading at which the step was
+        handed to the device, for a caller that reads its metrics later
+        (``telemetry.DeferredFetcher``): the window's rate is then taken
+        between dispatches, not between the calls of ``log``, which come
+        ``window`` steps late and, at a drain, several at once.
 
         A ``metrics["telemetry"]`` subtree (the trainer's telemetry-step
         output) forces emission regardless of the interval — telemetry
@@ -251,7 +258,7 @@ class MetricLogger:
         if (step + 1) % self.log_interval != 0 and "telemetry" not in metrics:
             return None
 
-        now = time.perf_counter()
+        now = time.perf_counter() if at is None else at
         window_s = max(now - self._window_t, 1e-9)
         tok_per_sec = self._window_tokens / window_s   # windowed, not cumulative (b6)
         record = {

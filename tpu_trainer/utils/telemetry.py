@@ -560,7 +560,10 @@ class DeferredFetcher:
     harmless, because recovery rolls back to a checkpoint that predates
     the spike by far more than ``window`` steps anyway.
 
-    ``push()`` returns the entries that matured this step (oldest first);
+    ``push()`` returns the entries that matured this step (oldest first)
+    as ``(step, host metrics, pushed_at)``, the last the host clock
+    (``time.perf_counter``) at the entry's push, so that a consumer's rates
+    are taken between dispatches and not between maturities;
     ``drain()`` materializes everything (eval/save/rollback/exit
     boundaries, where the state sync already paid the wait). ``transform``
     is applied to the fetched host copy at maturity — fault injections
@@ -576,26 +579,26 @@ class DeferredFetcher:
         return len(self._q)
 
     def push(self, step: int, metrics: dict,
-             transform=None) -> List[Tuple[int, dict]]:
-        self._q.append((step, metrics, transform))
+             transform=None) -> List[Tuple[int, dict, float]]:
+        self._q.append((step, metrics, transform, time.perf_counter()))
         out = []
         while len(self._q) > self.window:
             out.append(self._fetch(self._q.popleft()))
         return out
 
-    def drain(self) -> List[Tuple[int, dict]]:
+    def drain(self) -> List[Tuple[int, dict, float]]:
         out = []
         while self._q:
             out.append(self._fetch(self._q.popleft()))
         return out
 
     @staticmethod
-    def _fetch(entry) -> Tuple[int, dict]:
-        step, metrics, transform = entry
+    def _fetch(entry) -> Tuple[int, dict, float]:
+        step, metrics, transform, pushed_at = entry
         host = jax.device_get(metrics)
         if transform is not None:
             host = transform(host)
-        return step, host
+        return step, host, pushed_at
 
 
 class MetricsBridge:

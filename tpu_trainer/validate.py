@@ -9,7 +9,6 @@ chip. This module re-proves all of them
 with ONE command, meant to run every round after any kernel change::
 
     python -m tpu_trainer.validate --tpu
-    python bench.py --validate          # same lane, driver-friendly
 
 Checks (each prints PASS/FAIL/SKIP; exit code 1 on any failure, and
 without a TPU — nothing here can pass by being skipped):
@@ -30,9 +29,6 @@ without a TPU — nothing here can pass by being skipped):
       the kernel.
 13.   (>=2 devices only; SKIP on one chip) a 1F1B pipeline step on a real
       ``stage`` axis.
-
-Referenced from benchmarks/results.md; replaces the hand-run
-``benchmarks/validate_kernel_tpu.py`` (now a shim over this module).
 """
 
 from __future__ import annotations
