@@ -67,7 +67,7 @@ def _compile(fn, *shapes) -> str:
     (8, 1024, 12, 12, 64, False), (2, 4096, 12, 12, 64, False),
     (4, 2048, 16, 16, 128, False),
     (4, 2048, 15, 5, 64, True), (4, 2048, 32, 32, 64, True),
-    (2, 2048, 8, 2, 128, True)])
+    (2, 2048, 8, 2, 128, True), (4, 1536, 16, 16, 64, True)])
 def test_flash_attention_fwd_and_grad(chip, b, s, h, kvh, d, rope):
     # s=1024: fused backward (the `small` preset's training shape);
     # s=4096: the split dkv/dq backward; d=128: the queued configurations.
@@ -75,6 +75,9 @@ def test_flash_attention_fwd_and_grad(chip, b, s, h, kvh, d, rope):
     # 5 K/V heads, padded and expanded to 16; the 1.7B's 32) and a d=128
     # GQA, all through the streaming forward at 512 x 512 blocks: lane
     # rolls, [block_q, 128] state, K read back from the rotated-K output.
+    # Their gradient is the fused backward's multi-block body (PR 31: the
+    # gradient dots in their d-row form, accumulators transposed in VMEM
+    # scratch); s=1536 walks 3 x 3 blocks, not a power of two.
     q = chip((b, s, h, d), jnp.bfloat16)
     kv = chip((b, s, kvh, d), jnp.bfloat16)
     tabs = (chip((s, d), jnp.float32),) * 2 if rope else ()

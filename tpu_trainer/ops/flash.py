@@ -37,12 +37,28 @@ What runs where, as measured on a v5e (PERF.md has the runs):
 - Operands are the model's FOLDED ``[b, s, h*d]`` layout, sliced per
   head(-pair) by the BlockSpecs: no BSHD transpose ever exists in HBM.
 - Backward DISPATCHES on sequence length. At s <= 2048 it is one fused
-  kernel (grid over key blocks) with its own block shape (512x512: it is
-  FLOP-bound, causal skipping wins): one score/probability evaluation per
-  block pair feeds dk, dv, and dq — dq accumulates in f32 in a
-  VMEM-resident full-row block across sequential grid steps — using the
-  saved per-row logsumexp and the precomputed ``delta = rowsum(dO * O)``.
-  That full-row residency grows with s and overflows Mosaic's 16 MB
+  kernel (grid over key blocks) with its own block shape (512x512: causal
+  skipping wins): one score/probability evaluation per block pair feeds
+  dk, dv, and dq — dq accumulates in f32 in VMEM across the sequential
+  grid steps — using the saved per-row logsumexp and the precomputed
+  ``delta = rowsum(dO * O)``. Its multi-block body (1024 < s <= 2048)
+  works on whole ``[*, hp*d]`` slabs like the streaming forward (K / V
+  with the other head's lanes zeroed against the whole q / do slab) and
+  gives the MXU the cheaper form of each gradient dot. The MXU's time for
+  a dot is its left-hand rows times the 128 x 128 tiles of its right-hand
+  side: ``dv = p^T @ do`` pushes 512 rows through 4 tiles, half of each
+  empty at d=64, where ``dv^T = do^T @ p`` pushes 64 rows through 16. So
+  below 128 lanes a head dk, dv and dq are computed and accumulated
+  TRANSPOSED (``[hp*d, block_k]`` / ``[hp*d, seq]`` f32 scratch, turned
+  back once at the flush) and no ``[512, 512]`` operand is transposed at
+  all; from d=128 on both forms push the same rows and the natural ones
+  stay. PR 31, backward alone, bf16, causal, fused RoPE, ``[4, 2048, 16,
+  64]``: 1.06 ms a call (1.66 us a block pair and head) where per-head
+  64-lane halves and natural forms took 1.50 ms (2.35 us); the slab alone
+  1.33; ``[4, 2048, 32, 64]`` 2.10 ms against 3.00; gradients
+  bit-identical. With any one dot taken out the per-head body read 1.69
+  us: it was bound by the MXU's passes, not by work per score element.
+  The full-row residency grows with s and overflows Mosaic's 16 MB
   default scope past s=2048, so longer sequences take the SPLIT
   two-kernel backward (the FlashAttention-2 structure): a dkv kernel
   gridded over key blocks (dk/dv accumulate in VMEM scratch while q/do
@@ -88,8 +104,12 @@ from jax.experimental import pallas as pl
 # The wrapper also clamps to the sequence length.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
-# The backward is FLOP-bound (5 dots/block, no online rescan): causal
-# block-skipping at 512 measured faster than the single-block layout.
+# The fused backward is bound by its five dots' MXU passes (no online
+# rescan; PR 31: taking any one dot out of the 512-block body took its
+# whole MXU time off the call): causal block-skipping at 512 measured
+# faster than the single-block layout, and 512 x 512 is also what the
+# d-row forms of the gradient dots were measured at (16 weight tiles a
+# dot, 64 rows through each at d=64).
 _BWD_BLOCK = 512
 _LANES = 128  # lane width of a vector register
 _NEG_INF = float("-inf")
@@ -270,6 +290,22 @@ def _unrotate_grad(g, cos, sin):
     gs = g * sin
     rt = jnp.concatenate([gs[..., half:], -gs[..., :half]], axis=-1)
     return g * cos + rt
+
+
+def _unrotate_heads(g, cos, sin, d):
+    """``_unrotate_grad`` of every head of a ``[n, hp*d]`` slab at once
+    (``cos/sin`` tiled to ``[n, hp*d]``): the fused backward's counterpart of
+    ``_rotate_heads``, two lane rolls and a select; the arithmetic and its
+    order are ``_unrotate_grad``'s, so the result is bit-identical to
+    un-rotating head by head."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    w, half = g.shape[-1], d // 2
+    gs = g * sin
+    up = pltpu.roll(gs, w - half, 1)                    # up[j] = gs[j + d/2]
+    down = up if w == d else pltpu.roll(gs, half, 1)    # down[j] = gs[j - d/2]
+    lane = jax.lax.broadcasted_iota(jnp.int32, g.shape, 1)
+    return g * cos + jnp.where(lane % d < half, up, -down)
 
 
 def _seg_predicates(qseg, kseg):
@@ -694,6 +730,14 @@ def _flash_forward(q3, k3, v3, seed_f, seg_f, rope, *, num_heads, head_dim,
 # --------------------------------------------------------------------------
 
 
+def _d_row_form(d: int) -> bool:
+    """Whether the fused backward's multi-block body takes its three gradient
+    dots in their d-row form (``dv^T = do^T @ p`` ...; see
+    ``_bwd_fused_kernel``): below 128 lanes a head it halves the MXU's passes,
+    from 128 on it only loads more weight tiles (7% slower at d=128)."""
+    return d < _LANES
+
+
 def _bwd_fused_kernel(
     seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     block_q, scale, causal, dropout_rate, fuse_rope, hw_prng, hp,
@@ -708,26 +752,24 @@ def _bwd_fused_kernel(
     the first kv block). Compared to separate dq and dk/dv kernels this
     halves the backward's score matmuls and q/do reads.
 
-    With ``fuse_rope``, q/k blocks are re-rotated in VMEM for the score
-    recomputation; dq/dk leave the kernel in *rotated* space and the caller
-    applies the rotation's transpose (``_unrotate_grad``).
+    With ``fuse_rope``, q/k arrive already rotated (the forward's residuals);
+    dq/dk are accumulated in *rotated* space and the rotation's transpose is
+    applied in VMEM before they are written (``_unrotate_grad`` /
+    ``_unrotate_heads``; cos/sin ``[seq, d]`` for the single block, tiled to
+    ``[seq, hp*d]`` for the multi-block body, as in the forward).
     """
     if fuse_rope:
         cos_ref, sin_ref, dq_ref, dk_ref, dv_ref, *scrs = rest
     else:
         dq_ref, dk_ref, dv_ref, *scrs = rest
-    dk_scrs, dv_scrs = scrs[:hp], scrs[hp:]
     block_k = k_ref.shape[1]
-    d = k_ref.shape[2] // hp
+    width = k_ref.shape[2]
+    d = width // hp
     seq = q_ref.shape[1]
     ik = pl.program_id(2)
     k_start = ik * block_k
     seed = _seed_from_ref(seed_ref)
     num_q = seq // block_q
-    # Whole-sequence single block (mirrors the forward's fast path): no
-    # dq accumulation across programs, no scratch round-trips, and the
-    # dropout seed position is the same static (0, 0) the forward used.
-    single = num_q == 1 and seq == block_k
     salt0 = _block_salt()  # hoisted out of the pl.when bodies (see _fwd_kernel)
 
     def head_salt(t):
@@ -736,135 +778,187 @@ def _bwd_fused_kernel(
     # Under fuse_rope the forward already wrote rotated k and
     # rotated-scaled q as outputs (see _fwd_kernel): they arrive here as
     # the residuals, so no per-block re-rotation happens — only the final
-    # unrotate of dq/dk below needs cos/sin.
-    ks = [k_ref[0, :, pl.ds(t * d, d)] for t in range(hp)]
+    # unrotate of dq/dk needs cos/sin. Without it q is scaled by 1/sqrt(d)
+    # at its load: the score recompute then needs no per-element scale, and
+    # dk = sum ds^T @ q_scaled IS the correctly-scaled dk (chain rule puts
+    # one factor of `scale` on each of dq and dk).
+    def scaled(q):
+        if fuse_rope:
+            return q
+        return (q.astype(jnp.float32) * scale).astype(q_ref.dtype)
 
-    def body(iq, t, masked: bool, out=None):
-        # ``iq``/``t`` are static Python ints: the q-block and head loops
-        # are unrolled at trace time with `pl.when` predication per block
-        # (see _fwd_kernel for the measured rationale). q is loaded
-        # pre-scaled by 1/sqrt(d) (folded into the [bq, d] load /
-        # rotation): the score recompute then needs no per-element scale,
-        # and dk = sum ds^T @ q_scaled IS the correctly-scaled dk (chain
-        # rule puts one factor of `scale` on each of dq and dk).
-        k, v = ks[t], v_ref[0, :, pl.ds(t * d, d)]
-        q_start = iq * block_q
-        q = q_ref[0, pl.ds(q_start, block_q), pl.ds(t * d, d)]
-        do = do_ref[0, pl.ds(q_start, block_q), pl.ds(t * d, d)]
-        if not fuse_rope:
-            # fuse_rope residuals arrive pre-scaled (the forward folds
-            # 1/sqrt(d) into the q rotation it writes back).
-            q = (q.astype(jnp.float32) * scale).astype(q_ref.dtype)
-        lse = lse_ref[0, t, 0, pl.ds(q_start, block_q)][:, None]
-        delta = delta_ref[0, t, 0, pl.ds(q_start, block_q)][:, None]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [bq, bk] (scaled via q)
+    contract_lanes = (((1,), (1,)), ((), ()))
+    contract_rows = (((0,), (0,)), ((), ()))
+    f32 = dict(preferred_element_type=jnp.float32)
+
+    def score_grads(t, q, k, v, do, q_start, masked):
+        """Head t's probabilities (after dropout) and score gradient ``ds``
+        for one block pair, both ``[bq, bk]`` in the operands' dtype, from
+        one score evaluation. ``q`` / ``do`` hold rows ``q_start`` on."""
+        rows = pl.ds(q_start, q.shape[0])
+        lse = lse_ref[0, t, 0, rows][:, None]
+        delta = delta_ref[0, t, 0, rows][:, None]
+        s = jax.lax.dot_general(q, k, contract_lanes, **f32)  # scaled via q
         if masked:
             diff = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
                     - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
             s = jnp.where(diff >= k_start - q_start, s, _NEG_INF)
-        p = jnp.exp(s - lse)                       # [bq, bk] (normalized)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        p = jnp.exp(s - lse)                                  # normalized
+        dp = jax.lax.dot_general(do, v, contract_lanes, **f32)
         if dropout_rate > 0.0:
             # p_drop stays unscaled; the 1/(1-rate) folds into dv once at
             # the end ([bk, d] multiply instead of per-element per block).
-            keep = _keep(seed, head_salt(t), iq * block_q, k_start,
-                         block_q, block_k, seq, dropout_rate, hw_prng)
+            keep = _keep(seed, head_salt(t), q_start, k_start, q.shape[0],
+                         block_k, seq, dropout_rate, hw_prng)
             p_drop = jnp.where(keep, p, 0.0)
             dp = jnp.where(keep, dp / (1.0 - dropout_rate), 0.0)
         else:
             p_drop = p
-        dv_new = jax.lax.dot_general(
-            p_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta)                      # [bq, bk]
-        dk_new = jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dq_part = jnp.dot(
-            ds.astype(k.dtype), k, preferred_element_type=jnp.float32
-        ) * scale
-        if out is not None:
-            # Single-block: grads are complete after this one body.
-            out.append((dk_new, dv_new, dq_part))
-        else:
-            # Ref-based accumulation (pl.when bodies must return None).
-            sl = pl.ds(q_start, block_q)
-            dq_ref[0, sl, pl.ds(t * d, d)] += dq_part.astype(dq_ref.dtype)
-            dk_scrs[t][...] += dk_new
-            dv_scrs[t][...] += dv_new
+        return p_drop.astype(do.dtype), (p * (dp - delta)).astype(q.dtype)
 
-    if single:
+    # Whole-sequence single block (mirrors the forward's fast path): no
+    # dq accumulation across programs, no scratch round-trips, the dropout
+    # seed position is the same static (0, 0) the forward used, and heads
+    # run one by one over static column slices.
+    if num_q == 1 and seq == block_k:
         for t in range(hp):
-            out = []
-            body(0, t, masked=causal, out=out)
-            dk, dv, dq = out[0]
+            cols = pl.ds(t * d, d)
+            q, k = scaled(q_ref[0, :, cols]), k_ref[0, :, cols]
+            do = do_ref[0, :, cols]
+            p_drop, ds = score_grads(t, q, k, v_ref[0, :, cols], do, 0,
+                                     causal)
+            dv = jax.lax.dot_general(p_drop, do, contract_rows, **f32)
+            dk = jax.lax.dot_general(ds, q, contract_rows, **f32)
+            dq = jnp.dot(ds, k, **f32) * scale
             if fuse_rope:
                 dq = _unrotate_grad(dq, cos_ref[...], sin_ref[...])
                 dk = _unrotate_grad(dk, cos_ref[...], sin_ref[...])
             if dropout_rate > 0.0:
                 dv = dv / (1.0 - dropout_rate)
-            dq_ref[0, :, pl.ds(t * d, d)] = dq.astype(dq_ref.dtype)
-            dk_ref[0, :, pl.ds(t * d, d)] = dk.astype(dk_ref.dtype)
-            dv_ref[0, :, pl.ds(t * d, d)] = dv.astype(dv_ref.dtype)
+            dq_ref[0, :, cols] = dq.astype(dq_ref.dtype)
+            dk_ref[0, :, cols] = dk.astype(dk_ref.dtype)
+            dv_ref[0, :, cols] = dv.astype(dv_ref.dtype)
         return
+
+    # ---- multi-block path (what s = 2048 runs: 10 of 16 block pairs a
+    # head). As in the streaming forward, the body works on the program's
+    # whole ``[*, hp*d]`` slab and never slices a head out of the lanes; and
+    # it gives the MXU the cheaper of two forms of each gradient dot (PR 31;
+    # the module docstring has what each part measured on a v5e):
+    # - head t's scores and ``dp`` contract the whole q / do slab with a copy
+    #   of K / V whose other heads' lanes are zero, made once a program (its
+    #   K/V block is fixed): a 128-deep contraction costs the MXU what a
+    #   64-deep one does, and the zeros add nothing;
+    # - a dot costs the MXU its left-hand side's rows once per 128 x 128 tile
+    #   of its right-hand side. ``dv = p^T @ do`` pushes ``block_k`` rows
+    #   through ``block_q / 128`` tiles (half of each empty at d = 64), after
+    #   transposing ``p``; the same products as ``dv^T = do^T @ p`` push d
+    #   rows through ``block_q * block_k / 128^2`` tiles: at d = 64 half the
+    #   passes, and no ``[block_q, block_k]`` transpose. So below 128 lanes a
+    #   head (``d_rows``) the three gradient dots take their d-row form —
+    #   ``dv^T = do^T @ p``, ``dk^T = q^T @ ds``, ``dq^T = k^T @ ds^T`` (the
+    #   last contracts both operands' lanes, which the MXU does natively) —
+    #   on sublane slices of the transposed q / do / K slabs, and accumulate
+    #   TRANSPOSED: ``dk^T`` / ``dv^T`` in one ``[hp*d, block_k]`` f32 scratch
+    #   each, ``dq^T`` in a ``[hp*d, seq]`` scratch that lives across the
+    #   sequential kv grid steps; each is transposed back once, at its flush.
+    #   From 128 lanes a head on the two forms push the same rows and the
+    #   d-row form only loads more weight tiles (measured 7% slower at
+    #   d = 128), so there the natural forms stay, as they were.
+    # Either way every product is summed over the same index in the same
+    # order as in a per-head body: the gradients are bit-identical to it.
+    d_rows = _d_row_form(d)
+    assert hp == 1 or d_rows, "heads share a program only below 128 lanes"
+    if d_rows:
+        dk_scr, dv_scr, dq_scr = scrs
+        k_t = k_ref[0].T                                  # [hp*d, bk]
+    else:
+        dk_scr, dv_scr = scrs
+    if hp > 1:
+        k_all, v_all = k_ref[0], v_ref[0]
+        head_of_lane = jax.lax.broadcasted_iota(
+            jnp.int32, (block_k, width), 1) // d
+        ks = [jnp.where(head_of_lane == t, k_all, jnp.zeros_like(k_all))
+              for t in range(hp)]
+        vs = [jnp.where(head_of_lane == t, v_all, jnp.zeros_like(v_all))
+              for t in range(hp)]
+
+    def body(iq: int, masked: bool):
+        # ``iq`` is a static Python int: the q-block loop is unrolled at
+        # trace time with `pl.when` predication per block (see _fwd_kernel
+        # for the measured rationale).
+        q_start = iq * block_q
+        rows = pl.ds(q_start, block_q)
+        q_all = scaled(q_ref[0, rows, :])                 # [bq, hp*d]
+        do_all = do_ref[0, rows, :]
+        if d_rows:
+            q_t, do_t = q_all.T, do_all.T                 # [hp*d, bq]
+        for t in range(hp):
+            # One head a program reads its K/V block where it uses it; a
+            # hoisted value measured 4% slower at d=128.
+            k, v = (ks[t], vs[t]) if hp > 1 else (k_ref[0], v_ref[0])
+            p_drop, ds = score_grads(t, q_all, k, v, do_all, q_start, masked)
+            if d_rows:
+                head = slice(t * d, (t + 1) * d)          # sublanes
+                dv_scr[head, :] += jnp.dot(do_t[head], p_drop, **f32)
+                dk_scr[head, :] += jnp.dot(q_t[head], ds, **f32)
+                dq_scr[head, rows] += jax.lax.dot_general(
+                    k_t[head], ds, contract_lanes, **f32) * scale
+            else:
+                dv_scr[...] += jax.lax.dot_general(
+                    p_drop, do_all, contract_rows, **f32)
+                dk_scr[...] += jax.lax.dot_general(
+                    ds, q_all, contract_rows, **f32)
+                dq_ref[0, rows, :] += jnp.dot(ds, k, **f32) * scale
 
     @pl.when(ik == 0)
     def _zero_dq():
-        dq_ref[...] = jnp.zeros_like(dq_ref)
+        acc = dq_scr if d_rows else dq_ref
+        acc[...] = jnp.zeros_like(acc)
 
-    for t in range(hp):
-        dk_scrs[t][...] = jnp.zeros((block_k, d), jnp.float32)
-        dv_scrs[t][...] = jnp.zeros((block_k, d), jnp.float32)
+    dk_scr[...] = jnp.zeros_like(dk_scr)
+    dv_scr[...] = jnp.zeros_like(dv_scr)
     for iq in range(num_q):
-        q_start = iq * block_q
-
-        def run(masked, iq=iq):
-            for t in range(hp):
-                body(iq, t, masked=masked)
-
         if not causal:
-            run(False)
+            body(iq, False)
             continue
         # needed: the block's last row reaches its first column; full:
         # every element valid. k_start is dynamic (program id), so both
         # predicates are runtime branches on otherwise-static bodies.
+        q_start = iq * block_q
         needed = q_start + block_q - 1 >= k_start
         full = q_start >= k_start + block_k - 1
-        pl.when(full)(functools.partial(run, False))
-        pl.when(needed & jnp.logical_not(full))(functools.partial(run, True))
-    for t in range(hp):
-        dk = dk_scrs[t][...]
-        dv = dv_scrs[t][...]
-        if fuse_rope:
-            # dk leaves the kernel already un-rotated (the rotation's
-            # transpose applied in VMEM) — no external f32
-            # read-modify-write pass.
-            cos_k = cos_ref[pl.ds(k_start, block_k), :]
-            sin_k = sin_ref[pl.ds(k_start, block_k), :]
-            dk = _unrotate_grad(dk, cos_k, sin_k)
-        if dropout_rate > 0.0:
-            dv = dv / (1.0 - dropout_rate)
-        dk_ref[0, :, pl.ds(t * d, d)] = dk.astype(dk_ref.dtype)
-        dv_ref[0, :, pl.ds(t * d, d)] = dv.astype(dv_ref.dtype)
-
+        pl.when(full)(functools.partial(body, iq, False))
+        pl.when(needed & jnp.logical_not(full))(
+            functools.partial(body, iq, True))
+    dk, dv = dk_scr[...], dv_scr[...]
+    if d_rows:
+        dk, dv = dk.T, dv.T                               # [bk, hp*d]
     if fuse_rope:
+        # dk leaves the kernel already un-rotated (the rotation's
+        # transpose applied in VMEM) — no external f32
+        # read-modify-write pass.
+        k_rows = pl.ds(k_start, block_k)
+        dk = _unrotate_heads(dk, cos_ref[k_rows, :], sin_ref[k_rows, :], d)
+    if dropout_rate > 0.0:
+        dv = dv / (1.0 - dropout_rate)
+    dk_ref[0] = dk.astype(dk_ref.dtype)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    if d_rows or fuse_rope:
         # dq finishes accumulating at the last kv grid step (its block index
         # is constant in this grid dimension, so the full-row block is still
-        # VMEM-resident): un-rotate it in place before it is written back.
+        # VMEM-resident): transposed back and un-rotated, block by block,
+        # before it is written back.
         @pl.when(ik == pl.num_programs(2) - 1)
-        def _unrotate_dq():
-            for t in range(hp):
-                dq = dq_ref[0, :, pl.ds(t * d, d)]
-                dq_ref[0, :, pl.ds(t * d, d)] = _unrotate_grad(
-                    dq, cos_ref[...], sin_ref[...]
-                ).astype(dq_ref.dtype)
+        def _finish_dq():
+            for iq in range(num_q):
+                rows = pl.ds(iq * block_q, block_q)
+                dq = dq_scr[:, rows].T if d_rows else dq_ref[0, rows, :]
+                if fuse_rope:
+                    dq = _unrotate_heads(dq, cos_ref[rows, :],
+                                         sin_ref[rows, :], d)
+                dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
 
 
 # The fused kernel keeps full-sequence q/do/dq row blocks VMEM-resident,
@@ -1284,6 +1378,20 @@ def _flash_backward(q3, k3, v3, o3, lse, do3, seed_f, seg_f, rope, *,
     # (its block index is constant in that dimension, so it stays in VMEM).
     # Under fused rope, dq and dk are un-rotated *inside* the kernel (VMEM)
     # before they are written — no external pass over the gradients.
+    scratch = []
+    if not (s == block_q == block_k):
+        # The multi-block body (not the single block) works on the whole
+        # [*, hp*d] slab: one table column per lane, as in the forward, and
+        # one f32 accumulator each for dk and dv — transposed, with a
+        # [hp*d, s] one for dq beside them, where the gradient dots take
+        # their d-row form (see _bwd_fused_kernel).
+        if fuse_rope and hp > 1:
+            rope_args = tuple(jnp.tile(t, (1, hp)) for t in rope_args)
+        if _d_row_form(d):
+            scratch = ([pltpu.VMEM((hp * d, block_k), jnp.float32)] * 2
+                       + [pltpu.VMEM((hp * d, s), jnp.float32)])
+        else:
+            scratch = [pltpu.VMEM((block_k, hp * d), jnp.float32)] * 2
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_fused_kernel, block_q=block_q, scale=scale,
                           causal=causal, dropout_rate=dropout_rate,
@@ -1291,16 +1399,14 @@ def _flash_backward(q3, k3, v3, o3, lse, do3, seed_f, seg_f, rope, *,
         grid=(b, h // hp, s // block_k),
         in_specs=[_seed_spec(), full, kv_blk(block_k), kv_blk(block_k), full,
                   row, row]
-        + (_rope_specs(s, d) if fuse_rope else []),
+        + (_rope_specs(s, rope_args[0].shape[1]) if fuse_rope else []),
         out_specs=[full, blk(block_k), blk(block_k)],
         out_shape=[
             jax.ShapeDtypeStruct((b, s, h * d), jnp.float32),
             jax.ShapeDtypeStruct((b, s, h * d), kv_grad_dtype),
             jax.ShapeDtypeStruct((b, s, h * d), kv_grad_dtype),
         ],
-        scratch_shapes=(
-            [pltpu.VMEM((block_k, d), jnp.float32)] * (2 * hp)
-        ),
+        scratch_shapes=scratch,
         interpret=interpret,
     )(seed_f, q3, k3, v3, do3, lse, delta, *rope_args)
     if group > 1:
