@@ -24,10 +24,12 @@ ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
 
 # Two layers of every kind a cell has: for LFM2 the leading dense conv
-# layer and an attention layer with experts.
+# layer and an attention layer with experts; for the latent-attention family
+# the leading dense layer, a sparse one and the prediction module.
 CUT = {"num_hidden_layers": 2}
-CUT_LFM2 = {**CUT, "num_dense_layers": 1,
-            "layer_types": ["conv", "full_attention"]}
+CUT_FAMILY = {"lfm2_moe": {**CUT, "num_dense_layers": 1,
+                           "layer_types": ["conv", "full_attention"]},
+              "mla_moe": CUT}
 
 
 class _Chip:
@@ -35,8 +37,9 @@ class _Chip:
 
 
 def step_jaxpr(cell: str) -> str:
+    import importlib
+
     from perf import program
-    from perf.families import lfm2_moe
     from perf.runners import train_family
 
     def load(kind, name):
@@ -47,9 +50,11 @@ def step_jaxpr(cell: str) -> str:
     traffic = load("traffic", work["traffic"])
     job = work["job"]
     devices = jax.devices()[:work["chips"]]
-    if cfg.get("family") == "lfm2_moe":
+    if "family" in cfg:
+        family = importlib.import_module(f"perf.families.{cfg['family']}")
         trainer = train_family.build_trainer(
-            lfm2_moe, {**cfg, **CUT_LFM2}, traffic, job, devices)
+            family, {**cfg, **CUT_FAMILY[cfg["family"]]}, traffic, job,
+            devices)
     else:
         trainer = program.build_trainer({**cfg, **CUT}, traffic, job, devices)
     state = jax.eval_shape(trainer._make_state, jax.random.PRNGKey(0))

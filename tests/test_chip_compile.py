@@ -228,6 +228,28 @@ def test_flash_decode(chip, h, kvh, d, int8):
                  q, pool, pool, tables, lengths)
 
 
+@pytest.mark.parametrize("b,s", [(4, 4096), (1, 1024)])
+def test_latent_attention_fwd_and_grad(chip, b, s):
+    """The latent-attention kernels at the published head widths (128 + 64
+    score lanes, the 64 against ONE shared key; 128 value lanes, 32 heads):
+    forward, the split backward, the shared key's per-head partials."""
+    from tpu_trainer.ops.flash_mla import mla_flash_attention
+
+    h = 32
+    shapes = [chip((b, s, h, 128), jnp.bfloat16),
+              chip((b, s, h, 64), jnp.bfloat16),
+              chip((b, s, h, 128), jnp.bfloat16),
+              chip((b, s, 64), jnp.bfloat16),
+              chip((b, s, h, 128), jnp.bfloat16)]
+
+    def loss(*operands):
+        return jnp.sum(mla_flash_attention(
+            *operands, scale=192 ** -0.5).astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), *shapes)
+    assert text.count("tpu_custom_call") >= 3       # fwd, dkv, dq
+
+
 # --- the whole train step: where the compiled kernels say they came from ---
 
 @pytest.fixture(scope="module", params=[1, 4], ids=["one-chip", "fsdp4"])

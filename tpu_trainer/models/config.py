@@ -140,6 +140,37 @@ class GPTConfig:
     # RMSNorm epsilon, every norm of the model.
     norm_eps: float = 1e-6
 
+    # --- Latent attention, shared experts, an untied head, MTP ------------
+    # Again what a published architecture states (the DeepSeek-V3 family's
+    # keys), never a tuning knob; at their defaults nothing changes.
+    # Multi-head latent attention (models/gpt.py LatentAttention) in every
+    # attention layer iff kv_lora_rank is set: queries through a
+    # q_lora_rank latent, keys and values through a kv_lora_rank latent, a
+    # head's scores contracting qk_nope_head_dim un-rotated lanes plus
+    # qk_rope_head_dim rotated ones whose key is ONE head shared by all,
+    # values v_head_dim wide. rope_interleave: the rotation pairs lanes
+    # (2i, 2i+1) instead of (i, i + d/2).
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False
+    # Shared experts beside the routed ones: one SwiGLU of width
+    # moe_shared_experts x the expert width over every token.
+    moe_shared_experts: int = 0
+    # Factor on the routed sum (the sigmoid router's normalised gates), and
+    # the constant added to the chosen scores' sum before the division.
+    moe_routed_scale: float = 1.0
+    moe_gate_eps: float = 1e-6
+    # False: the head is its own [vocab, hidden] matrix (`lm_head`).
+    tie_word_embeddings: bool = True
+    # Multi-token prediction (DeepSeek-V3 section 2.2), depth 1: one more
+    # block over [norm(Emb(t_{i+1})); norm(h_i)] W_eh predicts t_{i+2}
+    # through the model's head; loss = CE + mtp_loss_weight x CE_mtp.
+    mtp_layers: int = 0
+    mtp_loss_weight: float = 0.3
+
     # Optimization flags (reference config.py:30-32)
     use_flash_attention: bool = False
     gradient_checkpointing: bool = False
@@ -389,6 +420,47 @@ class GPTConfig:
             raise ValueError(
                 "the sigmoid router and a held subset of experts run "
                 "through moe_impl='dropless' only")
+        if self.latent_attention:
+            widths = (self.q_lora_rank, self.qk_nope_head_dim,
+                      self.qk_rope_head_dim, self.v_head_dim)
+            if not all(isinstance(w, int) and w > 0 for w in widths) or (
+                    self.qk_rope_head_dim % 2):
+                raise ValueError(
+                    f"latent attention (kv_lora_rank={self.kv_lora_rank}) "
+                    f"needs q_lora_rank, qk_nope_head_dim, an even "
+                    f"qk_rope_head_dim and v_head_dim; got {widths!r}")
+            if self.num_kv_heads not in (None, self.num_heads):
+                raise ValueError(
+                    "latent attention has one key/value per query head "
+                    "(num_kv_heads must be num_heads)")
+            if self.qk_norm or self.attention_dropout > 0.0:
+                raise ValueError(
+                    "latent attention runs without qk_norm and without "
+                    "attention dropout")
+            if self.layer_types is not None and "conv" in self.layer_types:
+                raise ValueError(
+                    "latent attention beside conv layers is not supported")
+        elif self.q_lora_rank is not None or self.rope_interleave:
+            raise ValueError(
+                "q_lora_rank / rope_interleave belong to latent attention "
+                "(set kv_lora_rank)")
+        if self.moe_shared_experts < 0 or (
+                self.moe_shared_experts and (
+                    self.num_experts <= 0 or self.moe_impl != "dropless")):
+            raise ValueError(
+                "moe_shared_experts needs routed experts beside it "
+                "(num_experts > 0, moe_impl='dropless')")
+        if self.moe_routed_scale != 1.0 and self.moe_router != "sigmoid":
+            raise ValueError(
+                "moe_routed_scale scales the sigmoid router's gates")
+        if self.mtp_layers not in (0, 1):
+            raise ValueError(
+                f"mtp_layers ({self.mtp_layers}) must be 0 or 1: the "
+                f"prediction module is built at depth 1")
+        if self.mtp_layers and not self.fused_loss:
+            raise ValueError(
+                "the multi-token-prediction loss runs through the fused "
+                "head + cross entropy (fused_loss)")
         if self.remat_policy not in ("full", "dots"):
             raise ValueError(
                 f"unknown remat_policy {self.remat_policy!r}; "
@@ -404,6 +476,10 @@ class GPTConfig:
         """Resolved K/V head count (num_kv_heads, defaulting to num_heads)."""
         return (self.num_kv_heads if self.num_kv_heads is not None
                 else self.num_heads)
+
+    @property
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank is not None
 
     @property
     def expert_width(self) -> int:
@@ -485,28 +561,47 @@ class GPTConfig:
         attn = 2 * h * h + 2 * h * self.kv_heads * d  # q/o full, k/v grouped
         if self.qk_norm:
             attn += 2 * d
+        if self.latent_attention:
+            heads, rope = self.num_heads, self.qk_rope_head_dim
+            attn = (h * self.q_lora_rank + self.q_lora_rank
+                    + self.q_lora_rank * heads * (self.qk_nope_head_dim + rope)
+                    + h * (self.kv_lora_rank + rope) + self.kv_lora_rank
+                    + self.kv_lora_rank * heads * (
+                        self.qk_nope_head_dim + self.v_head_dim)
+                    + heads * self.v_head_dim * h)
         operator = {"attention": attn,
                     "conv": 3 * h * h + h * CONV_TAPS + h * h}
         router = h * self.num_experts
         if self.moe_router == "sigmoid":
             router += self.num_experts  # the selection bias (a buffer)
         ffn = {"dense": 3 * h * i,
-               "moe": experts_counted * 3 * h * self.expert_width + router}
+               "moe": (experts_counted + self.moe_shared_experts)
+               * 3 * h * self.expert_width + router}
         layers = sum(operator[op] + ffn[f] + 2 * h
                      for op, f in self.layer_kinds())
-        return self.vocab_size * h + layers + h
+        head = 0 if self.tie_word_embeddings else self.vocab_size * h
+        # The prediction module: W_eh, one block of the last layer's kind,
+        # its three norms.
+        last_op, last_ffn = self.layer_kinds()[-1]
+        mtp = self.mtp_layers * (
+            2 * h * h + operator[last_op] + ffn[last_ffn] + 2 * h + 3 * h)
+        return self.vocab_size * h + head + layers + mtp + h
 
     def num_parameters(self) -> int:
         """Exact parameter count of the actual model (what lives here: with
         ``moe_experts_held`` the held experts only).
 
-        embed (tied with lm_head): V*H
+        embed (tied with lm_head): V*H (+ V*H for an untied head)
         per layer: operator — attention 2*H^2 (q/o) + 2*H*(kv_heads*head_dim)
                    (k/v), + 2*head_dim with qk_norm; or the gated short
                    conv 3*H^2 (in) + H*L (taps) + H^2 (out); no bias
                    + FFN: SwiGLU 3*H*I (dense) or E_held*3*H*I_e + H*E router
                    (+ E selection bias under the sigmoid router)
+                   (+ the shared experts' 3*H*I_e each)
                    + 2 RMSNorm weight vectors (2*H)
+        latent attention: the two latents' down- and up-projections with
+                   their norms, and o_proj [heads*v_head_dim, H]
+        MTP module: 2*H^2 + one expert block + 3 norms
         final RMSNorm: H
         """
         return self._parameter_count(self.experts_held[1])

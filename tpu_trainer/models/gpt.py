@@ -41,7 +41,9 @@ import numpy as np
 
 from tpu_trainer.models.config import CONV_TAPS, GPTConfig
 from tpu_trainer.ops import ring
-from tpu_trainer.ops.attention import flash_attention, reference_attention
+from tpu_trainer.ops.attention import (
+    flash_attention, mla_attention, reference_attention,
+)
 from tpu_trainer.ops.dropout import residual_dropout
 from tpu_trainer.ops.loss import (
     fused_shifted_cross_entropy,
@@ -649,6 +651,89 @@ class CausalSelfAttention(nn.Module):
         return out
 
 
+_NO_LATENT_DECODE = (
+    "decode is not supported under latent attention: the cache of [tokens, "
+    "kv_lora_rank + rope] latents and the absorbed decode order are not built")
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2 / -V3 section 2.1), in its
+    training order (nothing absorbed): ``c_q = rms(x W_qa)``, ``q = c_q
+    W_qb`` (a head: ``qk_nope_head_dim`` lanes, then ``qk_rope_head_dim``
+    rotated ones); ``[c_kv | k_r] = x W_kva``, ``c_kv = rms(c_kv)``,
+    ``[k_nope | v] = c_kv W_kvb`` a head; ``k_r`` rotated, ONE head that all
+    share; scores ``(q_nope . k_nope + q_r . k_r) / sqrt(nope + rope)``,
+    causal softmax in f32, ``o = (P v) W_o``. No biases.
+
+    The parameters keep the published layout (``q_b_proj`` ``[q_lora_rank,
+    heads * (nope + rope)]`` ...); what the kernels want is cut out of the
+    WEIGHTS, not the activations: the ``nope`` and ``rope`` columns of
+    ``W_qb`` (and ``k_nope`` / ``v`` of ``W_kvb``) are two matmuls whose
+    results are already folded ``[b, s, heads * w]``, and ``rope_interleave``
+    (the rotation pairs lanes ``(2i, 2i+1)``) is a permutation of the rope
+    columns to the half-split order, the same for q and k, which no score
+    sees. Training and evaluation only."""
+
+    config: GPTConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array, deterministic: bool = True,
+                 decode: bool = False,
+                 segment_ids: Optional[jax.Array] = None) -> jax.Array:
+        cfg = self.config
+        if decode:
+            raise NotImplementedError(_NO_LATENT_DECODE)
+        if segment_ids is not None:
+            raise NotImplementedError(
+                "segment_ids are not supported under latent attention")
+        b, s, _ = x.shape
+        heads, nope, rope, dv = (cfg.num_heads, cfg.qk_nope_head_dim,
+                                 cfg.qk_rope_head_dim, cfg.v_head_dim)
+        cd = cfg.compute_dtype
+        kern = functools.partial(
+            _ProjKernel, param_dtype=cfg.params_dtype,
+            kernel_init=nn.initializers.normal(cfg.initializer_range))
+        norm = functools.partial(RMSNorm, eps=cfg.norm_eps, dtype=cd)
+        half_split = (np.concatenate([np.arange(0, rope, 2),
+                                      np.arange(1, rope, 2)])
+                      if cfg.rope_interleave else np.arange(rope))
+
+        def columns(w, width, cols):
+            """Columns ``cols`` of every head's ``width`` of ``w``."""
+            return w.reshape(w.shape[0], heads, width)[:, :, cols].reshape(
+                w.shape[0], heads * len(cols)).astype(cd)
+
+        x = x.astype(cd)
+        w_qa = kern(cfg.q_lora_rank, name="q_a_proj")(cfg.hidden_size)
+        c_q = norm(name="q_a_layernorm")(x @ w_qa.astype(cd))
+        w_qb = kern(heads * (nope + rope), name="q_b_proj")(cfg.q_lora_rank)
+        q_nope = (c_q @ columns(w_qb, nope + rope, np.arange(nope))
+                  ).reshape(b, s, heads, nope)
+        q_rope = (c_q @ columns(w_qb, nope + rope, nope + half_split)
+                  ).reshape(b, s, heads, rope)
+        w_kva = kern(cfg.kv_lora_rank + rope,
+                     name="kv_a_proj_with_mqa")(cfg.hidden_size)
+        kv_a = x @ w_kva[:, np.concatenate([
+            np.arange(cfg.kv_lora_rank), cfg.kv_lora_rank + half_split])
+        ].astype(cd)
+        c_kv = norm(name="kv_a_layernorm")(kv_a[..., :cfg.kv_lora_rank])
+        k_rope = kv_a[..., cfg.kv_lora_rank:]
+        w_kvb = kern(heads * (nope + dv), name="kv_b_proj")(cfg.kv_lora_rank)
+        k_nope = (c_kv @ columns(w_kvb, nope + dv, np.arange(nope))
+                  ).reshape(b, s, heads, nope)
+        v = (c_kv @ columns(w_kvb, nope + dv, nope + np.arange(dv))
+             ).reshape(b, s, heads, dv)
+
+        cos, sin = rope_tables(s, rope, cfg.rope_theta)
+        q_rope, k_rope = apply_rotary_pos_emb(
+            q_rope, k_rope[:, :, None, :], cos, sin)
+        out = mla_attention(q_nope, q_rope, k_nope, k_rope[:, :, 0], v,
+                            scale=float(nope + rope) ** -0.5)
+        w_o = kern(cfg.hidden_size, name="o_proj")(heads * dv)
+        out = out.reshape(b, s, heads * dv).astype(cd) @ w_o.astype(cd)
+        return residual_dropout(self, out, cfg.dropout, deterministic)
+
+
 class MLP(nn.Module):
     """SwiGLU feed-forward (reference ``gpt.py:245-283``):
     ``down(silu(gate(x)) * up(x))`` + dropout."""
@@ -762,7 +847,9 @@ class TransformerBlock(nn.Module):
         if operator == "conv":
             h = ShortConv(cfg, name="conv")(h, self.deterministic, segment_ids)
         else:
-            h = CausalSelfAttention(cfg, name="attention")(
+            attention = (LatentAttention if cfg.latent_attention
+                         else CausalSelfAttention)
+            h = attention(cfg, name="attention")(
                 h, self.deterministic, self.decode, segment_ids
             )
         attn_out = h
@@ -853,6 +940,57 @@ def _unstack_bwd(_, grads):
 _unstack_layers.defvjp(_unstack_fwd, _unstack_bwd)
 
 
+# ``GPTConfig.remat_policy`` -> what a rematerialised block may keep.
+_REMAT_POLICIES = {"full": None,
+                   "dots": jax.checkpoint_policies.dots_saveable}
+
+
+class MultiTokenPrediction(nn.Module):
+    """The multi-token-prediction module (DeepSeek-V3 section 2.2), depth 1:
+    ``h'_i = [rms_e(Emb(t_{i+1})) ; rms_h(h_i)] W_eh`` with ``h_i`` the main
+    stack's output before its final norm, one more block of the last
+    layer's kind over ``h'`` (its own weights), ``norm'`` of its own, then
+    the MODEL's head; the cross entropy of position ``i`` against
+    ``t_{i+2}``, a mean over the ``seq - 2`` positions that have one. The
+    sequence keeps its length (the last position's input embedding wraps
+    round and nothing reads it: the block is causal and the loss masks its
+    last two positions). The block's auxiliary losses are not added."""
+
+    config: GPTConfig
+    deterministic: bool = True
+
+    @nn.compact
+    def __call__(self, next_embedding, hidden, head, labels):
+        cfg = self.config
+        norm = functools.partial(
+            RMSNorm, eps=cfg.norm_eps, dtype=cfg.compute_dtype)
+        x = nn.Dense(
+            cfg.hidden_size, use_bias=False, dtype=cfg.compute_dtype,
+            param_dtype=cfg.params_dtype,
+            kernel_init=nn.initializers.normal(cfg.initializer_range),
+            name="eh_proj",
+        )(jnp.concatenate([norm(name="enorm")(next_embedding),
+                           norm(name="hnorm")(hidden)], axis=-1))
+        block = TransformerBlock
+        if cfg.gradient_checkpointing:
+            block = nn.remat(block, prevent_cse=False,
+                             policy=_REMAT_POLICIES[cfg.remat_policy])
+        (x, _), stats = block(
+            cfg, deterministic=self.deterministic,
+            kind=cfg.layer_kinds()[-1], name="block",
+        )((x, jnp.zeros((), jnp.float32)), None)
+        if stats is not None:
+            _publish_layer_stats(
+                jax.tree_util.tree_map(lambda a: a[None], stats),
+                key="layers_mtp")
+        x = norm(name="norm")(x)
+        if labels is None:
+            return None
+        with jax.named_scope("head_loss"):
+            return fused_shifted_cross_entropy(
+                head, x, labels, shift=2, allow_pallas=cfg.fused_loss_pallas)
+
+
 class GPT(nn.Module):
     """GPT for causal language modeling (reference ``gpt.py:319-484``)."""
 
@@ -896,16 +1034,23 @@ class GPT(nn.Module):
                 "rms": telemetry.rms(x), "absmax": telemetry.absmax(x),
             })
 
-        policies = {
-            "full": None,
-            "dots": jax.checkpoint_policies.dots_saveable,
-        }
+        policies = _REMAT_POLICIES
         carry0 = (x, jnp.zeros((), jnp.float32))
         from tpu_trainer.parallel import context as ctx_lib
 
         ctx_mesh = ctx_lib.current_mesh()
         stage_n = ctx_mesh.shape.get("stage", 1) if ctx_mesh is not None else 1
         manual_apply = not decode and not self.is_initializing()
+        if cfg.latent_attention and decode:
+            raise NotImplementedError(_NO_LATENT_DECODE)
+        if cfg.latent_attention and ctx_mesh is not None:
+            for axis in ("tensor", ring.SEQ_AXIS, "stage", "expert"):
+                if ctx_mesh.shape.get(axis, 1) > 1:
+                    raise NotImplementedError(
+                        f"latent attention does not run under a {axis!r} "
+                        f"mesh axis > 1: its projections have no tensor "
+                        f"rules, and the ring, the pipeline and the expert "
+                        f"exchange were not taught its operands")
         if cfg.uniform_layers and manual_apply and (
                 stage_n > 1 or cfg.scan_unroll):
             # Shared setup for the two manual apply paths (pipeline and
@@ -1020,6 +1165,7 @@ class GPT(nn.Module):
             )(carry0, segment_ids)
             _publish_layer_stats(layer_telem)
 
+        hidden = x  # the stack's output: what the prediction module reads
         x = RMSNorm(eps=cfg.norm_eps, dtype=cfg.compute_dtype, name="norm")(x)
         if telemetry.capturing():
             telemetry.record("final_norm", {
@@ -1029,8 +1175,16 @@ class GPT(nn.Module):
         # computes them: in a device trace they are `head_loss`, not the
         # anonymous rest of `GPT` (utils/profiling.py).
         with jax.named_scope("head_loss"):
-            # Weight tying (reference gpt.py:342): logits via the embedding matrix.
-            logits = embed.attend(x).astype(jnp.float32)
+            if cfg.tie_word_embeddings:
+                # Weight tying (reference gpt.py:342): logits via the embedding matrix.
+                head = embed.embedding
+                logits = embed.attend(x).astype(jnp.float32)
+            else:
+                head = self.param(
+                    "lm_head", nn.initializers.normal(cfg.initializer_range),
+                    (cfg.vocab_size, cfg.hidden_size), cfg.params_dtype)
+                logits = (x @ head.astype(cfg.compute_dtype).T
+                          ).astype(jnp.float32)
             if telemetry.capturing(deep=True):
                 # Between final_norm and the loss, nan-scan only: making the
                 # logits live here would defeat the fused loss head's
@@ -1051,7 +1205,7 @@ class GPT(nn.Module):
                     # either pass (ops/loss.py; the `logits` above are dead code
                     # in the training graph, which only consumes the loss).
                     loss = fused_shifted_cross_entropy(
-                        embed.embedding, x, labels,
+                        head, x, labels,
                         allow_pallas=cfg.fused_loss_pallas,
                         segment_ids=segment_ids,
                     )
@@ -1065,6 +1219,21 @@ class GPT(nn.Module):
                     # pre-weighted: moe_aux_weight * load-balance +
                     # router_z_weight * z-loss (models/moe.py).
                     loss = loss + moe_aux / cfg.num_layers
+        if cfg.mtp_layers and (labels is not None or self.is_initializing()):
+            # After the main loss, beside it: one more block predicts the
+            # token after next through the same head (training only; a
+            # forward without labels does not run it).
+            if segment_ids is not None:
+                raise NotImplementedError(
+                    "segment_ids are not supported with the multi-token-"
+                    "prediction loss")
+            mtp_loss = MultiTokenPrediction(
+                cfg, deterministic=not train, name="mtp")(
+                    embed(jnp.roll(input_ids, -1, axis=1)), hidden, head,
+                    labels)
+            if labels is not None:
+                telemetry.count("mtp_loss", mtp_loss, reduce="mean")
+                loss = loss + cfg.mtp_loss_weight * mtp_loss
         return logits, loss
 
     def _mixed_layers(self, carry, train, decode, segment_ids, policies,
@@ -1575,10 +1744,7 @@ def pipeline_1f1b_value_and_grad(model: "GPT", mesh, num_microbatches: int):
     needs_rng = cfg.dropout > 0.0 or cfg.attention_dropout > 0.0
     block_mod = TransformerBlock(cfg, deterministic=False)
     norm_mod = RMSNorm(eps=cfg.norm_eps, dtype=cfg.compute_dtype)
-    policies = {
-        "full": None,
-        "dots": jax.checkpoint_policies.dots_saveable,
-    }
+    policies = _REMAT_POLICIES
 
     def grad_fn(params, ids, rng, loss_scale):
         emb = params["embed_tokens"]["embedding"]

@@ -483,6 +483,38 @@ def _bounded_ffn_bwd(how: _FFN, res, g):
 _bounded_ffn.defvjp(_bounded_ffn_fwd, _bounded_ffn_bwd)
 
 
+# The router's Dense. Its module computes in f32 from an f32 kernel, and says
+# so here for whoever keeps a compute-dtype copy of the parameters.
+ROUTER = "router"
+
+
+def computed_in_f32(path_keys) -> bool:
+    """Whether a parameter leaf (the keys of its path) is one an expert
+    layer consumes in float32: a rounded copy of it would make tokens near
+    a tie choose other experts than the same parameters choose elsewhere."""
+    return ROUTER in path_keys
+
+
+class SharedExpert(nn.Module):
+    """The shared experts beside the routed ones: one SwiGLU of width
+    ``moe_shared_experts x expert_width`` over every token, no routing."""
+
+    config: GPTConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        cfg = self.config
+        dense = functools.partial(
+            nn.Dense, use_bias=False, dtype=cfg.compute_dtype,
+            param_dtype=cfg.params_dtype,
+            kernel_init=nn.initializers.normal(cfg.initializer_range))
+        width = cfg.moe_shared_experts * cfg.expert_width
+        act = {"silu": nn.silu, "gelu": nn.gelu}[cfg.activation]
+        mid = act(dense(width, name="gate_proj")(x)) * dense(
+            width, name="up_proj")(x)
+        return dense(cfg.hidden_size, name="down_proj")(mid)
+
+
 class MoEMLP(nn.Module):
     """Top-k routed expert SwiGLU (replaces ``MLP`` when experts are on)."""
 
@@ -507,7 +539,7 @@ class MoEMLP(nn.Module):
             router_logits = nn.Dense(
                 E, use_bias=False, dtype=jnp.float32, param_dtype=jnp.float32,
                 kernel_init=nn.initializers.normal(cfg.initializer_range),
-                name="router",
+                name=ROUTER,
             )(xt.astype(jnp.float32))
             if cfg.moe_router == "sigmoid":
                 # Scores are independent sigmoids; the bias only SELECTS
@@ -520,7 +552,9 @@ class MoEMLP(nn.Module):
                 _, gate_idx = jax.lax.top_k(scores + bias, k)
                 gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
                 gates = gate_vals / (jnp.sum(gate_vals, axis=-1,
-                                             keepdims=True) + 1e-6)
+                                             keepdims=True) + cfg.moe_gate_eps)
+                if cfg.moe_routed_scale != 1.0:
+                    gates = gates * cfg.moe_routed_scale
                 probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
             else:
                 probs = jax.nn.softmax(router_logits, axis=-1)      # [T, E]
@@ -561,6 +595,8 @@ class MoEMLP(nn.Module):
             out = self._dropless_ffn(
                 xt, gate_idx, gates, entropy, w_gate, w_up, w_down, act,
             ).reshape(b, s, H)
+            if cfg.moe_shared_experts:
+                out = out + SharedExpert(cfg, name="shared_expert")(x)
             out = residual_dropout(self, out, cfg.dropout, deterministic)
             return out, aux.astype(jnp.float32)
 

@@ -18,6 +18,7 @@ does for torch's BHSD convention).
 
 from __future__ import annotations
 
+import functools
 import math
 import os
 from typing import Optional
@@ -268,3 +269,55 @@ def flash_attention(
     # jax.nn.dot_product_attention handles grouped K/V natively (K heads
     # dividing N) — pass the compact tensors straight through.
     return jax.nn.dot_product_attention(q, k, v, is_causal=True)
+
+
+def _mla_reference(q_nope, q_rope, k_nope, k_rope, v, scale):
+    """Latent attention in plain jnp (the off-TPU path): float32 scores and
+    softmax, the result in the inputs' dtype."""
+    s = q_nope.shape[1]
+    scores = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope,
+                           preferred_element_type=jnp.float32)) * scale
+    scores = jnp.where(causal_mask(s)[None, None], scores,
+                       jnp.finfo(jnp.float32).min)
+    weights = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
+
+
+def mla_attention(q_nope, q_rope, k_nope, k_rope, v, *, scale: float):
+    """Causal latent attention (MLA): ``softmax((q_nope . k_nope + q_rope .
+    k_rope) * scale) v`` with ``k_rope [b, s, d_rope]`` one head shared by
+    all, q_rope / k_rope already rotated. On TPU (or under
+    ``TPU_TRAINER_FLASH_INTERPRET=1``) the Pallas kernels of
+    ``ops/flash_mla.py``, under ``shard_map`` over the batch axes of a
+    published mesh; plain jnp otherwise, and for a sequence the kernels'
+    blocks do not divide (the parameter initialiser's eight tokens)."""
+    from tpu_trainer.ops.flash_mla import fits, mla_flash_attention
+
+    interpret = os.environ.get(_INTERPRET_ENV, "0") == "1"
+    if not (interpret or any(d.platform == "tpu" for d in jax.devices())
+            ) or not fits(q_nope.shape[1]):
+        return _mla_reference(q_nope, q_rope, k_nope, k_rope, v, scale)
+
+    kernel = functools.partial(
+        mla_flash_attention, scale=scale, interpret=interpret)
+    mesh = _flash_mesh(q_nope)
+    if mesh is None:
+        return kernel(q_nope, q_rope, k_nope, k_rope, v)
+    from jax.sharding import PartitionSpec as P
+
+    from tpu_trainer.parallel.context import kernel_manual_axes
+    from tpu_trainer.parallel.mesh import attention_shard_spec
+    from tpu_trainer.utils.jax_compat import shard_map
+
+    # Batch only: the shared key has no head axis to split.
+    b_spec, _ = attention_shard_spec(mesh, q_nope.shape[0], 1, 1)
+    if b_spec is None:
+        return kernel(q_nope, q_rope, k_nope, k_rope, v)
+    heads, shared = P(b_spec, None, None, None), P(b_spec, None, None)
+    return shard_map(
+        kernel, mesh=mesh, in_specs=(heads, heads, heads, shared, heads),
+        out_specs=heads, axis_names=kernel_manual_axes(mesh, set(b_spec)),
+        check_vma=False,
+    )(q_nope, q_rope, k_nope, k_rope, v)

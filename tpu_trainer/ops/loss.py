@@ -521,8 +521,9 @@ def fused_shifted_cross_entropy(
     chunk_size: int = 0,
     allow_pallas: bool = True,
     segment_ids: jax.Array = None,
+    shift: int = 1,
 ) -> jax.Array:
-    """Mean next-token cross entropy of the tied LM head, logits-free.
+    """Mean next-token cross entropy of the LM head, logits-free.
 
     Semantically identical to
     ``mean(softmax_xent(x @ emb.T [:, :-1], labels[:, 1:]))`` — the
@@ -531,7 +532,8 @@ def fused_shifted_cross_entropy(
     (``ops/head_ce.py``) on compiled TPU where eligible.
 
     Args:
-      emb: tied embedding matrix ``[vocab, hidden]`` (the LM head weight).
+      emb: the LM head weight ``[vocab, hidden]``: the tied embedding
+        matrix, or a head of its own.
       x: final hidden states ``[batch, seq, hidden]`` (post final-norm).
       labels: token ids ``[batch, seq]`` (unshifted; shift happens here).
       chunk_size: sequence-chunk length; 0 = auto (~8k tokens per chunk).
@@ -540,17 +542,20 @@ def fused_shifted_cross_entropy(
       segment_ids: optional ``[batch, seq]`` packed-document ids
         (0 = padding); masks targets that cross a document boundary and
         shrinks the mean's denominator to the surviving targets.
+      shift: position ``i`` is scored against ``labels[i + shift]`` (1: the
+        next token; 2: a multi-token-prediction module's target).
 
     Returns: scalar float32 loss, averaged over the unmasked targets
-    (``batch * (seq - 1)`` without segments).
+    (``batch * (seq - shift)`` without segments).
     """
     b, s, _ = x.shape
     shifted = jnp.concatenate(
-        [labels[:, 1:], jnp.zeros((b, 1), labels.dtype)], axis=1
+        [labels[:, shift:], jnp.zeros((b, shift), labels.dtype)], axis=1
     )
     pos = jax.lax.broadcasted_iota(jnp.int32, (b, s), 1)
-    mask = (pos < s - 1).astype(jnp.float32)
+    mask = (pos < s - shift).astype(jnp.float32)
     if segment_ids is not None:
+        assert shift == 1, "segment masks know the next token only"
         mask = mask * segment_target_mask(segment_ids)
     from tpu_trainer.parallel.context import current_mesh
 

@@ -120,6 +120,12 @@ class ServingEngine:
         kv_link_gbps: float = 16.0,
         role: Optional[str] = None,
     ):
+        if config.latent_attention:
+            raise NotImplementedError(
+                "the serving engine cannot run latent attention: its cache "
+                "managers hold per-head K/V blocks, not [tokens, "
+                "kv_lora_rank + rope] latents, and the absorbed decode "
+                "order is not built")
         if not config.uniform_layers:
             raise NotImplementedError(
                 "the serving engine cannot run a model whose layers differ "

@@ -287,6 +287,14 @@ class Trainer:
                         f"num_dense_layers) does not run under a {axis!r} "
                         f"mesh axis > 1: the pipeline and the ring "
                         f"schedule one stacked block")
+        if self.model_config.latent_attention:
+            for axis in (mesh_lib.TENSOR_AXIS, mesh_lib.SEQUENCE_AXIS,
+                         mesh_lib.STAGE_AXIS, mesh_lib.EXPERT_AXIS):
+                if self.mesh.shape.get(axis, 1) > 1:
+                    raise ValueError(
+                        f"latent attention (kv_lora_rank) trains under "
+                        f"'data' and 'fsdp' mesh axes only; got {axis!r} = "
+                        f"{self.mesh.shape[axis]}")
         self.tp_size = self.mesh.shape[mesh_lib.TENSOR_AXIS]
         if self.tp_size > 1:
             if self.model_config.num_heads % self.tp_size != 0:
@@ -664,13 +672,14 @@ class Trainer:
         """Compute-dtype copy of the >=2-D param leaves (exactly the cast
         the modules apply: Dense/Embed promote their matrices to the
         module dtype; 1-D leaves — RMSNorm weights — stay f32, and so does
-        an expert layer's router, which its module computes in f32: with
-        its kernel rounded, tokens near a tie chose other experts in the
-        step than the same parameters choose anywhere else)."""
+        what an expert layer says it computes in f32, its router
+        (``models/moe.computed_in_f32``))."""
+        from tpu_trainer.models.moe import computed_in_f32
+
         cd = self.model_config.compute_dtype
         return jax.tree_util.tree_map_with_path(
             lambda path, p: p.astype(cd)
-            if p.ndim >= 2 and "router" not in _path_keys(path) else p,
+            if p.ndim >= 2 and not computed_in_f32(_path_keys(path)) else p,
             params)
 
     def _apply_params(self, state: TrainState):
