@@ -228,26 +228,49 @@ def test_flash_decode(chip, h, kvh, d, int8):
                  q, pool, pool, tables, lengths)
 
 
-@pytest.mark.parametrize("b,s", [(4, 4096), (1, 1024)])
+def _latent_shapes(chip, b, s, h=32):
+    """q_nope, q_rope, k_nope, the ONE shared k_rope and v at the published
+    head widths."""
+    return [chip((b, s, h, 128), jnp.bfloat16),
+            chip((b, s, h, 64), jnp.bfloat16),
+            chip((b, s, h, 128), jnp.bfloat16),
+            chip((b, s, 64), jnp.bfloat16),
+            chip((b, s, h, 128), jnp.bfloat16)]
+
+
+@pytest.mark.parametrize("b,s", [(4, 4096), (1, 1024), (1, 13312)])
 def test_latent_attention_fwd_and_grad(chip, b, s):
     """The latent-attention kernels at the published head widths (128 + 64
     score lanes, the 64 against ONE shared key; 128 value lanes, 32 heads):
-    forward, the split backward, the shared key's per-head partials."""
+    forward and the one-pass backward, whose full-row dq stays in VMEM under
+    the kernel's own limit (over Mosaic's default scope from s = 4096 on).
+    13,312 tokens are the most the FORWARD compiles for (its whole-sequence
+    K / V blocks pass the default scope at 13,824), so no length that reaches
+    the backward is left to another one."""
     from tpu_trainer.ops.flash_mla import mla_flash_attention
 
-    h = 32
-    shapes = [chip((b, s, h, 128), jnp.bfloat16),
-              chip((b, s, h, 64), jnp.bfloat16),
-              chip((b, s, h, 128), jnp.bfloat16),
-              chip((b, s, 64), jnp.bfloat16),
-              chip((b, s, h, 128), jnp.bfloat16)]
+    shapes = _latent_shapes(chip, b, s)
 
     def loss(*operands):
         return jnp.sum(mla_flash_attention(
             *operands, scale=192 ** -0.5).astype(jnp.float32))
 
     text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), *shapes)
-    assert text.count("tpu_custom_call") >= 3       # fwd, dkv, dq
+    kernels = re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*?op_name="([^"]*)"', text)
+    assert len(kernels) == 2                        # fwd, one-pass bwd
+    assert sorted("bwd_fused" in k for k in kernels) == [False, True]
+
+
+def test_latent_attention_forward_stops_before_the_backward_does(chip):
+    """One block past 13,312 tokens the forward is refused (VMEM). A forward
+    that learns to go further moves the length above with it: the backward's
+    resident dq rows pass its own limit between 32,768 and 36,864 tokens."""
+    from tpu_trainer.ops.flash_mla import mla_flash_attention
+
+    with pytest.raises(Exception, match="vmem"):
+        _compile(functools.partial(mla_flash_attention, scale=192 ** -0.5),
+                 *_latent_shapes(chip, 1, 13824))
 
 
 # --- the whole train step: where the compiled kernels say they came from ---

@@ -247,14 +247,36 @@ def _operands(seq, dtype, b=1, h=2, nope=128, rope=64, dv=128):
     return ops, w.astype(jnp.float32)
 
 
+# SHA-256 over the five gradients (q_nope, q_rope, k_nope, k_rope, v, as
+# float32) that the SPLIT pair of PR 32 (a dk/dv kernel and a dq kernel, each
+# with its own score evaluation) gave the cases below under the interpreter,
+# recorded on the parent commit of PR 33. The one-pass backward sums every
+# product over the same index in the same order, so with every gradient dot
+# in the split pair's natural form it gives the same bits.
+_SPLIT_PAIR_GRADS = {
+    (256, "float32"):
+        "c9e1c111069bb5910745239d1a31bc3a13558fbef097b427a6538f985b1c6ffc",
+    (1024, "bfloat16"):
+        "2cc37c6d0ab208ba0d740f1ef050f1bd02331def65f16dfeae5d79c5d3448a8f",
+    (1536, "float32"):
+        "319a585862fb7582bad78373ecf0316b2bbc498d1c50bde0f725c82ad1604ff9",
+}
+
+
+@pytest.mark.parametrize("against", ["dense_f32", "split_pair"])
 @pytest.mark.parametrize("seq,dtype,tol", [
     (256, jnp.float32, 2e-6), (1024, jnp.bfloat16, 8e-3),
     pytest.param(1536, jnp.float32, 2e-6, marks=pytest.mark.slow)])
-def test_the_latent_attention_kernels_at_192_and_128(seq, dtype, tol):
-    """Forward and the split backward (one block; 2 x 2 and 3 x 3 blocks of
-    512 with the causal skips and the clamped fetches) against dense float32
-    attention: the result and all five gradients, the shared key's summed
-    over the heads."""
+def test_the_latent_attention_kernels_at_192_and_128(
+        seq, dtype, tol, against, monkeypatch):
+    """Forward and the one-pass backward (one block; 3 and 6 causal pairs of
+    512 x 512 blocks, dq accumulated over a row's K/V blocks in VMEM): the
+    result and all five gradients, the shared key's summed over the heads,
+    against dense float32 attention; and against the split pair before it,
+    bit for bit in the natural forms of the dots, the 64-lane part's d-row
+    forms beside them (``dq_rope`` alone differs, and only here: the CPU's
+    dot sums a contraction of both operands' lanes in another order; the
+    chip gave the same bits, `perf/records/pr33.*variants.json`)."""
     ops, w = _operands(seq, dtype)
     scale = 192 ** -0.5
 
@@ -267,6 +289,16 @@ def test_the_latent_attention_kernels_at_192_and_128(seq, dtype, tol):
 
     with jax.default_matmul_precision("highest"):
         got, grads = jax.value_and_grad(kernel, argnums=(0, 1, 2, 3, 4))(*ops)
+        if against == "split_pair":
+            monkeypatch.setattr(flash_mla, "_d_row_form", lambda width: False)
+            natural = jax.grad(kernel, argnums=(0, 1, 2, 3, 4))(*ops)
+            digest = hashlib.sha256(b"".join(
+                np.asarray(g.astype(jnp.float32)).tobytes() for g in natural))
+            assert digest.hexdigest() == _SPLIT_PAIR_GRADS[
+                seq, jnp.dtype(dtype).name]
+            for i, (g, ng) in enumerate(zip(grads, natural)):
+                assert _rel(g, ng) <= (tol / 4 if i == 1 else 0.0)
+            return
         want, want_grads = jax.value_and_grad(
             dense, argnums=(0, 1, 2, 3, 4))(*ops)
     assert abs(float(got) - float(want)) < 50 * tol * abs(float(want)) + 1.0
@@ -275,21 +307,72 @@ def test_the_latent_attention_kernels_at_192_and_128(seq, dtype, tol):
         assert _rel(g, wg) < tol
 
 
-def test_the_padded_layout_gives_what_the_split_one_gives():
-    """The lab's variant (A), q and k padded to one 256-lane contraction
-    with the shared key broadcast to the heads, through the same bodies."""
-    (qn, qr, kn, kr, v), _ = _operands(256, jnp.float32)
+def _padded(qn, qr, kn, kr, v):
+    """The lab's variant (A): q and k padded to ONE 256-lane per-head part,
+    the shared key broadcast to the heads."""
     b, s, h, _ = qn.shape
-    pad = jnp.zeros((b, s, h, 64))
+    pad = jnp.zeros((b, s, h, 64), qn.dtype)
     q = jnp.concatenate([qn, qr, pad], -1).reshape(b, s, h * 256)
     k = jnp.concatenate([kn, jnp.broadcast_to(kr[:, :, None], qr.shape),
                          pad], -1).reshape(b, s, h * 256)
-    padded = flash_mla.parts_attention(
+    return flash_mla.parts_attention(
         ((q, k),), v.reshape(b, s, -1), shared=(False,), heads=h,
         scale=192 ** -0.5, interpret=True).reshape(v.shape)
+
+
+def test_the_padded_layout_gives_what_the_split_one_gives():
+    """One padded part through the same bodies as the two parts."""
+    ops, _ = _operands(256, jnp.float32)
     split = flash_mla.mla_flash_attention(
-        qn, qr, kn, kr, v, scale=192 ** -0.5, interpret=True)
-    assert float(jnp.max(jnp.abs(padded - split))) < 1e-5
+        *ops, scale=192 ** -0.5, interpret=True)
+    assert float(jnp.max(jnp.abs(_padded(*ops) - split))) < 1e-5
+
+
+def test_the_padded_layout_through_the_one_pass_backward():
+    """The backward is written over the tuple of parts too: one folded
+    256-lane part (no head-major q, no per-head partials of a shared key)
+    over three block pairs gives the gradients the two parts give; the
+    broadcast's transpose sums the shared key's over the heads."""
+    ops, w = _operands(1024, jnp.float32)
+
+    def through(attention):
+        return jax.grad(lambda *a: jnp.sum(attention(*a) * w),
+                        argnums=(0, 1, 2, 3, 4))(*ops)
+
+    split = through(lambda *a: flash_mla.mla_flash_attention(
+        *a, scale=192 ** -0.5, interpret=True))
+    for g, wg in zip(through(_padded), split):
+        assert g.shape == wg.shape and _rel(g, wg) < 1e-5
+
+
+def _pallas_grids(jaxpr):
+    from jax._src import core
+
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield tuple(eqn.params["grid_mapping"].grid)
+        for sub in core.jaxprs_in_params(eqn.params):
+            yield from _pallas_grids(sub)
+
+
+@pytest.mark.parametrize("seq,pairs", [(256, 1), (1024, 3), (4096, 36)])
+def test_the_backward_is_one_call_over_the_causal_pairs(seq, pairs):
+    """Forward + backward trace to two kernels (the split pair made three),
+    and the backward's grid holds the block pairs on and under the diagonal
+    and no other: no step is there to be skipped."""
+    shapes = [jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in (
+        (1, seq, 2, 128), (1, seq, 2, 64), (1, seq, 2, 128), (1, seq, 64),
+        (1, seq, 2, 128))]
+
+    def loss(*a):
+        return jnp.sum(flash_mla.mla_flash_attention(
+            *a, scale=1.0).astype(jnp.float32))
+
+    traced = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(*shapes)
+    blocks = -(-seq // 512)
+    assert list(_pallas_grids(traced.jaxpr)) == [
+        (1, 2, blocks), (1, 2, pairs)]
+    assert "bwd_fused" in str(traced.jaxpr.pretty_print(name_stack=True))
 
 
 def test_a_sequence_the_blocks_do_not_divide_is_refused():

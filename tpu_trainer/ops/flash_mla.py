@@ -5,11 +5,11 @@ q, k and v and rotate the whole head): a head's score contracts
 ``qk_nope_head_dim`` un-rotated lanes of its own key plus
 ``qk_rope_head_dim`` rotated lanes of a key that ALL heads share, and its
 values are ``v_head_dim`` wide (128 + 64 and 128 for the DeepSeek-V3
-family). The kernels here keep the structure of ``ops/flash.py``'s streaming
-forward and split backward (one head a program, 512 x 512 blocks, causal
-block skipping by ``pl.when``, lane-replicated softmax state, bf16 operands
-with f32 accumulation, scores never in HBM), written over a tuple of score
-PARTS so that how the 192 lanes are laid out is data, not code:
+family). The kernels here, a streaming forward and a one-pass backward, keep
+the ways of ``ops/flash.py`` (one head a program, 512 x 512 blocks, causal
+block skipping, lane-replicated softmax state, bf16 operands with f32
+accumulation, scores never in HBM), written over a tuple of score PARTS so
+that how the 192 lanes are laid out is data, not code:
 
 - a per-head part: q and k folded ``[b, s, h*w]``, ``w`` a multiple of 128,
   sliced a head by the BlockSpecs;
@@ -26,9 +26,18 @@ left-hand rows times the 128 x 128 tiles of its right-hand side, so a
 reduction of a padded ``dk`` (PERF.md section 6, PR 32, has both layouts
 measured on a v5e through these same bodies).
 
-The split backward's grids walk every (q block, k block) pair; a pair above
-the diagonal is skipped by ``pl.when``, and its operands' index maps are
-clamped to the diagonal's so that the skipped step moves no block either.
+The backward evaluates a block pair's scores, ``p``, ``dp`` and ``ds`` ONCE
+and feeds ``dv``, every part's ``dk`` and every part's ``dq`` from them:
+eight dots a pair at 128 + 64 lanes, where a dk/dv kernel beside a dq
+kernel pushed eleven (PERF.md section 6, PR 33). Its grid's last axis is the
+list of a head's block pairs on and under the diagonal, K/V block by K/V
+block and each one's q blocks upwards from the diagonal, named by two
+scalar-prefetch tables: no step is there to be skipped. ``dk`` / ``dv``
+accumulate in f32 over a K/V block's walk; a head's whole ``dq`` stays in
+VMEM as f32 rows through all of its walks, each row block summing its K/V
+blocks in ascending order, and is rounded once at the head's last step. That
+residency grows with the sequence, so the kernel asks for its own VMEM limit;
+it compiles for a v5e at every length the forward does.
 
 ``mla_flash_attention`` is the model's entry. RoPE is applied by the caller
 (64 of a head's 192 lanes and one head of k: an elementwise pass outside).
@@ -43,9 +52,10 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
-from tpu_trainer.ops.flash import _LANES, _NEG_INF, _lane_tile
+from tpu_trainer.ops.flash import _LANES, _NEG_INF, _d_row_form, _lane_tile
 
 BLOCK = 512
 _F32 = dict(preferred_element_type=jnp.float32)
@@ -96,12 +106,13 @@ def _rows(ref):
     return ref[0, 0] if len(ref.shape) == 4 else ref[0]
 
 
-def _store(ref, scr):
-    """An accumulator into its output block, whichever layout that has."""
+def _store(ref, rows, at=slice(None)):
+    """Accumulated rows into (rows ``at`` of) their output block, whichever
+    layout that has."""
     if len(ref.shape) == 4:
-        ref[0, 0] = scr[...].astype(ref.dtype)
+        ref[0, 0, at, :] = rows.astype(ref.dtype)
     else:
-        ref[0] = scr[...].astype(ref.dtype)
+        ref[0, at, :] = rows.astype(ref.dtype)
 
 
 def _scaled(q_refs, scale):
@@ -195,9 +206,19 @@ def _forward(parts, v3, *, shared, heads, scale, interpret):
 
 
 # --------------------------------------------------------------------------
-# backward: the split pair. dkv: grid (b, h, k blocks, q blocks), dk / dv
-# accumulate in VMEM across the q walk; dq: grid (b, h, q blocks, k blocks).
+# backward: one pass. Grid (b, h, causal block pairs): a head's pairs on and
+# under the diagonal, K/V block by K/V block, each one's q blocks upwards
+# from the diagonal; two scalar-prefetch tables name a step's blocks.
 # --------------------------------------------------------------------------
+
+# A head's whole dq stays in VMEM through its walk, every part's output rows
+# and f32 accumulator: over 1 KiB a token at 128 + 64 lanes beside a few MiB
+# of blocks and temporaries. That passes Mosaic's default scope of 16 MiB at
+# s = 4096; under this limit (the chip has 128 MiB) the kernel compiles for a
+# v5e through 32,768 tokens (65 MB asked for at 36,864), twice the 13,312 at
+# which the forward's whole-sequence K / V blocks stop.
+_BWD_VMEM_LIMIT = 48 * 1024 * 1024
+
 
 def _probabilities(q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref, scale,
                    masked, q_start, k_start):
@@ -212,62 +233,63 @@ def _probabilities(q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref, scale,
     return qs, ks, do, p.astype(do.dtype), ds.astype(do.dtype)
 
 
-def _bwd_dkv_kernel(*refs, n, scale):
+def _bwd_kernel(ik_of, iq_of, *refs, n, scale, d_rows):
     q_refs, k_refs = refs[:n], refs[n:2 * n]
     v_ref, do_ref, lse_ref, delta_ref = refs[2 * n:2 * n + 4]
-    dk_refs, dv_ref = refs[2 * n + 4:3 * n + 4], refs[3 * n + 4]
-    dk_scrs, dv_scr = refs[3 * n + 5:4 * n + 5], refs[4 * n + 5]
-    block_k, block_q = v_ref.shape[1], do_ref.shape[1]
-    iq = pl.program_id(3)
-    k_start, q_start = pl.program_id(2) * block_k, iq * block_q
+    dq_refs, dk_refs = refs[2 * n + 4:3 * n + 4], refs[3 * n + 4:4 * n + 4]
+    dv_ref = refs[4 * n + 4]
+    dq_scrs, dk_scrs = refs[4 * n + 5:5 * n + 5], refs[5 * n + 5:6 * n + 5]
+    dv_scr = refs[6 * n + 5]
+    block, q_blocks = v_ref.shape[1], dq_scrs[0].shape[0]
+    step = pl.program_id(2)
+    ik, iq = ik_of[step], iq_of[step]
 
-    @pl.when(iq == 0)
-    def _zero():
+    @pl.when(step == 0)
+    def _zero_dq():
+        for scr in dq_scrs:
+            scr[...] = jnp.zeros_like(scr)
+
+    @pl.when(iq == ik)
+    def _zero_dkv():
         for scr in (*dk_scrs, dv_scr):
             scr[...] = jnp.zeros_like(scr)
 
     def body(masked: bool):
-        qs, _, do, p, ds = _probabilities(
+        # One evaluation of the pair's scores, p, dp and ds feeds all of
+        # dv, every part's dk and every part's dq.
+        qs, ks, do, p, ds = _probabilities(
             q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref, scale, masked,
-            q_start, k_start)
+            iq * block, ik * block)
         dv_scr[...] += jax.lax.dot_general(p, do, _CONTRACT_ROWS, **_F32)
-        for scr, q in zip(dk_scrs, qs):
-            scr[...] += jax.lax.dot_general(ds, q, _CONTRACT_ROWS, **_F32)
+        for dk_scr, dq_scr, q, k, d_row in zip(dk_scrs, dq_scrs, qs, ks,
+                                               d_rows):
+            if d_row:   # dk^T = q^T @ ds, dq^T = k^T @ ds^T: w rows, not 512
+                dk_scr[...] += jnp.dot(q.T, ds, **_F32)
+                dq_scr[iq] += jax.lax.dot_general(
+                    k.T, ds, _CONTRACT_LANES, **_F32) * scale
+            else:
+                dk_scr[...] += jax.lax.dot_general(
+                    ds, q, _CONTRACT_ROWS, **_F32)
+                dq_scr[iq] += jnp.dot(ds, k, **_F32) * scale
 
-    _when_needed(body, q_start, k_start, block_q, block_k)
+    # Square blocks: the pair on the diagonal is the masked one.
+    pl.when(iq == ik)(functools.partial(body, True))
+    pl.when(iq != ik)(functools.partial(body, False))
 
-    @pl.when(iq == pl.num_programs(3) - 1)
-    def _flush():
-        for ref, scr in zip((*dk_refs, dv_ref), (*dk_scrs, dv_scr)):
-            _store(ref, scr)
+    def rows(acc, d_row):
+        return acc.T if d_row else acc
 
+    @pl.when(iq == q_blocks - 1)
+    def _flush_dkv():
+        for ref, scr, d_row in zip((*dk_refs, dv_ref), (*dk_scrs, dv_scr),
+                                   (*d_rows, False)):
+            _store(ref, rows(scr[...], d_row))
 
-def _bwd_dq_kernel(*refs, n, scale):
-    q_refs, k_refs = refs[:n], refs[n:2 * n]
-    v_ref, do_ref, lse_ref, delta_ref = refs[2 * n:2 * n + 4]
-    dq_refs, dq_scrs = refs[2 * n + 4:3 * n + 4], refs[3 * n + 4:]
-    block_k, block_q = v_ref.shape[1], do_ref.shape[1]
-    ik = pl.program_id(3)
-    q_start, k_start = pl.program_id(2) * block_q, ik * block_k
-
-    @pl.when(ik == 0)
-    def _zero():
-        for scr in dq_scrs:
-            scr[...] = jnp.zeros_like(scr)
-
-    def body(masked: bool):
-        _, ks, _, _, ds = _probabilities(
-            q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref, scale, masked,
-            q_start, k_start)
-        for scr, k in zip(dq_scrs, ks):
-            scr[...] += jnp.dot(ds, k, **_F32) * scale
-
-    _when_needed(body, q_start, k_start, block_q, block_k)
-
-    @pl.when(ik == pl.num_programs(3) - 1)
-    def _flush():
-        for ref, scr in zip(dq_refs, dq_scrs):
-            _store(ref, scr)
+    @pl.when(step == pl.num_programs(2) - 1)
+    def _flush_dq():
+        for ref, scr, d_row in zip(dq_refs, dq_scrs, d_rows):
+            for j in range(q_blocks):
+                _store(ref, rows(scr[j], d_row), pl.ds(j * block, block))
 
 
 def _backward(parts, v3, o3, lse, do3, *, shared, heads, scale, interpret):
@@ -279,58 +301,58 @@ def _backward(parts, v3, o3, lse, do3, *, shared, heads, scale, interpret):
     delta = jnp.moveaxis(
         (do3.astype(jnp.float32) * o3.astype(jnp.float32))
         .reshape(b, s, heads, dv).sum(axis=-1), 1, 2)[:, :, None, :]
-    operands = (*[q for q, _ in parts], *[k for _, k in parts], v3, do3, lse,
-                delta)
     widths = [k.shape[-1] if sh else k.shape[-1] // heads
               for (_, k), sh in zip(parts, shared)]
-    row = lambda index: pl.BlockSpec(                     # noqa: E731
-        (1, 1, 1, block), lambda ib, ih, *g: (ib, ih, 0, index(*g)))
-
-    # dkv. A q block above the diagonal (iq < ik) is not computed with; its
-    # index is clamped to the diagonal's so that it is not fetched either.
-    k_at = lambda ik, iq: ik                              # noqa: E731
-    q_at = lambda ik, iq: jnp.maximum(iq, ik)             # noqa: E731
+    # Under 128 lanes a part's two gradient dots take their d-row form and
+    # accumulate transposed (``ops/flash.py::_d_row_form``, PR 31): a 64-lane
+    # part then costs the MXU half the passes. The same bits on the chip.
+    d_rows = tuple(_d_row_form(w) for w in widths)
+    # Row-major upper triangle: K/V block ik with q blocks ik, ik + 1, ...
+    # No step is skipped, so none fetches a block it does not use.
+    ik_of, iq_of = (jnp.asarray(a, jnp.int32)
+                    for a in np.triu_indices(s // block))
+    k_at = lambda step, ik_of, iq_of: ik_of[step]         # noqa: E731
+    q_at = lambda step, ik_of, iq_of: iq_of[step]         # noqa: E731
+    whole = lambda *_: 0                                  # noqa: E731
     q_specs, k_specs = _part_specs(parts, shared, heads, block, q_at, block,
                                    k_at)
-    dk_specs = [_spec(w, block, k_at, "head_major" if sh else "folded")
+    dq_specs, _ = _part_specs(parts, shared, heads, s, whole, block, k_at)
+    row = pl.BlockSpec((1, 1, 1, block),
+                       lambda ib, ih, *g: (ib, ih, 0, q_at(*g)))
+    *grads, dv3 = pl.pallas_call(
+        functools.partial(_bwd_kernel, n=n, scale=scale, d_rows=d_rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, heads, len(ik_of)),
+            in_specs=q_specs + k_specs + [
+                _spec(dv, block, k_at, "folded"),
+                _spec(dv, block, q_at, "folded"), row, row],
+            out_specs=dq_specs + [
+                _spec(w, block, k_at, "head_major" if sh else "folded")
                 for w, sh in zip(widths, shared)]
-    *dks, dv3 = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, n=n, scale=scale),
-        grid=(b, heads, s // block, s // block),
-        in_specs=q_specs + k_specs + [
-            _spec(dv, block, k_at, "folded"), _spec(dv, block, q_at, "folded"),
-            row(q_at), row(q_at)],
-        out_specs=dk_specs + [_spec(dv, block, k_at, "folded")],
-        out_shape=[jax.ShapeDtypeStruct(
+            + [_spec(dv, block, k_at, "folded")],
+            scratch_shapes=[          # dq a q block, then dk, then dv
+                pltpu.VMEM(lead + ((w, block) if d_row else (block, w)),
+                           jnp.float32)
+                for lead in ((s // block,), ()) for w, d_row in zip(
+                    widths, d_rows)]
+            + [pltpu.VMEM((block, dv), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype) for q, _ in parts]
+        + [jax.ShapeDtypeStruct(
             (b, heads, s, w) if sh else (b, s, heads * w),
             jnp.float32 if sh else k.dtype)
             for (_, k), w, sh in zip(parts, widths, shared)]
         + [jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
-        scratch_shapes=[pltpu.VMEM((block, w), jnp.float32) for w in widths]
-        + [pltpu.VMEM((block, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT),
         interpret=interpret,
-    )(*operands)
+    )(ik_of, iq_of, *[q for q, _ in parts], *[k for _, k in parts], v3, do3,
+      lse, delta)
     # A shared key's gradient is the sum of what each head gave it.
     dks = [dk.sum(axis=1).astype(k.dtype) if sh else dk
-           for dk, (_, k), sh in zip(dks, parts, shared)]
-
-    # dq; a k block above the diagonal (ik > iq) clamped likewise.
-    q_at = lambda iq, ik: iq                              # noqa: E731
-    k_at = lambda iq, ik: jnp.minimum(ik, iq)             # noqa: E731
-    q_specs, k_specs = _part_specs(parts, shared, heads, block, q_at, block,
-                                   k_at)
-    dqs = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, n=n, scale=scale),
-        grid=(b, heads, s // block, s // block),
-        in_specs=q_specs + k_specs + [
-            _spec(dv, block, k_at, "folded"), _spec(dv, block, q_at, "folded"),
-            row(q_at), row(q_at)],
-        out_specs=q_specs,
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype) for q, _ in parts],
-        scratch_shapes=[pltpu.VMEM((block, w), jnp.float32) for w in widths],
-        interpret=interpret,
-    )(*operands)
-    return tuple(zip(dqs, dks)), dv3
+           for dk, (_, k), sh in zip(grads[n:], parts, shared)]
+    return tuple(zip(grads[:n], dks)), dv3
 
 
 # --------------------------------------------------------------------------
@@ -351,7 +373,9 @@ def _make(shared: Tuple[bool, ...], heads: int, scale: float,
         return o3, (parts, v3, o3, lse)
 
     def bwd(res, do3):
-        return _backward(*res, do3, **kw)
+        # The scope names the kernel in every trace (`bwd_fused.<n>`).
+        with jax.named_scope("bwd_fused"):
+            return _backward(*res, do3, **kw)
 
     attention.defvjp(fwd, bwd)
     return attention
