@@ -65,12 +65,14 @@ def _compile(fn, *shapes) -> str:
 
 @pytest.mark.parametrize("b,s,h,kvh,d,rope", [
     (8, 1024, 12, 12, 64, False), (2, 4096, 12, 12, 64, False),
-    (4, 2048, 16, 16, 128, False),
+    (4, 2048, 16, 16, 128, False), (2, 4096, 32, 2, 128, False),
     (4, 2048, 15, 5, 64, True), (4, 2048, 32, 32, 64, True),
     (2, 2048, 8, 2, 128, True), (4, 1536, 16, 16, 64, True)])
 def test_flash_attention_fwd_and_grad(chip, b, s, h, kvh, d, rope):
     # s=1024: fused backward (the `small` preset's training shape);
-    # s=4096: the split dkv/dq backward; d=128: the queued configurations.
+    # s=4096: the split dkv/dq backward; d=128: the queued configurations,
+    # and the Nemotron cell's micro-batch (32 heads over 2 K/V heads at
+    # 4,096 tokens, nothing rotated).
     # With RoPE fused: the benchmark's two cells (the 360M's 15 heads over
     # 5 K/V heads, padded and expanded to 16; the 1.7B's 32) and a d=128
     # GQA, all through the streaming forward at 512 x 512 blocks: lane
@@ -106,6 +108,28 @@ def test_flash_attention_rope_dropout_fused(chip, s):
         return out.astype(jnp.float32).sum()
 
     _compile(jax.grad(loss, argnums=(0, 1, 2)), x, x, x, tab, tab, key)
+
+
+def test_chunked_scan_fwd_and_grad(chip):
+    """`ops/ssd.py` at the Nemotron cell's micro-batch (2 x 4,096 tokens, 64
+    heads of 64 lanes, 8 groups, state 128, chunk 128): plain XLA, no Pallas
+    kernel; the chip's compiler must take the batched products and fit the
+    `[2, 32, 64, 128, 128]` f32 intermediates of both passes."""
+    from tpu_trainer.ops.ssd import ssd
+
+    b, s, heads, p, groups, n = 2, 4096, 64, 64, 8, 128
+    x = chip((b, s, heads, p), jnp.bfloat16)
+    dt = chip((b, s, heads), jnp.float32)
+    a = chip((heads,), jnp.float32)
+    bc = chip((b, s, groups, n), jnp.bfloat16)
+
+    def loss(x, dt, a, b_in, c_in):
+        y, low = ssd(x, dt, a, b_in, c_in, chunk=128)
+        return y.sum() + low
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        x, dt, a, bc, bc).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8e9
 
 
 @pytest.mark.parametrize("vocab", [50257, 50304])

@@ -33,6 +33,11 @@ _DTYPES = {
 # two earlier positions. One value in use (``conv_L_cache`` 3), so a constant.
 CONV_TAPS = 3
 
+# ``GPTConfig.layer_types``: the sequence operators, and (blocks of one
+# sublayer only) the blocks that are an FFN alone.
+_OPERATORS = ("full_attention", "conv", "mamba")
+_FFN_ONLY = ("moe", "mlp")
+
 
 def dtype_of(name: str):
     """Map a dtype name ('float32' | 'bfloat16' | 'float16') to a jnp dtype."""
@@ -170,6 +175,46 @@ class GPTConfig:
     # through the model's head; loss = CE + mtp_loss_weight x CE_mtp.
     mtp_layers: int = 0
     mtp_loss_weight: float = 0.3
+
+    # --- State-space layers, blocks of one sublayer (Nemotron-H's keys) ---
+    # Again what a published architecture states, never a tuning knob; at
+    # their defaults nothing changes.
+    # Every block holds ONE sublayer with its own pre-norm and residual,
+    # ``h <- h + mixer(norm(h))``: a "full_attention" / "conv" / "mamba"
+    # entry of ``layer_types`` is that operator alone, a "moe" / "mlp" entry
+    # the expert layer / the dense FFN alone (``layer_kinds`` gives "none"
+    # for the half a block lacks).
+    one_sublayer_blocks: bool = False
+    # A head's width where it is not hidden_size // num_heads: q and o are
+    # then [hidden, num_heads * head_dim] and back.
+    attention_head_dim: Optional[int] = None
+    # False: attention applies no rotary embedding (no position signal of
+    # its own: the state-space layers carry order).
+    rotary_embedding: bool = True
+    # The FFN of the dense MLP, the experts and the shared expert: "swiglu"
+    # (down(act(gate x) * up x), three matrices) or "relu2"
+    # (down(relu(up x)^2), two matrices, no gate).
+    ffn_kind: str = "swiglu"
+    # The shared expert's width where it is stated by its own key and not
+    # as moe_shared_experts x the expert width.
+    moe_shared_expert_width: Optional[int] = None
+    # Mamba-2 (models/gpt.py Mamba2Mixer, ops/ssd.py) in every "mamba"
+    # layer: mamba_num_heads heads of mamba_head_dim lanes (their product
+    # is the inner width, whatever hidden_size is), B and C shared by the
+    # heads of each of mamba_n_groups groups, ssm_state_size lanes of state
+    # a head lane, a depthwise causal convolution of mamba_conv_kernel taps
+    # (with bias) over [x, B, C], the scan computed mamba_chunk_size tokens
+    # a chunk. mamba_dt_*: the range the time steps are initialised in
+    # (dt_bias = softplus^-1 of dt log-uniform in [min, max], floored).
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    mamba_n_groups: int = 1
+    mamba_conv_kernel: int = 4
+    mamba_chunk_size: int = 128
+    mamba_dt_min: float = 0.001
+    mamba_dt_max: float = 0.1
+    mamba_dt_floor: float = 1e-4
 
     # Optimization flags (reference config.py:30-32)
     use_flash_attention: bool = False
@@ -311,10 +356,11 @@ class GPTConfig:
     def __post_init__(self):
         if self.intermediate_size is None:
             object.__setattr__(self, "intermediate_size", 4 * self.hidden_size)
-        assert self.hidden_size % self.num_heads == 0, (
-            f"hidden_size ({self.hidden_size}) must be divisible by "
-            f"num_heads ({self.num_heads})"
-        )
+        if self.attention_head_dim is None:
+            assert self.hidden_size % self.num_heads == 0, (
+                f"hidden_size ({self.hidden_size}) must be divisible by "
+                f"num_heads ({self.num_heads})"
+            )
         # num_kv_heads stays None (= num_heads) rather than being
         # materialized: dataclasses.replace(cfg, num_heads=...) must keep
         # working on configs that never asked for GQA. Resolved via the
@@ -388,13 +434,16 @@ class GPTConfig:
         if self.layer_types is not None:
             object.__setattr__(
                 self, "layer_types", tuple(self.layer_types))
+            known = _OPERATORS + (_FFN_ONLY if self.one_sublayer_blocks
+                                  else ())
             if len(self.layer_types) != self.num_layers or any(
-                    t not in ("conv", "full_attention")
-                    for t in self.layer_types):
+                    t not in known for t in self.layer_types):
                 raise ValueError(
-                    f"layer_types must name 'conv' or 'full_attention' for "
-                    f"each of the {self.num_layers} layers; got "
-                    f"{self.layer_types!r}")
+                    f"layer_types must name one of {known!r} for "
+                    f"each of the {self.num_layers} layers ('moe' and "
+                    f"'mlp' are blocks of an FFN alone: "
+                    f"one_sublayer_blocks); got {self.layer_types!r}")
+        self._check_block_keys()
         if not 0 <= self.num_dense_layers <= self.num_layers:
             raise ValueError(
                 f"num_dense_layers ({self.num_dense_layers}) must be in "
@@ -467,9 +516,88 @@ class GPTConfig:
                 f"choose from ['dots', 'full']"
             )
 
+    def _check_block_keys(self):
+        types = self.layer_types or ()
+        if self.one_sublayer_blocks:
+            if self.layer_types is None:
+                raise ValueError(
+                    "one_sublayer_blocks needs layer_types: the sublayer "
+                    "of each block")
+            if self.num_dense_layers or self.mtp_layers:
+                raise ValueError(
+                    "one_sublayer_blocks names each FFN block by its type "
+                    "('moe' / 'mlp'): num_dense_layers does not apply, and "
+                    "the prediction module is a block of two sublayers")
+            if "moe" in types and self.num_experts <= 0:
+                raise ValueError("a 'moe' block needs num_experts > 0")
+        if "mamba" in types:
+            sizes = (self.mamba_num_heads, self.mamba_head_dim,
+                     self.ssm_state_size, self.mamba_n_groups,
+                     self.mamba_conv_kernel, self.mamba_chunk_size)
+            if not all(isinstance(v, int) and v > 0 for v in sizes) or (
+                    self.mamba_num_heads % self.mamba_n_groups):
+                raise ValueError(
+                    f"a 'mamba' layer needs mamba_num_heads (a multiple of "
+                    f"mamba_n_groups), mamba_head_dim, ssm_state_size, "
+                    f"mamba_conv_kernel and mamba_chunk_size; got {sizes!r}")
+            if self.latent_attention:
+                raise ValueError(
+                    "latent attention beside mamba layers is not supported")
+        if self.ffn_kind not in ("swiglu", "relu2"):
+            raise ValueError(
+                f"unknown ffn_kind {self.ffn_kind!r}; choose swiglu or relu2")
+        if self.ffn_kind == "relu2" and self.num_experts > 0 and (
+                self.moe_impl != "dropless"):
+            raise ValueError(
+                "two-matrix relu2 experts run through moe_impl='dropless' "
+                "only")
+        if self.moe_shared_expert_width is not None and (
+                not self.moe_shared_experts):
+            raise ValueError(
+                "moe_shared_expert_width is the width of the shared expert "
+                "(moe_shared_experts >= 1)")
+        if not self.rotary_embedding and (
+                self.latent_attention or self.layer_types is None):
+            raise ValueError(
+                "rotary_embedding=False is for a hybrid stack (layer_types) "
+                "whose other operators carry the order; latent attention "
+                "always rotates")
+
     @property
     def head_dim(self) -> int:
+        if self.attention_head_dim is not None:
+            return self.attention_head_dim
         return self.hidden_size // self.num_heads
+
+    @property
+    def attention_width(self) -> int:
+        """Lanes of all query heads: hidden_size unless a head's width is
+        stated."""
+        return self.num_heads * self.head_dim
+
+    @property
+    def shared_expert_width(self) -> int:
+        return (self.moe_shared_expert_width
+                if self.moe_shared_expert_width is not None
+                else self.moe_shared_experts * self.expert_width)
+
+    @property
+    def ffn_matrices(self) -> int:
+        return 2 if self.ffn_kind == "relu2" else 3
+
+    @property
+    def has_mamba(self) -> bool:
+        return "mamba" in (self.layer_types or ())
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels the taps run over: x, then B and C of every group."""
+        return (self.mamba_inner
+                + 2 * self.mamba_n_groups * self.ssm_state_size)
 
     @property
     def kv_heads(self) -> int:
@@ -501,10 +629,17 @@ class GPTConfig:
 
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
         """(operator, ffn) of each layer in published order: operator
-        "attention" | "conv", ffn "dense" | "moe"."""
+        "attention" | "conv" | "mamba", ffn "dense" | "moe"; under
+        ``one_sublayer_blocks`` one of the two is "none"."""
         ops = self.layer_types or ("full_attention",) * self.num_layers
+        if self.one_sublayer_blocks:
+            return tuple(
+                ("none", "moe" if op == "moe" else "dense")
+                if op in _FFN_ONLY
+                else ("attention" if op == "full_attention" else op, "none")
+                for op in ops)
         return tuple(
-            ("conv" if op == "conv" else "attention",
+            ("attention" if op == "full_attention" else op,
              "moe" if self.num_experts > 0 and i >= self.num_dense_layers
              else "dense")
             for i, op in enumerate(ops))
@@ -558,7 +693,8 @@ class GPTConfig:
     def _parameter_count(self, experts_counted: int) -> int:
         h, i = self.hidden_size, self.intermediate_size
         d = self.head_dim
-        attn = 2 * h * h + 2 * h * self.kv_heads * d  # q/o full, k/v grouped
+        # q/o full, k/v grouped
+        attn = 2 * h * self.attention_width + 2 * h * self.kv_heads * d
         if self.qk_norm:
             attn += 2 * d
         if self.latent_attention:
@@ -569,15 +705,27 @@ class GPTConfig:
                     + self.kv_lora_rank * heads * (
                         self.qk_nope_head_dim + self.v_head_dim)
                     + heads * self.v_head_dim * h)
+        inner, conv_dim = self.mamba_inner, self.mamba_conv_dim
         operator = {"attention": attn,
-                    "conv": 3 * h * h + h * CONV_TAPS + h * h}
+                    "conv": 3 * h * h + h * CONV_TAPS + h * h,
+                    # in_proj [z, xBC, dt]; taps and their bias; dt_bias,
+                    # A_log, D; the gated norm; out_proj
+                    "mamba": (h * (inner + conv_dim + self.mamba_num_heads)
+                              + conv_dim * (self.mamba_conv_kernel + 1)
+                              + 3 * self.mamba_num_heads + inner + inner * h),
+                    "none": 0}
         router = h * self.num_experts
         if self.moe_router == "sigmoid":
             router += self.num_experts  # the selection bias (a buffer)
-        ffn = {"dense": 3 * h * i,
-               "moe": (experts_counted + self.moe_shared_experts)
-               * 3 * h * self.expert_width + router}
-        layers = sum(operator[op] + ffn[f] + 2 * h
+        m = self.ffn_matrices
+        shared = (self.shared_expert_width if self.moe_shared_experts else 0)
+        ffn = {"dense": m * h * i,
+               "moe": m * h * (experts_counted * self.expert_width + shared)
+               + router,
+               "none": 0}
+        # A norm for each sublayer a block holds.
+        layers = sum(operator[op] + ffn[f]
+                     + h * ((op != "none") + (f != "none"))
                      for op, f in self.layer_kinds())
         head = 0 if self.tie_word_embeddings else self.vocab_size * h
         # The prediction module: W_eh, one block of the last layer's kind,
@@ -592,13 +740,20 @@ class GPTConfig:
         ``moe_experts_held`` the held experts only).
 
         embed (tied with lm_head): V*H (+ V*H for an untied head)
-        per layer: operator — attention 2*H^2 (q/o) + 2*H*(kv_heads*head_dim)
+        per layer: operator — attention 2*H*W (q/o, W = heads*head_dim = H
+                   unless a head's width is stated) + 2*H*(kv_heads*head_dim)
                    (k/v), + 2*head_dim with qk_norm; or the gated short
-                   conv 3*H^2 (in) + H*L (taps) + H^2 (out); no bias
-                   + FFN: SwiGLU 3*H*I (dense) or E_held*3*H*I_e + H*E router
+                   conv 3*H^2 (in) + H*L (taps) + H^2 (out); no bias; or
+                   Mamba-2: H*(d_in + conv_dim + heads) (in), conv_dim*(K+1)
+                   (taps and their bias), 3*heads (dt_bias, A_log, D), d_in
+                   (the gated norm), d_in*H (out)
+                   + FFN (m = 3 matrices, SwiGLU, or 2, relu^2): m*H*I
+                   (dense) or E_held*m*H*I_e + H*E router
                    (+ E selection bias under the sigmoid router)
-                   (+ the shared experts' 3*H*I_e each)
-                   + 2 RMSNorm weight vectors (2*H)
+                   (+ the shared experts' m*H*I_e each, or m*H*width where
+                   the shared expert's width is stated)
+                   + a RMSNorm weight vector for each sublayer the block
+                   holds (2*H; H in a block of one sublayer)
         latent attention: the two latents' down- and up-projections with
                    their norms, and o_proj [heads*v_head_dim, H]
         MTP module: 2*H^2 + one expert block + 3 norms

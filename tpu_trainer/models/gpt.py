@@ -49,6 +49,7 @@ from tpu_trainer.ops.loss import (
     fused_shifted_cross_entropy,
     vocab_sharded_shifted_cross_entropy,
 )
+from tpu_trainer.ops.ssd import ssd
 from tpu_trainer.utils import telemetry
 
 
@@ -179,11 +180,11 @@ class CausalSelfAttention(nn.Module):
             # _fused_projection / GPTConfig.fused_projections).
             q, k, v = _fused_projection(
                 cfg, x,
-                [("q_proj", cfg.hidden_size), ("k_proj", kv_features),
+                [("q_proj", cfg.attention_width), ("k_proj", kv_features),
                  ("v_proj", kv_features)],
             )
         else:
-            q = dense(features=cfg.hidden_size, name="q_proj")(x)
+            q = dense(features=cfg.attention_width, name="q_proj")(x)
             k = dense(features=kv_features, name="k_proj")(x)
             v = dense(features=kv_features, name="v_proj")(x)
 
@@ -259,11 +260,15 @@ class CausalSelfAttention(nn.Module):
                     dropout_rate=cfg.attention_dropout,
                     deterministic=deterministic,
                     dropout_rng=dropout_rng,
-                    rope=(cos, sin),
+                    # None: a hybrid stack whose other operators carry the
+                    # order (the ring paths above are not reached by one:
+                    # GPT._mixed_layers refuses a sequence axis).
+                    rope=(cos, sin) if cfg.rotary_embedding else None,
                     segment_ids=segment_ids,
                 )
             else:
-                q, k = apply_rotary_pos_emb(q, k, cos, sin)
+                if cfg.rotary_embedding:
+                    q, k = apply_rotary_pos_emb(q, k, cos, sin)
                 out = reference_attention(
                     q, k, v,
                     dropout_rate=cfg.attention_dropout,
@@ -272,7 +277,7 @@ class CausalSelfAttention(nn.Module):
                     segment_ids=segment_ids,
                 )
 
-        out = out.reshape(b, s, cfg.hidden_size)
+        out = out.reshape(b, s, cfg.attention_width)
         out = dense(features=cfg.hidden_size, name="o_proj")(out)
         out = residual_dropout(self, out, cfg.dropout, deterministic)
         return out
@@ -750,6 +755,12 @@ class MLP(nn.Module):
             param_dtype=cfg.params_dtype,
             kernel_init=nn.initializers.normal(cfg.initializer_range),
         )
+        if cfg.ffn_kind == "relu2":
+            # Two matrices, no gate: down(relu(up x)^2).
+            x = jnp.square(nn.relu(dense(cfg.intermediate_size,
+                                         name="up_proj")(x)))
+            x = dense(cfg.hidden_size, name="down_proj")(x)
+            return residual_dropout(self, x, cfg.dropout, deterministic)
         if _use_fused_projections(cfg):
             # gate+up as one [H, 2I] matmul (see _fused_projection).
             gate, up = _fused_projection(
@@ -773,7 +784,7 @@ class ShortConv(nn.Module):
     ``v_t = sum_j w[:, j] * z_{t-(L-1-j)}`` (zeros before the sequence, and
     before a packed document's first token), then ``(C * v) W_out``. No
     bias, no activation. Plain ``jax.numpy``: L - 1 shifted multiply-adds
-    beside two matmuls."""
+    (``causal_taps``) beside two matmuls."""
 
     config: GPTConfig
 
@@ -797,16 +808,153 @@ class ShortConv(nn.Module):
             lambda key, shape, dtype: jax.random.uniform(
                 key, shape, dtype, -bound, bound),
             (hidden, taps), cfg.params_dtype).astype(cfg.compute_dtype)
-        v = z * weight[:, taps - 1]
-        for back in range(1, taps):
-            shifted = jnp.pad(z, ((0, 0), (back, 0), (0, 0)))[:, :-back]
-            if segment_ids is not None:
-                same = segment_ids == jnp.pad(
-                    segment_ids, ((0, 0), (back, 0)),
-                    constant_values=-1)[:, :-back]
-                shifted = jnp.where(same[..., None], shifted, 0)
-            v = v + shifted * weight[:, taps - 1 - back]
+        v = causal_taps(z, weight, segment_ids=segment_ids)
         out = dense(hidden, name="out_proj")(gate_out * v)
+        return residual_dropout(self, out, cfg.dropout, deterministic)
+
+
+def causal_taps(z, weight, bias=None, segment_ids=None):
+    """Depthwise causal convolution over the sequence: ``v_t = sum_j w[:, j]
+    z_{t-(L-1-j)}`` (+ bias), ``z [batch, seq, channels]``, ``weight
+    [channels, L]`` with the last tap on the current position (torch's
+    depthwise Conv1d layout); zeros before the sequence, and before a packed
+    document's first token. ``L - 1`` shifted multiply-adds."""
+    taps = weight.shape[1]
+    v = z * weight[:, taps - 1]
+    for back in range(1, taps):
+        shifted = jnp.pad(z, ((0, 0), (back, 0), (0, 0)))[:, :-back]
+        if segment_ids is not None:
+            same = segment_ids == jnp.pad(
+                segment_ids, ((0, 0), (back, 0)),
+                constant_values=-1)[:, :-back]
+            shifted = jnp.where(same[..., None], shifted, 0)
+        v = v + shifted * weight[:, taps - 1 - back]
+    return v if bias is None else v + bias
+
+
+def _mamba_dt_bias(cfg: GPTConfig):
+    """Mamba-2's initialiser of ``dt_bias``: the inverse softplus of time
+    steps log-uniform in ``[mamba_dt_min, mamba_dt_max]``, floored."""
+    lo, hi = np.log(cfg.mamba_dt_min), np.log(cfg.mamba_dt_max)
+
+    def init(key, shape, dtype):
+        dt = jnp.maximum(jnp.exp(jax.random.uniform(
+            key, shape, jnp.float32, lo, hi)), cfg.mamba_dt_floor)
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+    return init
+
+
+class _Weight(nn.Module):
+    """A ``[width]`` vector of ones under ``<name>/weight``: a norm's weight
+    for a caller that applies the norm itself."""
+
+    width: int
+
+    @nn.compact
+    def __call__(self) -> jax.Array:
+        return self.param("weight", nn.initializers.ones, (self.width,),
+                          jnp.float32)
+
+
+def _mamba_core(cfg: GPTConfig, zxbcdt, conv_weight, conv_bias, dt_bias,
+                a_log, skip, norm_weight):
+    """A Mamba-2 mixer between its two projections: ``[z, xBC, dt]`` in,
+    the gated, group-normalised ``y [batch, seq, d_in]`` out (and the scan's
+    most negative in-chunk log-decay). One function so that the mixer can
+    recompute it in the backward (``Mamba2Mixer``)."""
+    b, s, _ = zxbcdt.shape
+    heads, p = cfg.mamba_num_heads, cfg.mamba_head_dim
+    groups, n = cfg.mamba_n_groups, cfg.ssm_state_size
+    inner, conv_dim = cfg.mamba_inner, cfg.mamba_conv_dim
+    cd, f32 = cfg.compute_dtype, jnp.float32
+    z, xbc, dt = jnp.split(zxbcdt, [inner, inner + conv_dim], axis=-1)
+    with jax.named_scope("taps"):
+        xbc = nn.silu(causal_taps(xbc, conv_weight.astype(cd),
+                                  conv_bias.astype(cd)))
+    x, b_in, c_in = jnp.split(xbc, [inner, inner + groups * n], axis=-1)
+    dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
+    x = x.reshape(b, s, heads, p)
+    y, low = ssd(x, dt, -jnp.exp(a_log.astype(f32)),
+                 b_in.reshape(b, s, groups, n), c_in.reshape(b, s, groups, n),
+                 chunk=cfg.mamba_chunk_size, dtype=cd)
+    y = (y + skip.astype(f32)[:, None] * x.astype(f32)).astype(cd)
+    # The gate first, then the norm, over each group's lanes in f32.
+    gated = (y.reshape(b, s, inner) * nn.silu(z)).astype(f32).reshape(
+        b, s, groups, inner // groups)
+    normed = gated * jax.lax.rsqrt(
+        jnp.mean(jnp.square(gated), axis=-1, keepdims=True) + cfg.norm_eps)
+    normed = normed.reshape(b, s, inner) * norm_weight.astype(f32)
+    return normed.astype(cd), low
+
+
+class Mamba2Mixer(nn.Module):
+    """The Mamba-2 state-space mixer (arXiv:2405.21060; Nemotron-H's layer,
+    arXiv:2504.03624), the sequence operator of a ``mamba`` layer. With ``H``
+    heads of ``P`` lanes (``d_in = H P``), ``G`` groups and ``N`` lanes of
+    state: ``[z, xBC, dt] = u W_in`` (``d_in``, ``d_in + 2 G N``, ``H`` wide,
+    in that order); ``xBC <- silu(causal depthwise conv with bias)``; ``[x,
+    B, C] = xBC``; ``dt <- softplus(dt + dt_bias)``; ``A = -exp(A_log)``;
+    per head ``h`` of group ``h // (H / G)`` the scan ``S_t = exp(dt_t A_h)
+    S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t C_t + D_h x_t`` (``ops/ssd.py``,
+    chunked); ``y <- RMSNorm_groups(y * silu(z))``, the gate first, then a
+    norm over each group's ``d_in / G`` lanes times a ``[d_in]`` weight;
+    ``out = y W_out``. No bias but the conv's.
+
+    What lies between the two projections (``_mamba_core``) is recomputed
+    in the backward from ``[z, xBC, dt]``, as the family's fused kernels do:
+    the taps, their silu, the scan's ``[chunk, chunk]`` scores and decays
+    (64 x the size of ``x``), the gate and the norm never live between the
+    passes; a block keeps its in-projection's result and the normalised
+    ``y``.
+
+    ``A_log``, ``dt_bias`` and ``D`` are consumed in float32
+    (``F32_LEAVES``: whoever keeps a compute-type copy of the parameters
+    leaves them as they are), and ``A_log`` and ``D`` take no weight decay
+    (``UNDECAYED``, the family's convention). Training and evaluation
+    only."""
+
+    config: GPTConfig
+    F32_LEAVES = ("A_log", "dt_bias", "D")
+    UNDECAYED = ("A_log", "D")
+
+    @nn.compact
+    def __call__(self, u: jax.Array, deterministic: bool = True,
+                 segment_ids: Optional[jax.Array] = None) -> jax.Array:
+        cfg = self.config
+        if segment_ids is not None:
+            raise NotImplementedError(
+                "segment_ids are not supported under mamba layers: the scan "
+                "does not reset its state at a packed document's first token")
+        heads = cfg.mamba_num_heads
+        inner, conv_dim = cfg.mamba_inner, cfg.mamba_conv_dim
+        dense = functools.partial(
+            nn.Dense, use_bias=False, dtype=cfg.compute_dtype,
+            param_dtype=cfg.params_dtype,
+            kernel_init=nn.initializers.normal(cfg.initializer_range))
+        zxbcdt = dense(inner + conv_dim + heads, name="in_proj")(u)
+        bound = cfg.mamba_conv_kernel ** -0.5
+
+        def uniform(key, shape, dtype):
+            return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+        f32 = jnp.float32
+        leaves = (
+            self.param("conv_weight", uniform,
+                       (conv_dim, cfg.mamba_conv_kernel), cfg.params_dtype),
+            self.param("conv_bias", uniform, (conv_dim,), cfg.params_dtype),
+            self.param("dt_bias", _mamba_dt_bias(cfg), (heads,), f32),
+            self.param("A_log", lambda key, shape, dtype: jnp.log(
+                jax.random.uniform(key, shape, dtype, 1.0, 16.0)),
+                (heads,), f32),
+            self.param("D", nn.initializers.ones, (heads,), f32),
+            _Weight(inner, name="norm")())
+        y, low = jax.checkpoint(functools.partial(_mamba_core, cfg))(
+            zxbcdt, *leaves)
+        telemetry.count("ssm_tokens",
+                        jnp.asarray(u.shape[0] * u.shape[1], f32))
+        telemetry.count("ssd_min_log_decay", low, reduce="min")
+        out = dense(cfg.hidden_size, name="out_proj")(y)
         return residual_dropout(self, out, cfg.dropout, deterministic)
 
 
@@ -827,9 +975,9 @@ class TransformerBlock(nn.Module):
     config: GPTConfig
     deterministic: bool = True
     decode: bool = False
-    # The layer's kind where layers differ (GPTConfig.layer_kinds); None =
-    # the uniform stack's block: attention, and experts iff the model has
-    # them.
+    # The layer's kind where layers differ (GPTConfig.layer_kinds; "none"
+    # for the sublayer a block of one sublayer lacks); None = the uniform
+    # stack's block: attention, and experts iff the model has them.
     kind: Optional[Tuple[str, str]] = None
 
     @nn.compact
@@ -842,47 +990,54 @@ class TransformerBlock(nn.Module):
         norm = functools.partial(
             RMSNorm, eps=cfg.norm_eps, dtype=cfg.compute_dtype)
         x, aux = carry
-        residual = x
-        h = norm(name=norm_names[0])(x)
-        if operator == "conv":
-            h = ShortConv(cfg, name="conv")(h, self.deterministic, segment_ids)
-        else:
-            attention = (LatentAttention if cfg.latent_attention
-                         else CausalSelfAttention)
-            h = attention(cfg, name="attention")(
-                h, self.deterministic, self.decode, segment_ids
-            )
-        attn_out = h
-        x = residual + h
+        attn_out = ffn_out = None
+        # The sublayers' step counters (models/moe.py, Mamba2Mixer) leave
+        # the layer loop beside the telemetry, under their own key, whether
+        # or not telemetry is being captured.
+        with (telemetry.counters() if telemetry.counting()
+              else contextlib.nullcontext()) as counts:
+            if operator != "none":
+                residual = x
+                h = norm(name=norm_names[0])(x)
+                if operator == "conv":
+                    h = ShortConv(cfg, name="conv")(
+                        h, self.deterministic, segment_ids)
+                elif operator == "mamba":
+                    h = Mamba2Mixer(cfg, name="mamba")(
+                        h, self.deterministic, segment_ids)
+                else:
+                    attention = (LatentAttention if cfg.latent_attention
+                                 else CausalSelfAttention)
+                    h = attention(cfg, name="attention")(
+                        h, self.deterministic, self.decode, segment_ids
+                    )
+                attn_out = h
+                x = residual + h
 
-        residual = x
-        h = norm(name=norm_names[1])(x)
-        counts = None
-        if ffn == "moe":
-            from tpu_trainer.models.moe import MoEMLP
+            if ffn != "none":
+                residual = x
+                h = norm(name=norm_names[1])(x)
+                if ffn == "moe":
+                    from tpu_trainer.models.moe import MoEMLP
 
-            # The expert layer's step counters (models/moe.py) leave the
-            # layer loop beside the telemetry, under their own key, whether
-            # or not telemetry is being captured.
-            with (telemetry.counters() if telemetry.counting()
-                  else contextlib.nullcontext()) as counts:
-                h, layer_aux = MoEMLP(cfg, name="moe_mlp")(
-                    h, self.deterministic)
-            aux = aux + layer_aux
-        else:
-            h = MLP(cfg, name="mlp")(h, self.deterministic)
-        x = residual + h
+                    h, layer_aux = MoEMLP(cfg, name="moe_mlp")(
+                        h, self.deterministic)
+                    aux = aux + layer_aux
+                else:
+                    h = MLP(cfg, name="mlp")(h, self.deterministic)
+                ffn_out = h
+                x = residual + h
 
         telem = None
         if telemetry.capturing():
             telem = {
-                "attn_rms": telemetry.rms(attn_out),
-                "attn_absmax": telemetry.absmax(attn_out),
-                "ffn_rms": telemetry.rms(h),
-                "ffn_absmax": telemetry.absmax(h),
                 "block_rms": telemetry.rms(x),
                 "block_absmax": telemetry.absmax(x),
             }
+            for site, out in (("attn", attn_out), ("ffn", ffn_out)):
+                if out is not None:
+                    telem[f"{site}_rms"] = telemetry.rms(out)
+                    telem[f"{site}_absmax"] = telemetry.absmax(out)
             router = telemetry.pop("router")
             if router is not None:
                 telem.update(
@@ -891,6 +1046,31 @@ class TransformerBlock(nn.Module):
         if counts:
             telem = {**(telem or {}), telemetry.LAYER_COUNTS: counts}
         return (x, aux), telem
+
+
+# What the modules say of their own parameter leaves, for whoever keeps a
+# compute-type copy of the parameters (``Trainer._cast_params``) or masks the
+# weight decay (``training/optimizer.decay_mask``): asked here, by the keys
+# of a leaf's path, never listed there by name.
+
+def computed_in_f32(path_keys) -> bool:
+    """Whether a leaf is one its module consumes in float32 whatever the
+    compute type: the expert layers' router (``models/moe.py``), a Mamba-2
+    mixer's ``A_log``, ``dt_bias`` and ``D``."""
+    from tpu_trainer.models import moe
+
+    return moe.computed_in_f32(path_keys) or (
+        "mamba" in path_keys and path_keys[-1] in Mamba2Mixer.F32_LEAVES)
+
+
+def undecayed(path_keys) -> bool:
+    """Whether a leaf takes no weight decay: every norm's weight and every
+    bias (the reference's exclusion by name, ``ddp_trainer.py:216-227``:
+    modules here name them ``*norm*`` / ``*bias*``), and what a module
+    declares (``Mamba2Mixer.UNDECAYED``)."""
+    return any("norm" in k.lower() or "bias" in k.lower()
+               for k in path_keys) or (
+        "mamba" in path_keys and path_keys[-1] in Mamba2Mixer.UNDECAYED)
 
 
 def stack_name(kind: Tuple[str, str]) -> str:
@@ -1248,10 +1428,18 @@ class GPT(nn.Module):
         slice. Training and evaluation only."""
         cfg = self.config
         if decode:
+            _refuse_state_space(cfg)
             raise NotImplementedError(
                 "decode is not supported for a model whose layers differ: "
                 "a conv layer needs the two previous positions of its gated "
                 "input as state, which no cache holds")
+        if cfg.has_mamba and mesh is not None and (
+                mesh.shape.get("tensor", 1) > 1):
+            raise NotImplementedError(
+                "mamba layers do not run under a 'tensor' mesh axis > 1: "
+                "in_proj's [z, xBC, dt] columns, the taps and the heads' "
+                "A_log / dt_bias / D have no tensor-parallel rule "
+                "(parallel/sharding.py replicates them)")
         for axis in ("stage", ring.SEQ_AXIS):
             if mesh is not None and mesh.shape.get(axis, 1) > 1:
                 raise NotImplementedError(
@@ -1331,6 +1519,18 @@ class GPT(nn.Module):
         return carry
 
 
+_NO_STATE_SPACE_GENERATION = (
+    "generation is not supported for a model with state-space (mamba) "
+    "layers: no cache here holds a scan's [P, N] state and the taps' last "
+    "positions beside keys and values (ROADMAP M8: other state than K/V)")
+
+
+def _refuse_state_space(config: GPTConfig) -> None:
+    """``generate*`` and the caches, for a model that no cache can serve."""
+    if config.has_mamba:
+        raise NotImplementedError(_NO_STATE_SPACE_GENERATION)
+
+
 def _masked_shifted_mean(ce: jax.Array, segment_ids) -> jax.Array:
     """Mean of per-position shifted CE ``[b, s-1]``, dropping positions whose
     next-token target crosses a packed-document boundary (or is padding).
@@ -1392,6 +1592,7 @@ def generate(
     (``infer.py`` hot loop, SURVEY.md §3.5); a windowed full forward matches
     that exactly. ``generate_kv`` is the cached fast path.
     """
+    _refuse_state_space(config)
     model = GPT(config)
     b, width = input_ids.shape
     total = width + max_new_tokens
@@ -1476,6 +1677,7 @@ def generate_bucketed(
 
 def init_cache(config: GPTConfig, batch_size: int):
     """Zero-initialized KV cache pytree for ``generate_kv``."""
+    _refuse_state_space(config)
     model = GPT(config)
     shapes = jax.eval_shape(
         lambda: model.init(
@@ -1550,6 +1752,7 @@ def generate_kv(
     ``generate_kv`` skip this check and get the clamped-lengths behavior
     documented in the body.
     """
+    _refuse_state_space(config)
     if prompt_lens is not None and not isinstance(
         jnp.asarray(prompt_lens), jax.core.Tracer
     ):

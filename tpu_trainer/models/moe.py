@@ -61,6 +61,11 @@ rows chose a held expert goes through ALL the sorted rows instead, a chunk
 at a time (``lax.cond``, counted as ``moe_overflow_passes``), so no row is
 dropped at any load and the result is the same either way; only the bounded
 path runs the Pallas kernels, the overflow the compiler's own ragged dots.
+An expert is the gated three-matrix FFN ``down(act(gate x) * up x)`` or,
+with ``GPTConfig.ffn_kind = "relu2"``, the two-matrix ``down(relu(up x)^2)``:
+every formulation takes the weights as they come (``_split``, ``_mid``), two
+``gmm`` forward and two ``gmm`` + two ``tgmm`` backward where the gated one
+has three of each.
 Holding half the experts or more, or all of them as every uniform model
 does, gives ``R == k*T``: one formulation over all rows, no ``cond``.
 """
@@ -272,13 +277,31 @@ class _FFN(NamedTuple):
     plain: bool = False
 
 
-def _worst_case_ffn(how: _FFN, xt, gates, w_gate, w_up, w_down,
-                    perm, inv_perm, mine, counts):
+def _relu2(x):
+    return jnp.square(nn.relu(x))
+
+
+def _mid(how: _FFN, pre):
+    """What the down projection reads, from the first projections' results:
+    ``act(gate) * up`` of a gated FFN's two, ``act(up)`` of an ungated one's
+    (``GPTConfig.ffn_kind``)."""
+    return how.act(pre[0]) * pre[1] if len(pre) == 2 else how.act(pre[0])
+
+
+def _split(args):
+    """``(xt, gates, weights, (perm, inv_perm, mine, counts))`` of an expert
+    FFN's arguments: the weights are the three matrices of a gated FFN or
+    the two of an ungated one, the last of them the down projection."""
+    return args[0], args[1], args[2:-4], args[-4:]
+
+
+def _worst_case_ffn(how: _FFN, *args):
     """The expert FFN over ALL ``k*T`` sorted rows in one set of buffers:
     what a layer runs whose receive bound is ``k*T`` (every expert held, or
-    half of them and more). The grouped matmuls' schedule skips the rows
-    past ``sum(counts)``, the row movement and the elementwise passes do
-    not."""
+    half of them and more). ``args`` as ``_split`` reads them. The grouped
+    matmuls' schedule skips the rows past ``sum(counts)``, the row movement
+    and the elementwise passes do not."""
+    xt, gates, weights, (perm, inv_perm, mine, counts) = _split(args)
     k, T = mine.shape
     with jax.named_scope("route"):
         grouped_in = _sorted_rows(xt, perm, inv_perm, mine)     # [k*T, H]
@@ -287,8 +310,8 @@ def _worst_case_ffn(how: _FFN, xt, gates, w_gate, w_up, w_down,
         return gmm(lhs, w, counts, use_kernel=how.use_kernel)
 
     with jax.named_scope("experts"):
-        mid = how.act(grouped(grouped_in, w_gate)) * grouped(grouped_in, w_up)
-        grouped_out = grouped(mid, w_down)                      # [k*T, H]
+        mid = _mid(how, [grouped(grouped_in, w) for w in weights[:-1]])
+        grouped_out = grouped(mid, weights[-1])                 # [k*T, H]
     with jax.named_scope("route"):
         rows = _unsorted_rows(grouped_out, perm, inv_perm).reshape(k, T, -1)
         weighted = rows * gates.T[..., None].astype(xt.dtype)
@@ -334,13 +357,14 @@ def _to_tokens(source, slots, here, scale=None):
     return out
 
 
-def _bounded_forward(how: _FFN, xt, gates, w_gate, w_up, w_down,
-                     perm, inv_perm, mine, counts, chunk):
+def _bounded_forward(how: _FFN, *args_and_chunk):
     """The FFN over one chunk of ``how.rows`` sorted rows. With
     ``sum(counts) <= R`` chunk 0 holds every row that chose a held expert,
     in the worst case's order, so the groups and the ``gmm`` tiling over
     them are the worst case's. Returns the chunk's part of the layer's sum
     (f32) and what the backward needs of the ``[R, .]`` intermediates."""
+    *args, chunk = args_and_chunk
+    xt, gates, weights, (perm, inv_perm, mine, counts) = _split(args)
     first, sizes, slots, here = _chunk(
         how, perm, inv_perm, mine, counts, chunk)
 
@@ -352,12 +376,11 @@ def _bounded_forward(how: _FFN, xt, gates, w_gate, w_up, w_down,
     with jax.named_scope("route"):
         grouped_in = xt[first % xt.shape[0]]                    # [R, H]
     with jax.named_scope("experts"):
-        gate_out = grouped(grouped_in, w_gate)
-        up_out = grouped(grouped_in, w_up)
-        grouped_out = grouped(how.act(gate_out) * up_out, w_down)
+        pre = tuple(grouped(grouped_in, w) for w in weights[:-1])
+        grouped_out = grouped(_mid(how, pre), weights[-1])
     with jax.named_scope("route"):
         out = _to_tokens(grouped_out, slots, here, gates.T)
-    return out, (gate_out, up_out, grouped_out)
+    return out, (*pre, grouped_out)
 
 
 def _bounded_backward(how: _FFN, args, saved, g, chunk):
@@ -365,9 +388,9 @@ def _bounded_backward(how: _FFN, args, saved, g, chunk):
     the gate-scaled output gradient is gathered in sorted order (``R`` rows
     of ``g``), the grouped matmuls' transposes are ``gmm`` against the
     transposed weights and ``tgmm``, and the way back to the tokens is the
-    forward's. All five gradients in f32: the caller rounds them."""
-    xt, gates, w_gate, w_up, w_down, perm, inv_perm, mine, counts = args
-    gate_out, up_out, grouped_out = saved
+    forward's. Every gradient in f32: the caller rounds them."""
+    xt, gates, weights, (perm, inv_perm, mine, counts) = _split(args)
+    *pre, grouped_out = saved
     k, T = mine.shape
     first, sizes, slots, here = _chunk(
         how, perm, inv_perm, mine, counts, chunk)
@@ -386,11 +409,11 @@ def _bounded_backward(how: _FFN, args, saved, g, chunk):
         d_scale = jnp.sum(g_rows * grouped_out.astype(jnp.float32), axis=-1)
         d_out = (g_rows * scale[:, None]).astype(grouped_out.dtype)
     with jax.named_scope("experts"):
-        mid, act_vjp = jax.vjp(lambda a, b: how.act(a) * b, gate_out, up_out)
-        d_gate_out, d_up_out = act_vjp(dgrad(d_out, w_down))
-        d_in = dgrad(d_gate_out, w_gate) + dgrad(d_up_out, w_up)
-        d_weights = (tgmm(grouped_in, d_gate_out, sizes, **kernel),
-                     tgmm(grouped_in, d_up_out, sizes, **kernel),
+        mid, act_vjp = jax.vjp(lambda *p: _mid(how, p), *pre)
+        d_pre = act_vjp(dgrad(d_out, weights[-1]))
+        d_in = functools.reduce(
+            jnp.add, [dgrad(d, w) for d, w in zip(d_pre, weights)])
+        d_weights = (*[tgmm(grouped_in, d, sizes, **kernel) for d in d_pre],
                      tgmm(mid, d_out, sizes, **kernel))
     with jax.named_scope("route"):
         dx = _to_tokens(d_in, slots, here)
@@ -451,7 +474,7 @@ def _bounded_ffn_fwd(how: _FFN, *args):
     def overflow():
         out = _sum_over_chunks(
             _plain(how), mine.size,
-            lambda c: _overflow_part(how, args[:5], args[5:], c), xt)
+            lambda c: _overflow_part(how, args[:-4], args[-4:], c), xt)
         saved = jax.eval_shape(bounded)[1]
         return out.astype(xt.dtype), tuple(
             jnp.zeros(s.shape, s.dtype) for s in saved)
@@ -463,7 +486,7 @@ def _bounded_ffn_fwd(how: _FFN, *args):
 
 def _bounded_ffn_bwd(how: _FFN, res, g):
     args, saved = res
-    floats, ints = args[:5], args[5:]       # ints: perm ... mine, counts
+    floats, ints = args[:-4], args[-4:]     # ints: perm ... mine, counts
     *_, mine, counts = ints
 
     def chunk_grads(c):
@@ -496,8 +519,10 @@ def computed_in_f32(path_keys) -> bool:
 
 
 class SharedExpert(nn.Module):
-    """The shared experts beside the routed ones: one SwiGLU of width
-    ``moe_shared_experts x expert_width`` over every token, no routing."""
+    """The shared experts beside the routed ones: one FFN of the experts'
+    kind (``GPTConfig.ffn_kind``) of width ``shared_expert_width``
+    (``moe_shared_experts x expert_width`` unless stated) over every token,
+    no routing."""
 
     config: GPTConfig
 
@@ -508,10 +533,13 @@ class SharedExpert(nn.Module):
             nn.Dense, use_bias=False, dtype=cfg.compute_dtype,
             param_dtype=cfg.params_dtype,
             kernel_init=nn.initializers.normal(cfg.initializer_range))
-        width = cfg.moe_shared_experts * cfg.expert_width
-        act = {"silu": nn.silu, "gelu": nn.gelu}[cfg.activation]
-        mid = act(dense(width, name="gate_proj")(x)) * dense(
-            width, name="up_proj")(x)
+        width = cfg.shared_expert_width
+        if cfg.ffn_kind == "relu2":
+            mid = _relu2(dense(width, name="up_proj")(x))
+        else:
+            act = {"silu": nn.silu, "gelu": nn.gelu}[cfg.activation]
+            mid = act(dense(width, name="gate_proj")(x)) * dense(
+                width, name="up_proj")(x)
         return dense(cfg.hidden_size, name="down_proj")(mid)
 
 
@@ -586,14 +614,18 @@ class MoEMLP(nn.Module):
             ).astype(dtype)
 
         # Only the experts held here have weights here.
-        w_gate = ffn_param("experts_gate", (held, H, I))
+        gated = cfg.ffn_kind != "relu2"
+        if gated:
+            w_gate = ffn_param("experts_gate", (held, H, I))
         w_up = ffn_param("experts_up", (held, H, I))
         w_down = ffn_param("experts_down", (held, I, H))
-        act = {"silu": nn.silu, "gelu": nn.gelu}[cfg.activation]
+        act = ({"silu": nn.silu, "gelu": nn.gelu}[cfg.activation] if gated
+               else _relu2)
 
         if cfg.moe_impl == "dropless":
             out = self._dropless_ffn(
-                xt, gate_idx, gates, entropy, w_gate, w_up, w_down, act,
+                xt, gate_idx, gates, entropy,
+                (w_gate, w_up, w_down) if gated else (w_up, w_down), act,
             ).reshape(b, s, H)
             if cfg.moe_shared_experts:
                 out = out + SharedExpert(cfg, name="shared_expert")(x)
@@ -707,8 +739,7 @@ class MoEMLP(nn.Module):
         out = residual_dropout(self, out, cfg.dropout, deterministic)
         return out, aux.astype(jnp.float32)
 
-    def _dropless_ffn(self, xt, gate_idx, gates, entropy,
-                      w_gate, w_up, w_down, act):
+    def _dropless_ffn(self, xt, gate_idx, gates, entropy, weights, act):
         """Token-dropless expert FFN over grouped matmuls.
 
         One stable argsort of the ``T*k`` token-choice rows by expert id
@@ -778,7 +809,7 @@ class MoEMLP(nn.Module):
 
         how = _FFN(act, use_kernel, subset,
                    _receive_rows(k * T, held, cfg.num_experts))
-        args = (xt.astype(dtype), gates, w_gate, w_up, w_down,
+        args = (xt.astype(dtype), gates, *weights,
                 perm, inv_perm, mine, counts)
         bounded = how.rows < k * T
         out = (_bounded_ffn if bounded else _worst_case_ffn)(how, *args)

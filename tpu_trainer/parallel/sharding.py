@@ -87,6 +87,19 @@ _TENSOR_RULES: List[Tuple[Tuple[str, ...], int]] = [
 ]
 
 
+# A Mamba-2 mixer (models/gpt.py Mamba2Mixer) has no tensor-parallel rule:
+# ``in_proj``'s columns are ``[z, xBC, dt]`` back to back, the taps and the
+# gated norm run over lanes that a column split would cut through a group,
+# and ``A_log`` / ``dt_bias`` / ``D`` are a scalar a head. Every leaf under
+# ``mamba`` REPLICATES over ``tensor`` (the Trainer and the model refuse a
+# tensor axis > 1 for such a model in words); under zero3 / zero2 the
+# shape-driven fsdp rule shards them like any other leaf (stacked ``[count,
+# ...]``: ``in_proj`` and ``out_proj`` on their largest dim, ``conv_weight``
+# and ``conv_bias`` on the channels, the ``[count, heads]`` leaves on the
+# heads where the axis divides them).
+_TENSOR_REPLICATED_SCOPES = ("mamba",)
+
+
 def canonical_strategy(name: str) -> str:
     if name not in STRATEGY_ALIASES:
         raise ValueError(
@@ -118,7 +131,8 @@ def _expert_dim(path_keys: Tuple[str, ...], shape, expert_size: int) -> Optional
 
 def _tensor_dim(path_keys: Tuple[str, ...], shape, tensor_size: int) -> Optional[int]:
     """Dim to shard over the tensor axis for this param path, or None."""
-    if tensor_size <= 1:
+    if tensor_size <= 1 or any(
+            scope in path_keys for scope in _TENSOR_REPLICATED_SCOPES):
         return None
     for suffix, dim in _TENSOR_RULES:
         if path_keys[-len(suffix):] == suffix:

@@ -29,8 +29,6 @@ from tpu_trainer.utils.quant import (
     quantize_blockwise_int8,
 )
 
-_NO_DECAY_MARKERS = ("norm", "bias")
-
 # Leaves below this size stay f32 in the quantized-state modes: the HBM win
 # is negligible and small vectors (norm gains) are where quantization noise
 # would bite hardest.
@@ -40,15 +38,17 @@ _QUANT_MIN_SIZE = 65536
 def decay_mask(params: Any) -> Any:
     """True where weight decay applies.
 
-    Name-based, matching the reference's exclusion of params whose name
-    contains 'bias' or 'norm' (``ddp_trainer.py:216-227``): our RMSNorm
-    modules are named ``*norm*`` and their weight vectors are excluded; the
-    projections and the (tied) embedding decay.
+    The model says which leaves take none (``models/gpt.undecayed``): the
+    norms' weights and the biases, as the reference excludes params whose
+    name contains 'bias' or 'norm' (``ddp_trainer.py:216-227``), and what a
+    module declares of its own leaves (a Mamba-2 mixer's ``A_log`` and
+    ``D``); the projections and the (tied) embedding decay.
     """
+    from tpu_trainer.models.gpt import undecayed
+    from tpu_trainer.parallel.sharding import _path_keys
 
     def keep(path, _leaf) -> bool:
-        keys = [str(getattr(p, "key", getattr(p, "name", ""))) for p in path]
-        return not any(marker in k.lower() for k in keys for marker in _NO_DECAY_MARKERS)
+        return not undecayed(_path_keys(path))
 
     return jax.tree_util.tree_map_with_path(keep, params)
 

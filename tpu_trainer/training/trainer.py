@@ -287,6 +287,13 @@ class Trainer:
                         f"num_dense_layers) does not run under a {axis!r} "
                         f"mesh axis > 1: the pipeline and the ring "
                         f"schedule one stacked block")
+        if self.model_config.has_mamba and (
+                self.mesh.shape.get(mesh_lib.TENSOR_AXIS, 1) > 1):
+            raise ValueError(
+                "mamba layers train under 'data', 'fsdp' and 'expert' mesh "
+                "axes: in_proj's [z, xBC, dt] columns, the taps and the "
+                "heads' A_log / dt_bias / D have no tensor-parallel rule "
+                f"(got 'tensor' = {self.mesh.shape[mesh_lib.TENSOR_AXIS]})")
         if self.model_config.latent_attention:
             for axis in (mesh_lib.TENSOR_AXIS, mesh_lib.SEQUENCE_AXIS,
                          mesh_lib.STAGE_AXIS, mesh_lib.EXPERT_AXIS):
@@ -672,9 +679,10 @@ class Trainer:
         """Compute-dtype copy of the >=2-D param leaves (exactly the cast
         the modules apply: Dense/Embed promote their matrices to the
         module dtype; 1-D leaves — RMSNorm weights — stay f32, and so does
-        what an expert layer says it computes in f32, its router
-        (``models/moe.computed_in_f32``))."""
-        from tpu_trainer.models.moe import computed_in_f32
+        what a module says it computes in f32: an expert layer's router, a
+        Mamba-2 mixer's ``A_log`` / ``dt_bias`` / ``D``
+        (``models/gpt.computed_in_f32``))."""
+        from tpu_trainer.models.gpt import computed_in_f32
 
         cd = self.model_config.compute_dtype
         return jax.tree_util.tree_map_with_path(

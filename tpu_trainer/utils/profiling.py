@@ -5,7 +5,10 @@ and what the device was doing land in the same trace file:
 
 - **Scopes** inside the jitted programs are ``jax.named_scope`` (the flax
   module names, and ``grad_accum`` / ``grad_finalize`` / ``optimizer`` in
-  ``training/trainer.py``, ``head_loss`` in ``models/gpt.py``). They are
+  ``training/trainer.py``, ``head_loss`` in ``models/gpt.py``; a Mamba-2
+  mixer is ``mamba`` with ``taps`` round its depthwise convolution and
+  ``ssd`` round the chunked scan, ``ops/ssd.py``; an expert layer is
+  ``moe_mlp`` with ``route`` / ``experts`` / ``shared_expert``). They are
   trace-time metadata: every compiled instruction carries the path of scopes
   it came from (``op_name``) and nothing about the computation changes.
   ``jax_compilation_cache_include_metadata_in_key`` is set below so that an

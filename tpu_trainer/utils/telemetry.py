@@ -124,14 +124,14 @@ _COUNTS: List[Dict[str, Dict[str, object]]] = []
 LAYER_COUNTS = "layer_counts"
 # "mean" is for what is counted ONCE an apply (a loss term): it folds over
 # micro-batches and never within one set.
-_FOLD = {"sum": jnp.add, "max": jnp.maximum}
-_OVER = {"sum": jnp.sum, "max": jnp.max, "mean": jnp.mean}
+_FOLD = {"sum": jnp.add, "max": jnp.maximum, "min": jnp.minimum}
+_OVER = {"sum": jnp.sum, "max": jnp.max, "min": jnp.min, "mean": jnp.mean}
 
 
 @contextlib.contextmanager
 def counters():
     """Open a set of counters, ``{kind: {name: value}}``: how a counter
-    folds (``"sum"``, ``"max"`` or ``"mean"``) is said where it is counted and travels
+    folds (``"sum"``, ``"max"``, ``"min"`` or ``"mean"``) is said where it is counted and travels
     as the tree's own key, through ``lax.scan`` as anything else does."""
     counts: Dict[str, Dict[str, object]] = {}
     _COUNTS.append(counts)
