@@ -110,26 +110,79 @@ def test_flash_attention_rope_dropout_fused(chip, s):
     _compile(jax.grad(loss, argnums=(0, 1, 2)), x, x, x, tab, tab, key)
 
 
-def test_chunked_scan_fwd_and_grad(chip):
-    """`ops/ssd.py` at the Nemotron cell's micro-batch (2 x 4,096 tokens, 64
-    heads of 64 lanes, 8 groups, state 128, chunk 128): plain XLA, no Pallas
-    kernel; the chip's compiler must take the batched products and fit the
-    `[2, 32, 64, 128, 128]` f32 intermediates of both passes."""
-    from tpu_trainer.ops.ssd import ssd
+def _scan_as_on_a_tpu(monkeypatch):
+    """`ops/ssd.py` dispatches on `jax.devices()`, which is the CPU here:
+    steer the shapes its kernels take to them, compiled, as a TPU would."""
+    from tpu_trainer.ops import ssd as ssd_ops
 
+    def path(*shapes):
+        return (False, None, None) if ssd_ops.fits(*shapes) else None
+
+    monkeypatch.setattr(ssd_ops, "kernel_path", path)
+    monkeypatch.setattr("tpu_trainer.models.gpt.ssd_kernel_path", path)
+
+
+def test_chunked_scan_fwd_and_grad(chip, monkeypatch):
+    """`ops/ssd.py` at the Nemotron cell's micro-batch (2 x 4,096 tokens, 64
+    heads of 64 lanes, 8 groups, state 128, chunk 128) through its Pallas
+    kernels, as a TPU takes it: Mosaic must accept the forward, the forward
+    that keeps its chunks' starting states and the backward, and what they
+    leave in HBM is the operands' order (`y` in f32, the starts 134 MB: 0.35e9
+    B of temporaries), never a `[2, 32, 64, 128, 128]` f32 tensor (268 MB
+    each) nor a `reduce_window` cumulative sum."""
+    from tpu_trainer.ops import ssd as ssd_ops
+
+    _scan_as_on_a_tpu(monkeypatch)
     b, s, heads, p, groups, n = 2, 4096, 64, 64, 8, 128
     x = chip((b, s, heads, p), jnp.bfloat16)
     dt = chip((b, s, heads), jnp.float32)
     a = chip((heads,), jnp.float32)
     bc = chip((b, s, groups, n), jnp.bfloat16)
 
+    def fwd(x, dt, a, b_in, c_in):
+        return ssd_ops.ssd(x, dt, a, b_in, c_in, chunk=128)
+
     def loss(x, dt, a, b_in, c_in):
-        y, low = ssd(x, dt, a, b_in, c_in, chunk=128)
+        y, low = fwd(x, dt, a, b_in, c_in)
         return y.sum() + low
 
+    text = _compile(fwd, x, dt, a, bc, bc)
+    assert "reduce-window" not in text
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         x, dt, a, bc, bc).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 8e9
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2 and "reduce-window" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+def test_mamba_mixer_value_and_grad_through_its_checkpoint(chip, monkeypatch):
+    """One `Mamba2Mixer` at the cell's widths and micro-batch (2 x 4,096
+    tokens of 2,688 lanes, bf16): value and gradient through the module's own
+    `jax.checkpoint`, so the recomputed forward (the kernel that keeps its
+    starts) and the backward kernel sit where a step has them."""
+    from perf import registry
+    from tpu_trainer.models.gpt import Mamba2Mixer
+
+    _scan_as_on_a_tpu(monkeypatch)
+    cell = registry.workload("train-nemotron-twotower-ep16-1chip")
+    cfg_file = registry.config(cell["config"])
+    cfg = registry.family(cfg_file).gpt_config(cfg_file, dtype="bfloat16")
+    mixer = Mamba2Mixer(cfg)
+    u = chip((2, 4096, cfg.hidden_size), jnp.bfloat16)
+    params = jax.eval_shape(
+        mixer.init, jax.random.PRNGKey(0),
+        jax.ShapeDtypeStruct((1, 8, cfg.hidden_size), jnp.bfloat16))
+    params = jax.tree_util.tree_map(
+        lambda leaf: chip(leaf.shape, leaf.dtype), params)
+
+    def loss(params, u):
+        return mixer.apply(params, u).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, u).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2 and "reduce-window" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.6e9
 
 
 @pytest.mark.parametrize("vocab", [50257, 50304])

@@ -503,6 +503,7 @@ def test_a_zero3_step_is_the_replicated_step_and_counts_the_scan():
     state, want = one.train_step(one.init_state(0), batch)
     # 4 x 32 tokens through 2 state-space blocks.
     assert float(want["ssm_tokens"]) == 4 * 32 * 2
+    assert float(want["ssd_kernel_tokens"]) == 0    # chunk 16, off the chip
     assert -26.0 < float(want["ssd_min_log_decay"]) < 0.0  # 16 x 0.1 x 16
     assert float(want["moe_rows_held"]) > 0
     many = _trainer({"fsdp": 4}, 4, "zero3", gradient_accumulation_steps=1,
@@ -521,6 +522,32 @@ def test_a_zero3_step_is_the_replicated_step_and_counts_the_scan():
     for a, b in zip(jax.tree_util.tree_leaves(state.params),
                     jax.tree_util.tree_leaves(sharded.params)):
         assert float(jnp.max(jnp.abs(a - jax.device_get(b)))) < 1e-5
+
+
+def test_a_step_counts_the_tokens_whose_scan_took_the_kernels(monkeypatch):
+    """At sizes the scan's kernels take (heads of 64 lanes, state 128, chunk
+    128) and under the interpret hook, the step's ``ssd_kernel_tokens`` is
+    its ``ssm_tokens``; the same step on the plain CPU path counts none and
+    reads the same loss."""
+    fitting = dict(TINY, hybrid_override_pattern="MEM", num_hidden_layers=3,
+                   mamba_num_heads=2, mamba_head_dim=64, ssm_state_size=128,
+                   n_groups=1, chunk_size=128, max_position_embeddings=128)
+    batch = np.random.default_rng(2).integers(0, 128, size=(2, 128),
+                                              dtype=np.int32)
+    options = dict(cfg=fitting, gradient_accumulation_steps=1,
+                   mixed_precision="fp32", batch_size=2, max_seq_len=128)
+    plain = _trainer({}, 1, **options)
+    _, want = plain.train_step(plain.init_state(0), batch)
+    assert float(want["ssm_tokens"]) == 2 * 128 * 2
+    assert float(want["ssd_kernel_tokens"]) == 0
+    monkeypatch.setenv("TPU_TRAINER_FLASH_INTERPRET", "1")
+    hooked = _trainer({}, 1, **options)
+    _, got = hooked.train_step(hooked.init_state(0), batch)
+    assert float(got["ssd_kernel_tokens"]) == float(got["ssm_tokens"]) == 512
+    assert abs(float(got["loss"]) - float(want["loss"])) < 1e-5 * float(
+        want["loss"])
+    assert abs(float(got["grad_norm"]) - float(want["grad_norm"])) < (
+        1e-3 * float(want["grad_norm"]))
 
 
 def test_a_checkpoint_round_trip(tmp_path):

@@ -49,7 +49,7 @@ from tpu_trainer.ops.loss import (
     fused_shifted_cross_entropy,
     vocab_sharded_shifted_cross_entropy,
 )
-from tpu_trainer.ops.ssd import ssd
+from tpu_trainer.ops.ssd import kernel_path as ssd_kernel_path, ssd
 from tpu_trainer.utils import telemetry
 
 
@@ -903,10 +903,13 @@ class Mamba2Mixer(nn.Module):
 
     What lies between the two projections (``_mamba_core``) is recomputed
     in the backward from ``[z, xBC, dt]``, as the family's fused kernels do:
-    the taps, their silu, the scan's ``[chunk, chunk]`` scores and decays
-    (64 x the size of ``x``), the gate and the norm never live between the
-    passes; a block keeps its in-projection's result and the normalised
-    ``y``.
+    the taps, their silu, the scan (on a TPU the Pallas kernels of
+    ``ops/ssd.py``, whose ``[chunk, chunk]`` scores and decays never leave
+    VMEM; the states its chunks start from live only inside the block's
+    backward), the gate and the norm never live between the passes; a block
+    keeps its in-projection's result and the normalised ``y``. The step
+    metrics say what ran: ``ssm_tokens`` the tokens scanned,
+    ``ssd_kernel_tokens`` those of them whose scan took the kernels.
 
     ``A_log``, ``dt_bias`` and ``D`` are consumed in float32
     (``F32_LEAVES``: whoever keeps a compute-type copy of the parameters
@@ -951,8 +954,15 @@ class Mamba2Mixer(nn.Module):
             _Weight(inner, name="norm")())
         y, low = jax.checkpoint(functools.partial(_mamba_core, cfg))(
             zxbcdt, *leaves)
-        telemetry.count("ssm_tokens",
-                        jnp.asarray(u.shape[0] * u.shape[1], f32))
+        tokens = u.shape[0] * u.shape[1]
+        telemetry.count("ssm_tokens", jnp.asarray(tokens, f32))
+        # The tokens whose scan took the Pallas kernels (``ops/ssd.py``).
+        kernels = ssd_kernel_path(
+            (*u.shape[:2], heads, cfg.mamba_head_dim),
+            (*u.shape[:2], cfg.mamba_n_groups, cfg.ssm_state_size),
+            cfg.mamba_chunk_size) is not None
+        telemetry.count("ssd_kernel_tokens",
+                        jnp.asarray(tokens if kernels else 0, f32))
         telemetry.count("ssd_min_log_decay", low, reduce="min")
         out = dense(cfg.hidden_size, name="out_proj")(y)
         return residual_dropout(self, out, cfg.dropout, deterministic)

@@ -229,6 +229,45 @@ def _kernel_checks():
           "max|kernel - reference|: out={:.2e} dq={:.2e} dk={:.2e} "
           "dv={:.2e}".format(*errs))
 
+    # 10. The state-space scan's kernels (forward, and the backward through
+    # the states the forward leaves) at the Nemotron cell's lanes, eight
+    # chunks, against the plain XLA form in f32 at the highest precision.
+    from tpu_trainer.ops import ssd as ssd_ops
+
+    bs, ss, hs, ps, gs, ns = 2, 1024, 16, 64, 2, 128
+    ks = jax.random.split(jax.random.PRNGKey(36), 6)
+    scan_args = (
+        jax.random.normal(ks[0], (bs, ss, hs, ps), jnp.bfloat16),
+        jax.nn.softplus(jax.random.normal(ks[1], (bs, ss, hs)) - 4.0),
+        -jnp.exp(jax.random.uniform(ks[2], (hs,), maxval=2.77)),
+        *((0.5 * jax.random.normal(k, (bs, ss, gs, ns))).astype(jnp.bfloat16)
+          for k in ks[3:5]))
+    probe_s = jax.random.normal(ks[5], (bs, ss, hs, ps), jnp.float32)
+    assert ssd_ops.kernel_path(scan_args[0].shape, scan_args[3].shape, 128)
+
+    def scan_grads(scan, *xs):
+        def loss(*v):
+            out = scan(*v)
+            return jnp.sum(out * probe_s), out
+        return jax.jit(jax.grad(loss, argnums=range(5), has_aux=True))(*xs)
+
+    got_g, got_y = scan_grads(
+        lambda *v: ssd_ops.ssd(*v, chunk=128)[0], *scan_args)
+    with jax.default_matmul_precision("highest"):
+        want_g, want_y = scan_grads(
+            lambda *v: ssd_ops._chunked(*v, 128, jnp.dtype(jnp.float32))[0],
+            *(v.astype(jnp.float32) for v in scan_args))
+
+    def rel(g, w):
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        return float(jnp.sqrt(jnp.mean((g - w) ** 2) / jnp.mean(w ** 2)))
+
+    errs = [rel(g, w) for g, w in zip((got_y, *got_g), (want_y, *want_g))]
+    check(f"state-space scan kernels vs plain f32 [{bs}, {ss}, {hs}/{gs}, "
+          f"{ps}, {ns}]", errs[0] < 1e-2 and max(errs[1:]) < 3e-2,
+          "rel rms: y={:.2e} dx={:.2e} ddt={:.2e} da={:.2e} db={:.2e} "
+          "dc={:.2e}".format(*errs))
+
 
 def _tiny_trainer(offload=False, offload_dtype="float32",
                   mixed_precision="fp32", flash=False, mesh_kw=None,
