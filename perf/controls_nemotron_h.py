@@ -175,13 +175,15 @@ def readings(cell, devices, seeds, controls_wanted=CONTROLS):
     for seed in seeds:
         batch = next(generator.generate(traffic, seed=seed,
                                         vocab_size=cfg["vocab_size"]))
-        state = trainer.init_state(seed % (2 ** 31 - 1))
+        state = train_family.initial_state(trainer, family, cfg, job, seed,
+                                           batch, spans)
 
         def whole(which):
             """The whole check from a fresh state; one state on the chip."""
             return train_family.check(
-                which, which.init_state(seed % (2 ** 31 - 1)), family, cfg,
-                job, batch, spans)[0]
+                which, train_family.initial_state(
+                    which, family, cfg, job, seed, batch, spans),
+                family, cfg, job, batch, spans)[0]
 
         for control in controls_wanted:
             if control not in PROGRAM_FAULTS:

@@ -10,8 +10,8 @@ and one **phase**, and sums by both the time each op ran with no op nested in
 it (a ``while`` spans its body's ops; a kernel may span the asynchronous copy
 that is started during it), averaged over the chips:
 
-    regions  collective  flash  attn_proj  mlp  head_loss  norm  embed
-             grad_accum  grad_finalize  optimizer  other
+    regions  collective  flash  attn_proj  mlp  conv  ssm  head_loss  norm
+             embed  grad_accum  grad_finalize  optimizer  other
     phases   fwd (``jvp(`` in the path)   bwd (``transpose(``)
              opt (the trainer's three scopes)   other
 
@@ -23,9 +23,9 @@ function gives the region readers nothing to read: they return ``None``.
 
     python3 -m perf.program_trace --workload <cell> --seed <n> --seconds <s>
 
-is the cell's ``--trace 1`` run (``perf/run.py``) with every metric of
-``perf/metrics/`` that this module's readers read added to the cell's list,
-for as long as the cell's own file does not list them (PERF.md section 7);
+is the cell's ``--trace 1`` run (``perf/run.py``) under the name the records
+of ``perf/records/`` quote: the metrics this module's readers read are in the
+cell's resolved list since their files name their cells (PR 37);
 
     python3 -m perf.program_trace <trace dir or .json> [steps [step.hlo.txt]]
 
@@ -48,12 +48,12 @@ from perf import harness, trace_reduce
 from perf.trace_reduce import Event, Interval
 
 PROGRAM_SPAN_PREFIX = "tpu_trainer:"
-REGIONS = ("collective", "flash", "attn_proj", "mlp", "head_loss", "norm",
-           "embed", "grad_accum", "grad_finalize", "optimizer", "other")
+REGIONS = ("collective", "flash", "attn_proj", "mlp", "conv", "ssm",
+           "head_loss", "norm", "embed", "grad_accum", "grad_finalize",
+           "optimizer", "other")
 PHASES = ("fwd", "bwd", "opt", "other")
 TRAINER_SCOPES = ("grad_accum", "grad_finalize", "optimizer")
 PALLAS = "tpu_custom_call"
-PROGRAM_READERS = ("region_ms", "program_span", "program_counter")
 
 _INSTRUCTION = re.compile(
     r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s+(?:\(.*?\)|\S+)\s+'
@@ -81,6 +81,14 @@ def scope_region(op_name: str, kind: str = "") -> str:
         return "flash" if kind == PALLAS else "attn_proj"
     if "mlp" in segs or "moe_mlp" in segs:
         return "mlp"
+    # A sequence operator's module whole, as `conv_ms.train` and
+    # `ssm_ms.train` read it: before `norm` (the mixer's grouped norm is the
+    # mixer's), the scan's kernels and the taps inside `mamba` included, the
+    # core a backward recomputes too (`.../checkpoint/mamba/...`).
+    if "conv" in segs:
+        return "conv"
+    if "mamba" in segs:
+        return "ssm"
     # Before `embed`: the tied head's matmul is `head_loss/embed_tokens/...`.
     if "head_loss" in segs:
         return "head_loss"
@@ -344,6 +352,9 @@ def compile_entries() -> Optional[List[Any]]:
     return _from_program("compile_log")
 
 
+COMPILE_KINDS = ("trace", "lower", "compile", "cache_read")
+
+
 def outermost(entries: Iterable[Any], eps: float = 1e-3) -> List[Any]:
     """Trace, lowering and compile entries that lie inside no other: a jit
     traced inside another's trace reports a duration inside the outer one,
@@ -362,6 +373,51 @@ def entries_within(entries: Iterable[Any], intervals: Iterable[Interval]
     intervals = list(intervals)
     return [e for e in entries
             if any(lo <= e.end <= hi for lo, hi in intervals)]
+
+
+def compile_sums(entries: Iterable[Any], intervals: Iterable[Interval]
+                 ) -> Dict[str, Dict[str, float]]:
+    """``{kind: {"seconds", "count"}}`` of the log's entries that ended
+    inside one of ``intervals`` (host clock): the outermost ``trace``,
+    ``lower`` and ``compile`` entries, and every ``cache_read`` (which lies
+    inside its ``compile``, so the four do not add up: ``compile`` already
+    holds the read). What ``trace_lower_s``, ``executable_s`` and
+    ``recompiles.train`` read, and what a run's ``setup`` note says its
+    first calls were made of."""
+    entries = list(entries)
+    found = entries_within(
+        outermost(entries) + [e for e in entries if e.kind == "cache_read"],
+        intervals)
+    return {kind: {"seconds": sum(e.seconds for e in found if e.kind == kind),
+                   "count": sum(1 for e in found if e.kind == kind)}
+            for kind in COMPILE_KINDS}
+
+
+def log_covers(entries: Iterable[Any], intervals: Iterable[Interval]) -> bool:
+    """Whether the log still holds what happened in every one of
+    ``intervals``: an entry that ended inside each. The program's log keeps
+    its newest 4,096 entries, and tracing one large unrolled step reports
+    more nested entries than that before the outer one replaces them (the
+    JoyAI cell, my chip run, PR 37: everything before ``_train_step`` was
+    gone), so sums over spans it no longer covers would read low."""
+    entries = list(entries)
+    return all(any(lo <= e.end <= hi for e in entries)
+               for lo, hi in intervals)
+
+
+def first_call_compiles(spans) -> Optional[Dict[str, Any]]:
+    """:func:`compile_sums` over the harness's ``first_call`` spans so far
+    (``harness.Spans``): what a run's set-up was made of, from what is in
+    memory, and under ``complete`` whether the log still covers every one
+    of them (:func:`log_covers`; each is the first call of a jitted shape,
+    so each has its entries). ``None`` on a commit whose program keeps no
+    compile log."""
+    log = compile_entries()
+    if log is None:
+        return None
+    intervals = [(s.start, s.end) for s in spans.named("first_call")]
+    return {**compile_sums(log, intervals),
+            "complete": log_covers(log, intervals)}
 
 
 # --- one load and one table a run ----------------------------------------------
@@ -424,25 +480,9 @@ def span_summary(trace: ProgramTrace, window: Interval) -> Dict[str, Any]:
 
 
 def run_cell(argv: Sequence[str]) -> int:
-    """The cell's traced run through ``perf/run.py``, with the metrics of
-    :data:`PROGRAM_READERS` that move an end-to-end metric of the cell and
-    that its file does not list yet."""
-    from perf import registry, run
+    """The cell's traced run through ``perf/run.py``."""
+    from perf import run
 
-    resolve = registry.workload
-
-    def workload(name: str) -> Dict[str, Any]:
-        cell = resolve(name)
-        for metric in registry.names("metrics"):
-            spec = registry.metric(metric)
-            if (spec.get("reader") in PROGRAM_READERS
-                    and metric not in cell["per_layer"]
-                    and spec["moves"] in cell["end_to_end"]):
-                cell["per_layer"].append(metric)
-                cell["per_layer_specs"][metric] = spec
-        return cell
-
-    registry.workload = workload
     return run.main([*argv, "--trace", "1"])
 
 

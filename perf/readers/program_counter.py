@@ -3,7 +3,8 @@
 (trace | lower | compile) that ended inside the measured window
 (``within="window"``) or inside the harness's ``first_call`` spans
 (``within="first_call"``). Read in the traced run, like every per-layer
-metric. A program that keeps no such log: ``None``."""
+metric. A program that keeps no such log, or whose log no longer holds an
+entry of every such span (``program_trace.log_covers``): ``None``."""
 
 from perf import program_trace
 
@@ -16,10 +17,8 @@ def read(obs, *, kinds, within, value):
         intervals = [obs.window]
     else:
         intervals = [(s.start, s.end) for s in obs.spans.named(within)]
-        if not intervals:
+        # A log that has lost a span's entries would read low: nothing.
+        if not intervals or not program_trace.log_covers(log, intervals):
             return None
-    found = [e for e in program_trace.entries_within(
-        program_trace.outermost(log), intervals) if e.kind in kinds]
-    if value == "count":
-        return float(len(found))
-    return sum(e.seconds for e in found)
+    sums = program_trace.compile_sums(log, intervals)
+    return float(sum(sums[kind][value] for kind in kinds))

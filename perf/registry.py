@@ -8,7 +8,9 @@ a generator or a runner by adding a file; nothing here lists them.
     perf/workloads/<name>.json   one cell: configuration, traffic, chips, runner,
                                  job or engine parameters, the metrics it reports
     perf/metrics/<name>.json     one metric: unit, better, source, and for a
-                                 per-layer metric its layer, `moves` and reader
+                                 per-layer metric its layer, `moves`, reader
+                                 and, optionally, the cells it is read in
+                                 beside those that list it (`workloads`)
     perf/generators/<name>.py    traffic generator:  generate(params, ...)
     perf/runners/<name>.py       runs a cell:        run(cell, args) -> Result
     perf/readers/<name>.py       reads one metric:   read(obs, **args) -> float | None
@@ -85,6 +87,11 @@ def metric(name: str) -> Dict[str, Any]:
         raise RegistryError(f"metric {name!r}: better={data['better']!r}")
     if data["source"] not in SOURCES:
         raise RegistryError(f"metric {name!r}: source={data['source']!r}")
+    if "workloads" in data:
+        missing = sorted(set(data["workloads"]) - set(names("workloads")))
+        if missing:
+            raise RegistryError(
+                f"metric {name!r} names cells that do not exist: {missing}")
     return data
 
 
@@ -110,7 +117,10 @@ def family(cfg: Dict[str, Any]):
 
 def workload(name: str) -> Dict[str, Any]:
     """One cell, resolved: its configuration, traffic mix and metric files
-    attached, and every cross-reference checked."""
+    attached, and every cross-reference checked. Its per-layer metrics are
+    those its own file lists, in the file's order, then every metric whose
+    file names the cell under ``workloads``, in sorted order: a later PR
+    adds a metric to a standing cell by the metric's files alone."""
     cell = _load("workloads", name)
     for key in ("config", "traffic", "chips", "runner", "why",
                 "end_to_end", "per_layer"):
@@ -124,6 +134,12 @@ def workload(name: str) -> Dict[str, Any]:
     code("generators", cell["traffic_file"]["generator"])
     e2e = {m: metric(m) for m in cell["end_to_end"]}
     layer = {m: metric(m) for m in cell["per_layer"]}
+    for m in names("metrics"):
+        if m not in layer:
+            spec = metric(m)
+            if name in spec.get("workloads", ()):
+                layer[m] = spec
+    cell["per_layer"] = list(layer)
     if "setup_s" not in e2e or len(e2e) < 2:
         raise RegistryError(
             f"workload {name!r} must report setup_s and one more "

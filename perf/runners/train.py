@@ -8,7 +8,7 @@ import math
 import time
 from typing import Any, Dict
 
-from perf import harness, program, reference, registry, work
+from perf import harness, program, program_trace, reference, registry, work
 
 MAX_IN_FLIGHT = 2  # steps dispatched ahead of the one the host waits for
 
@@ -140,7 +140,10 @@ def run(cell: Dict[str, Any], *, devices, seed: int, seconds: float,
                  build_trainer_s=built - runner_start,
                  first_calls=[[s.attrs["what"], s.dur]
                               for s in spans.named("first_call")],
-                 cache_hits=cache.hits, cache_misses=cache.misses)
+                 cache_hits=cache.hits, cache_misses=cache.misses,
+                 # What the first calls were made of, by the program's own
+                 # compile log: a `setup_s` near its bound names its phase.
+                 compile_log=program_trace.first_call_compiles(spans))
     losses = []
     window_start = time.perf_counter()
     setup_s = window_start - process_start

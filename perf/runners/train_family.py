@@ -15,8 +15,10 @@ What differs from ``runners/train.py`` besides that:
   with the reference's (``check``);
 - the step's metrics may carry device counters (``COUNTERS``); they are
   kept as device scalars and read after the window, never inside it. The
-  readers get the first two; ``moe_overflow_passes`` and ``mtp_loss`` go to
-  the ``train_window`` note only.
+  readers get ``moe_rows_held``, ``moe_max_load``, ``ssm_tokens`` and
+  ``ssd_kernel_tokens`` (``obs.counters``); ``moe_overflow_passes``,
+  ``mtp_loss`` and ``ssd_min_log_decay`` go to the ``train_window`` note
+  only.
 """
 
 from __future__ import annotations
@@ -26,11 +28,12 @@ import math
 import time
 from typing import Any, Dict
 
-from perf import harness, program, registry
+from perf import harness, held_experts, program, program_trace, registry
 from perf.runners.train import MAX_IN_FLIGHT, _place_rows
 
 COUNTERS = ("moe_rows_held", "moe_max_load", "moe_overflow_passes",
-            "mtp_loss")
+            "mtp_loss", "ssm_tokens", "ssd_kernel_tokens",
+            "ssd_min_log_decay")
 
 
 def build_trainer(family, cfg, traffic, job, devices):
@@ -76,11 +79,39 @@ def build_trainer(family, cfg, traffic, job, devices):
                    mesh=make_mesh(mesh_config, devices=list(devices)))
 
 
+def initial_state(trainer, family, cfg, job, seed, batch, spans):
+    """The trainer's state from the seed, by the program's own initialiser;
+    where the configuration picks the experts held here by their load
+    (``experts_held_pick``: ``perf/held_experts.py``), relabelled so, with a
+    note of what was done. Every seed then draws work of one difficulty."""
+    import jax
+
+    with spans.span("first_call", what="init_state"):
+        state = trainer.init_state(seed % (2 ** 31 - 1))
+        jax.block_until_ready(state.params)
+    if cfg.get("experts_held_pick"):
+        with spans.span("first_call", what="experts_held_pick"):
+            state, done = held_experts.apply(
+                trainer, state, family, cfg, job, batch,
+                program_side(trainer), _place_rows)
+        harness.note("experts_held_pick", **done)
+    return state
+
+
 def program_side(trainer):
     """``f(params, tokens) -> (logits, choices)``: the program's forward
     through the job's own layer loop, kernels and compute type, and which
     experts each token chose in each expert layer, in the order the layers
-    run (``[batch, seq, k]`` ids; the capture's `deep` site)."""
+    run (``[batch, seq, k]`` ids; the capture's `deep` site). One jitted
+    function a trainer, kept on it: the forward is traced in Python once a
+    process, whoever calls it first (the pick of the held experts, the logit
+    check), and a second caller's program takes the same jaxpr."""
+    import jax
+
+    kept = getattr(trainer, "_perf_program_side", None)
+    if kept is not None:
+        return kept
+
     from tpu_trainer.models.gpt import GPT
     from tpu_trainer.parallel import context as ctx_lib
     from tpu_trainer.utils import telemetry
@@ -102,7 +133,8 @@ def program_side(trainer):
                     *toks.shape, -1))
         return logits, choices
 
-    return side
+    trainer._perf_program_side = jax.jit(side)
+    return trainer._perf_program_side
 
 
 def compare_fn(side, family, cfg):
@@ -251,6 +283,14 @@ def check(trainer, state, family, cfg, job, batch, spans):
         leaves, want_norm = jax.jit(gradient_errors_fn(trainer))(
             first_moment(state.opt_state), gradient)
         leaves = {k: float(v) for k, v in leaves.items()}
+    if hasattr(family, "held"):
+        # Rows a token of the batch brought to the experts held here, by
+        # expert layer in the order they run (an even load: the family's
+        # `even_rows_per_token`; the row buffers hold twice that).
+        first, count = family.held(cfg)
+        numbers["held_rows_per_token"] = [
+            float(((c >= first) & (c < first + count)).sum()) / batch.size
+            for c in choices]
     numbers.update(
         first_step_loss=got_loss, reference_loss=want_loss,
         loss_rel=abs(got_loss - want_loss) / abs(want_loss),
@@ -275,15 +315,15 @@ def run(cell: Dict[str, Any], *, devices, seed: int, seconds: float,
     runner_start = time.perf_counter()
     trainer = build_trainer(family, cfg, traffic, job, devices)
     built = time.perf_counter()
-    with spans.span("first_call", what="init_state"):
-        state = trainer.init_state(seed % (2 ** 31 - 1))
-        jax.block_until_ready(state.params)
+    first_batch = next(batches)
+    state = initial_state(trainer, family, cfg, job, seed, first_batch,
+                          spans)
 
     # --- correct: outside the window, every run --------------------------
     # The tolerances belong to the configuration and the compute type, not
     # to the cell: every cell of one configuration is held to the same.
     tol = cfg["reference_tolerance"][program.COMPUTE_TYPE]
-    numbers, state = check(trainer, state, family, cfg, job, next(batches),
+    numbers, state = check(trainer, state, family, cfg, job, first_batch,
                            spans)
     held = judge(numbers, tol)
     correct = all(ok for _, _, ok in held.values())
@@ -304,7 +344,10 @@ def run(cell: Dict[str, Any], *, devices, seed: int, seconds: float,
                  build_trainer_s=built - runner_start,
                  first_calls=[[s.attrs["what"], s.dur]
                               for s in spans.named("first_call")],
-                 cache_hits=cache.hits, cache_misses=cache.misses)
+                 cache_hits=cache.hits, cache_misses=cache.misses,
+                 # What the first calls were made of, by the program's own
+                 # compile log: a `setup_s` near its bound names its phase.
+                 compile_log=program_trace.first_call_compiles(spans))
     step_metrics = []
     window_start = time.perf_counter()
     setup_s = window_start - process_start
@@ -358,6 +401,11 @@ def run(cell: Dict[str, Any], *, devices, seed: int, seconds: float,
                                        * cfg["num_experts_per_tok"])
         rows_per_token = counters["moe_rows_held"] / (
             window_steps * tokens_per_step * layers)
+    # Tokens x state-space blocks, and those of them whose scan took the
+    # Pallas kernels of `ops/ssd.py`: summed over the window.
+    for name in ("ssm_tokens", "ssd_kernel_tokens"):
+        if name in counted:
+            counters[name] = sum(counted[name])
     harness.note(
         "train_window", steps=window_steps, window_s=window_s,
         tokens_per_step=tokens_per_step, train_tokens_per_s=rate,
@@ -372,6 +420,11 @@ def run(cell: Dict[str, Any], *, devices, seed: int, seconds: float,
         moe_overflow_passes=(sum(counted["moe_overflow_passes"])
                              if "moe_overflow_passes" in counted else None),
         mtp_loss=counted["mtp_loss"][-1] if "mtp_loss" in counted else None,
+        ssm_tokens=counters.get("ssm_tokens"),
+        ssd_kernel_tokens=counters.get("ssd_kernel_tokens"),
+        # The window's most negative in-chunk cumulative `dt A`.
+        ssd_min_log_decay=(min(counted["ssd_min_log_decay"])
+                           if "ssd_min_log_decay" in counted else None),
         loss_first=losses[0], loss_last=losses[-1],
         setup_s=setup_s)
     # After the window, so that it costs neither set-up nor measured time:

@@ -131,7 +131,7 @@ def test_train_family_runner():
     got = harness.read_per_layer(cell, result.observations)
     # Span and counter metrics are read; trace metrics find nothing.
     assert set(got) == {"compile_s", "step_ms.train", "data_wait_frac.train",
-                        "moe_held_rows_frac.train"}
+                        "moe_held_rows_frac.train", "mfu.train"}
     assert got["moe_held_rows_frac.train"]["value"] == pytest.approx(
         100 * counters["moe_rows_held"] / counters["moe_rows_routed"])
     assert 10 < got["moe_held_rows_frac.train"]["value"] < 50   # 4 of 16 held

@@ -196,8 +196,10 @@ def test_train_family_runner(capsys):
     got = harness.read_per_layer(cell, result.observations)
     # Span and counter metrics are read; trace metrics find nothing.
     assert set(got) == {"compile_s", "step_ms.train", "data_wait_frac.train",
-                        "moe_held_rows_frac.train"}
+                        "moe_held_rows_frac.train", "mfu.train"}
     assert 10 < got["moe_held_rows_frac.train"]["value"] < 50   # 4 of 16 held
+    assert got["mfu.train"]["value"] == pytest.approx(
+        100.0 * window["mfu"], rel=1e-6)
 
 
 _TINY_CONTROLS = ("kv_norm_dropped", "shared_expert_dropped",

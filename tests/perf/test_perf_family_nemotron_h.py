@@ -221,8 +221,18 @@ def test_train_family_runner(capsys):
     got = harness.read_per_layer(cell, result.observations)
     # Span and counter metrics are read; trace metrics find nothing.
     assert set(got) == {"compile_s", "step_ms.train", "data_wait_frac.train",
-                        "moe_held_rows_frac.train"}
+                        "moe_held_rows_frac.train", "mfu.train",
+                        "ssd_kernel_frac.train"}
     assert 5 < got["moe_held_rows_frac.train"]["value"] < 50    # 2 of 8 held
+    # The scan's counters reach the run: two state-space blocks a token; off
+    # the chip no scan takes the kernels, and the share says so.
+    assert counters["ssm_tokens"] == window["ssm_tokens"] \
+        == result.attempted * 256 * 2
+    assert counters["ssd_kernel_tokens"] == 0
+    assert got["ssd_kernel_frac.train"]["value"] == 0.0
+    assert window["ssd_min_log_decay"] < 0
+    assert got["mfu.train"]["value"] == pytest.approx(
+        100.0 * window["mfu"], rel=1e-6)
 
 
 @pytest.mark.parametrize("wanted", [

@@ -30,9 +30,77 @@ def test_workload_resolves(name):
     assert hasattr(generator, "generate")
 
 
+@pytest.mark.parametrize("name", registry.names("workloads"))
+def test_a_cell_lists_its_own_metrics_then_those_that_name_it(name):
+    """A cell's resolved per-layer metrics: its file's list in the file's
+    order, then exactly the metric files whose `workloads` names the cell,
+    in sorted order, each resolved once and each moving an end-to-end
+    metric the cell reports."""
+    own = registry._load("workloads", name)["per_layer"]
+    naming = [m for m in registry.names("metrics")
+              if name in registry._load("metrics", m).get("workloads", ())]
+    cell = registry.workload(name)
+    assert cell["per_layer"] == own + [m for m in naming if m not in own]
+    assert list(cell["per_layer_specs"]) == cell["per_layer"]
+    assert len(set(cell["per_layer"])) == len(cell["per_layer"])
+    for m in naming:
+        assert cell["per_layer_specs"][m]["moves"] in cell["end_to_end"], m
+    assert "mfu.train" in naming      # the whole step's share, every cell
+
+
+def _with_metric_files(monkeypatch, files):
+    """The registry with some metric files added or replaced in memory."""
+    real_load, real_names = registry._load, registry.names
+
+    def load(kind, name):
+        if kind == "metrics" and name in files:
+            return dict(files[name], name=name)
+        return real_load(kind, name)
+
+    monkeypatch.setattr(registry, "_load", load)
+    monkeypatch.setattr(registry, "names", lambda kind: sorted(
+        set(real_names(kind)) | set(files)) if kind == "metrics"
+        else real_names(kind))
+
+
+def test_a_metric_that_names_a_missing_cell_is_refused(monkeypatch):
+    spec = dict(registry.metric("step_ms.train"),
+                workloads=["train-360m-1chip", "no-such-cell"])
+    _with_metric_files(monkeypatch, {"made_up_ms.train": spec})
+    with pytest.raises(registry.RegistryError, match="no-such-cell"):
+        registry.metric("made_up_ms.train")
+    # A cell is not resolved past such a file, whichever cell it is.
+    with pytest.raises(registry.RegistryError, match="do not exist"):
+        registry.workload("train-lfm2-24b-ep8-1chip")
+
+
+def test_a_metric_named_by_the_cell_and_by_its_own_file_is_resolved_once(
+        monkeypatch):
+    """`step_ms.train` is in every cell's own list; naming a cell in its file
+    too changes nothing, and the cell's order stands."""
+    before = registry.workload("train-360m-1chip")["per_layer"]
+    spec = dict(registry.metric("step_ms.train"),
+                workloads=["train-360m-1chip"])
+    _with_metric_files(monkeypatch, {"step_ms.train": spec})
+    cell = registry.workload("train-360m-1chip")
+    assert cell["per_layer"] == before
+    assert cell["per_layer"].count("step_ms.train") == 1
+
+
+def test_a_metric_that_names_a_cell_must_move_what_the_cell_reports(
+        monkeypatch):
+    spec = dict(registry.metric("step_ms.train"), moves="ttft_p95_ms",
+                workloads=["train-360m-1chip"])
+    _with_metric_files(monkeypatch, {"made_up_ms.serve": spec})
+    with pytest.raises(registry.RegistryError, match="does not report"):
+        registry.workload("train-360m-1chip")
+    registry.workload("train-1.7b-fsdp4")     # not named: not held to it
+
+
 @pytest.mark.parametrize("name", registry.names("metrics"))
 def test_metric_file(name):
-    spec = registry.metric(name)
+    spec = registry.metric(name)      # refuses a cell that does not exist
+    assert set(spec.get("workloads", ())) <= set(registry.names("workloads"))
     assert registry.NAME_RE.match(name)
     assert 1 <= len(spec["unit"]) <= 16 and " " not in spec["unit"]
     if "layer" in spec:  # a per-layer metric moves an end-to-end one
@@ -298,12 +366,12 @@ SCRATCH_CONFIG = {
 
 
 def test_a_new_cell_needs_new_files_only(tmp_path):
-    """A dummy configuration, traffic mix, cell, metric and reader, and a
-    family with a configuration no family of today could state, are added
-    to a scratch copy of the benchmark as files; the harness resolves the
-    cell and reads the metric, every configuration there passes the
-    registry's checks through its own family, and no file that was there
-    has changed."""
+    """A dummy configuration, traffic mix, cell, metric and reader, a metric
+    and its reader for a cell that STANDS, and a family with a configuration
+    no family of today could state, are added to a scratch copy of the
+    benchmark as files; the harness resolves both cells and reads both
+    metrics, every configuration there passes the registry's checks through
+    its own family, and no file that was there has changed."""
     copy = tmp_path / "perf"
     ignore = shutil.ignore_patterns("__pycache__", "out")
     shutil.copytree(registry.ROOT, copy, ignore=ignore)
@@ -328,6 +396,15 @@ def test_a_new_cell_needs_new_files_only(tmp_path):
         "reader": "dummy_reader", "args": {"scale": 2.0}}))
     (copy / "readers" / "dummy_reader.py").write_text(
         "def read(obs, *, scale):\n    return scale * obs.counters['x']\n")
+    # A metric on a STANDING cell: the metric's file names the cell, its
+    # reader beside it, and no file of the cell is touched.
+    (copy / "metrics" / "standing_ms.train.json").write_text(json.dumps({
+        "unit": "ms", "better": "lower", "source": "program_counter",
+        "layer": "trainer", "moves": "train_tokens_per_s",
+        "reader": "standing_reader",
+        "workloads": ["train-lfm2-24b-ep8-1chip"]}))
+    (copy / "readers" / "standing_reader.py").write_text(
+        "def read(obs):\n    return obs.counters['x'] / 3\n")
     cell = dict(registry._load("workloads", "train-360m-1chip"),
                 name="dummy-cell", config="dummy-config",
                 traffic="dummy-mix")
@@ -346,6 +423,14 @@ def test_a_new_cell_needs_new_files_only(tmp_path):
         "got = harness.read_per_layer(cell, obs)\n"
         "assert got['dummy_ms.train'] == {'value': 42.0, 'unit': 'ms'}, got\n"
         "assert 'flash_roofline.train' not in got  # nothing to read\n"
+        "assert 'standing_ms.train' not in cell['per_layer']\n"
+        "standing = registry.workload('train-lfm2-24b-ep8-1chip')\n"
+        "own = registry._load('workloads', standing['name'])['per_layer']\n"
+        "assert standing['per_layer'][:len(own)] == own\n"
+        "assert 'standing_ms.train' in standing['per_layer'][len(own):]\n"
+        "obs.cell = standing\n"
+        "got = harness.read_per_layer(standing, obs)\n"
+        "assert got['standing_ms.train'] == {'value': 7.0, 'unit': 'ms'}\n"
         "names = registry.names('configs')\n"
         "assert {'scratch-config', 'dummy-config'} < set(names)\n"
         "for name in names:\n"
@@ -361,4 +446,4 @@ def test_a_new_cell_needs_new_files_only(tmp_path):
         str(copy), str(tmp_path / "tests" / "perf" / "test_perf_registry.py")]
     after = _digest(tmp_path)
     assert {k: v for k, v in after.items() if k in before} == before
-    assert len(after) == len(before) + 7
+    assert len(after) == len(before) + 9

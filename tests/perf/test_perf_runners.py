@@ -14,7 +14,7 @@ from tests.perf.test_perf_reference import TINY
 def _cell(name):
     cell = registry.workload(name)
     cell["config_file"] = dict(
-        TINY, max_position_embeddings=512,
+        TINY, family="llama", max_position_embeddings=512,
         reference_tolerance=cell["config_file"]["reference_tolerance"])
     cell["peaks"] = registry.peaks("TPU v5 lite")
     return cell
@@ -45,7 +45,14 @@ def test_train_runner():
         "losses_not_finite": 0}
     got = _read(cell, result)
     # Span metrics are read; trace metrics find nothing and are left out.
-    assert {"compile_s", "step_ms.train", "data_wait_frac.train"} == set(got)
+    # `mfu.train` is the window's rate through the family's count, as the
+    # `train_window` note has it.
+    assert {"compile_s", "step_ms.train", "data_wait_frac.train",
+            "mfu.train"} == set(got)
+    from perf import work
+    assert got["mfu.train"]["value"] == pytest.approx(100.0 * work.mfu(
+        cell["config_file"], 64, result.end_to_end["train_tokens_per_s"], 1,
+        cell["peaks"]["bf16_flops_per_s"]), rel=1e-9)
     steps = result.attempted
     lo, hi = result.observations.window
     assert got["step_ms.train"]["value"] == pytest.approx(
