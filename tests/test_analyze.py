@@ -306,8 +306,6 @@ class TestRecompileWatchdog:
         trainer = make_trainer(
             MeshConfig(data=1, fsdp=1), devices=jax.devices()[:1],
             model_kw={"max_seq_len": 32}, train_kw={"max_seq_len": 32})
-        if trainer.executable_cache_size() is None:
-            pytest.skip("jit cache-size hook unavailable on this jax")
         state = trainer.init_state()
         rng = np.random.default_rng(0)
 
@@ -316,7 +314,7 @@ class TestRecompileWatchdog:
                 rng.integers(0, 64, (trainer.global_batch_size, seq),
                              dtype=np.int32))
 
-        wd = RecompileWatchdog(trainer, warn_after=2)
+        wd = RecompileWatchdog(warn_after=2)
         b1 = batch(32)
         state, _ = trainer.train_step(state, b1)
         assert wd.observe(0, b1, expected=True) is None  # warmup compile
@@ -335,14 +333,33 @@ class TestRecompileWatchdog:
         assert rec2 is not None and rec2["storm"] is True
         assert rec2["recompiles_total"] == 2
 
-    def test_disarmed_watchdog_is_silent(self):
-        class Stub:
-            def executable_cache_size(self):
-                return None
+    def test_an_eval_compile_is_not_a_train_step_recompile(self):
+        """The watchdog reads the program's compile log by span: an eval
+        step's first compile between two train steps is not a recompile of
+        the train step; a new batch shape after it is."""
+        trainer = make_trainer(
+            MeshConfig(data=1, fsdp=1), devices=jax.devices()[:1],
+            model_kw={"max_seq_len": 32}, train_kw={"max_seq_len": 32})
+        state = trainer.init_state()
+        rng = np.random.default_rng(1)
 
-        wd = RecompileWatchdog(Stub())
-        assert wd.observe(0) is None
+        def rows(seq):
+            return rng.integers(0, 64, (trainer.global_batch_size, seq),
+                                dtype=np.int32)
+
+        wd = RecompileWatchdog()
+        b1 = trainer.put_batch(rows(32))
+        state, _ = trainer.train_step(state, b1)
+        assert wd.observe(0, b1, expected=True) is None
+        trainer.eval_step(state, rows(24))              # compiles, not a step
+        state, _ = trainer.train_step(state, b1)
+        assert wd.observe(1, b1) is None
         assert wd.events == []
+        b2 = trainer.put_batch(rows(24))
+        state, _ = trainer.train_step(state, b2)
+        rec = wd.observe(2, b2)
+        assert rec is not None and rec["new_executables"] == 1
+        assert rec["executables"] == 2 and "24" in rec["batch_abstract"]
 
 
 # --- crash flight recorder -------------------------------------------------

@@ -12,6 +12,7 @@ import contextlib
 import dataclasses
 import gc
 import glob
+import time
 
 import jax
 import numpy as np
@@ -232,6 +233,89 @@ def test_of_nested_entries_the_log_keeps_the_outermost():
         ("trace", "outer"), ("lower", "jit(outer)"), ("compile", "jit(outer)")]
 
 
+TRACE = "/jax/core/compile/jaxpr_trace_duration"
+COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+def test_a_burst_of_nested_traces_keeps_the_runs_first_calls():
+    """More nested reports than the log holds, inside one outer trace (the
+    JoyAI cell's step, PR 37), push out nothing logged before them: they
+    are never stored. The listeners are driven as jax drives them, a scalar
+    at each start and a duration at each end."""
+    profiling._on_start(TRACE, 0.0, fun_name="first_call")
+    profiling._on_duration(TRACE, 1e-3, fun_name="first_call")
+    before = profiling.compile_log()
+    profiling._on_start(TRACE, 0.0, fun_name="_train_step")
+    for i in range(5000):
+        profiling._on_start(TRACE, 0.0, fun_name=f"inner_{i}")
+        profiling._on_duration(TRACE, 1e-6, fun_name=f"inner_{i}")
+        profiling._on_duration(COMPILE, 1e-6, fun_name=f"jit(inner_{i})")
+    profiling._on_duration(TRACE, 2.0, fun_name="_train_step")
+    log = profiling.compile_log()
+    assert log[-2] == before[-1]
+    assert [e.fun_name for e in log[-2:]] == ["first_call", "_train_step"]
+    assert profiling._depth() == 0
+
+
+def test_a_jax_that_reports_no_start_keeps_the_outermost_as_before():
+    """Without the start scalars the log falls back to replacing what ran
+    inside a trace when the trace itself reports."""
+    profiling._on_duration(COMPILE, 5.0, fun_name="jit(before)")
+    profiling._on_duration(TRACE, 1e-6, fun_name="inner")
+    profiling._on_duration(COMPILE, 1e-6, fun_name="jit(inner)")
+    profiling._on_duration(TRACE, 1.0, fun_name="outer")
+    profiling._on_duration(COMPILE, 1e-6, fun_name="jit(outer)")
+    assert [(e.kind, e.fun_name) for e in profiling.compile_log()[-3:]] == [
+        ("compile", "jit(before)"), ("trace", "outer"),
+        ("compile", "jit(outer)")]
+
+
+def test_the_log_holds_what_the_old_rule_and_outermost_kept():
+    """The per-thread depth against the rule it replaced (store everything,
+    replace what ran inside a trace or lowering when it reports) on a real
+    run: the readers, which pass the log through ``program_trace.outermost``,
+    read the same sums. The new log also keeps a sub-millisecond trace that
+    ends within ``outermost``'s 1 ms of its own lowering (which that drops
+    again)."""
+    old = []
+
+    def old_rule(event, seconds, **kwargs):
+        kind = profiling._COMPILE_EVENTS.get(event)
+        if kind is None:
+            return
+        entry = profiling.CompileEntry(
+            kind, float(seconds), time.perf_counter(),
+            kwargs.get("fun_name"), None, None, 0)
+        if kind in ("trace", "lower"):
+            while old and old[-1].start >= entry.start:
+                old.pop()
+        old.append(entry)
+
+    jax.monitoring.register_event_duration_secs_listener(old_rule)
+    try:
+        begin = time.perf_counter()
+        trainer = tiny_trainer(accum=1)
+        state = trainer.init_state(4)
+        for batch in batches(trainer, 2, seed=4) + batches(trainer, 1, seq=8):
+            state, _ = trainer.train_step(state, batch)
+        trainer.eval_step(state, batches(trainer, 1, seed=5)[0])
+        end = time.perf_counter()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(old_rule)
+    new = [e for e in profiling.compile_log() if e.start >= begin]
+
+    def key(entries):
+        return sorted(repr((e.kind, e.fun_name, round(e.seconds, 9)))
+                      for e in entries)
+
+    assert set(key(program_trace.outermost(old))) <= set(key(new))
+    assert key(program_trace.outermost(new)) == key(
+        program_trace.outermost(old))
+    assert program_trace.compile_sums(new, [(begin, end)]) == \
+        program_trace.compile_sums(old, [(begin, end)])
+    assert {e.kind for e in new} >= {"trace", "lower", "compile"}
+
+
 def test_outermost_drops_nested_entries_and_cache_reads():
     entry = profiling.CompileEntry
     log = [entry("trace", 0.2, 10.3, "inner", None, None, 0),     # in outer
@@ -263,7 +347,42 @@ def test_compiled_step_text_needs_no_live_buffers():
     assert profiling.program_texts()["train_step"] == want
 
 
-# --- (v) tracing changes nothing that is computed ----------------------------------
+# --- (v) the collector -------------------------------------------------------
+
+def test_a_collection_in_a_traced_step_is_a_host_span_and_a_log_entry(
+        tmp_path):
+    before = profiling.gc_totals()
+    started = time.perf_counter()
+    with profiling.trace(str(tmp_path)):
+        with profiling.span("trainer:train_step", step=3):
+            gc.collect()
+    found = [stats for name, stats in host_events(tmp_path / "host_0")
+             if name == "tpu_trainer:host:gc"]
+    assert {"generation": 2} in [{"generation": s["generation"]}
+                                 for s in found]
+    assert all(set(s) == {"generation", "collected"} for s in found)
+    ours = [e for e in profiling.gc_log() if e.end > started
+            and e.generation == 2 and e.span == "trainer:train_step"]
+    assert [e.step for e in ours] == [3]
+    after = profiling.gc_totals()
+    assert after["count"][2] >= before["count"][2] + 1
+    assert after["seconds"][2] - before["seconds"][2] >= ours[0].seconds > 0
+
+
+def test_with_no_trace_open_a_collection_is_counted_and_opens_no_span():
+    before = profiling.gc_totals()
+    started = time.perf_counter()
+    for generation in (0, 1, 2):
+        gc.collect(generation)
+    after = profiling.gc_totals()
+    assert all(a >= b + 1 for a, b in zip(after["count"], before["count"]))
+    assert profiling._gc_span is None
+    full = [e for e in profiling.gc_log()
+            if e.end > started and e.generation == 2]
+    assert full and all((e.span, e.step) == (None, None) for e in full)
+
+
+# --- (vi) tracing changes nothing that is computed --------------------------
 
 def test_losses_are_bit_identical_with_a_trace_open(tmp_path):
     def losses(traced):

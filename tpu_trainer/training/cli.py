@@ -1470,10 +1470,11 @@ def run_training(argv=None, mode: str = "ddp") -> int:
     jit_warm = {"step": False, "telemetry": False}
     cost_emitted = False
     replay_until = -1   # steps <= this are rollback replay, not fresh work
-    # Recompile watchdog (ISSUE 3): executable-cache growth after warmup
-    # means XLA recompiled the step — log it; repeated growth is a storm
-    # (loader shape churn) and warns loudly.
-    watchdog = RecompileWatchdog(trainer)
+    # Recompile watchdog (ISSUE 3): a train-step compile after warmup means
+    # XLA recompiled the step — log it; repeated ones are a storm (loader
+    # shape churn) and warn loudly. A trainer rebuilt on LR backoff compiles
+    # as expected (`jit_warm` is reset with it).
+    watchdog = RecompileWatchdog()
 
     try:
         while True:
@@ -1772,10 +1773,6 @@ def run_training(argv=None, mode: str = "ddp") -> int:
                 # Buffered batches belong to the abandoned timeline; a fresh
                 # feed re-bases its cursor on the rewound loader.
                 feed = make_feed()
-                # The rebuilt trainer (LR backoff) has a fresh executable
-                # cache; re-arm the watchdog on it either way so the
-                # watermark matches the trainer actually stepping.
-                watchdog = RecompileWatchdog(trainer)
                 logger.log_record({
                     "kind": "rollback",
                     "step": int(step),

@@ -32,6 +32,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import time
 from typing import Any, Optional, Tuple
 
 import flax.struct as struct
@@ -909,22 +910,6 @@ class Trainer:
             batch = self.place_batch(batch)
         return step_jit.lower(state, batch).compile().as_text()
 
-    def executable_cache_size(self) -> Optional[int]:
-        """Number of executables cached across the train-step jit variants.
-
-        Growth after warmup means XLA recompiled the step — on TPU a
-        multi-second stall per occurrence, usually shape churn from the
-        loader. Returns None when this jax doesn't expose the private
-        cache-size hook (the watchdog then disarms rather than guessing).
-        """
-        total = 0
-        for fn in (self._step_jit, self._step_tel_jit):
-            try:
-                total += fn._cache_size()
-            except Exception:
-                return None
-        return total
-
     def nan_scan(self, state: TrainState, batch) -> dict:
         """Forward-only activation scan: where does the first NaN/Inf appear?
 
@@ -1188,51 +1173,44 @@ class RecompileWatchdog:
     ``jax.jit`` silently compiles a fresh executable for every new abstract
     input signature; a loader that churns shapes (ragged tails, bucketing
     bugs) turns each "cache miss" into a multi-second compile stall that
-    telemetry otherwise books as ordinary step time. The watchdog samples
-    ``Trainer.executable_cache_size()`` after every step: growth that the
-    training loop did not expect (first use of the plain or telemetry
-    variant is expected) produces a ``kind:"recompile"`` record carrying
-    the offending batch's abstract shape; ``warn_after`` unexpected events
-    flips ``storm`` on, the loop's cue to warn loudly.
-
-    Disarms (observe returns None forever) when the cache-size hook is
-    unavailable on this jax.
+    telemetry otherwise books as ordinary step time. The watchdog reads the
+    program's compile log (``profiling.compile_log()``) after every step:
+    backend compiles under the ``trainer:train_step`` span since the last
+    look that the training loop did not expect (first use of the plain or
+    telemetry variant is expected) produce a ``kind:"recompile"`` record
+    carrying the offending batch's abstract shape; ``warn_after`` unexpected
+    events flips ``storm`` on, the loop's cue to warn loudly.
     """
 
-    def __init__(self, trainer: Trainer, warn_after: int = 3):
-        self.trainer = trainer
+    SPAN = "trainer:train_step"
+
+    def __init__(self, warn_after: int = 3):
         self.warn_after = warn_after
         self.events: list = []
-        self._watermark: Optional[int] = None
-        self._armed = trainer.executable_cache_size() is not None
+        self.executables = 0           # train-step compiles seen so far
+        self._watermark = time.perf_counter()
 
     def observe(self, step: int, batch=None,
                 expected: bool = False) -> Optional[dict]:
-        """Sample the executable cache after ``step`` ran on ``batch``.
+        """Read the compile log after ``step`` ran on ``batch``.
 
-        ``expected=True`` raises the watermark silently (warmup compiles:
+        ``expected=True`` moves the watermark silently (warmup compiles:
         the first use of each step variant). Returns the recompile record
         to log, or None when nothing unexpected happened.
         """
-        if not self._armed:
+        since, self._watermark = self._watermark, time.perf_counter()
+        grew = sum(1 for e in profiling.compile_log()
+                   if e.kind == "compile" and e.span == self.SPAN
+                   and since < e.end <= self._watermark)
+        self.executables += grew
+        if expected or not grew:
             return None
-        size = self.trainer.executable_cache_size()
-        if size is None:
-            self._armed = False
-            return None
-        if self._watermark is None or expected:
-            self._watermark = max(self._watermark or 0, size)
-            return None
-        if size <= self._watermark:
-            return None
-        grew = size - self._watermark
-        self._watermark = size
         shape = tuple(getattr(batch, "shape", ()) or ())
         dtype = getattr(batch, "dtype", None)
         record = {
             "kind": "recompile",
             "step": int(step),
-            "executables": int(size),
+            "executables": int(self.executables),
             "new_executables": int(grew),
             "batch_abstract": "{}[{}]".format(
                 dtype if dtype is not None else "?",
