@@ -155,6 +155,43 @@ def test_chunked_scan_fwd_and_grad(chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
 
 
+def test_chunked_scan_with_the_mixers_epilogue_fwd_and_grad(chip, monkeypatch):
+    """The same scan with the skip, the gate and the grouped norm taken in
+    (`gate=`), at the cell's micro-batch: Mosaic must accept the forward that
+    writes the normalised bf16 output, the forward that also keeps `y + D x`
+    and the `[b, G, s, 1]` column of `rstd`, and the backward that reads them
+    (the 512-lane reductions, the resident rows of the skip's and the
+    weight's sums), and the gradient reaches all eight operands. No f32 `y`
+    and no f32 cotangent of it is left in HBM: less than the scan alone."""
+    from tpu_trainer.ops import ssd as ssd_ops
+
+    _scan_as_on_a_tpu(monkeypatch)
+    b, s, heads, p, groups, n = 2, 4096, 64, 64, 8, 128
+    x = chip((b, s, heads, p), jnp.bfloat16)
+    dt = chip((b, s, heads), jnp.float32)
+    a = chip((heads,), jnp.float32)
+    bc = chip((b, s, groups, n), jnp.bfloat16)
+    z = chip((b, s, heads * p), jnp.bfloat16)
+    skip = chip((heads,), jnp.float32)
+    weight = chip((heads * p,), jnp.float32)
+
+    def fwd(x, dt, a, b_in, c_in, z, skip, weight):
+        return ssd_ops.ssd(x, dt, a, b_in, c_in, chunk=128,
+                           gate=(z, skip, weight, 1e-5))[0]
+
+    def loss(*args):
+        return fwd(*args).astype(jnp.float32).sum()
+
+    args = (x, dt, a, bc, bc, z, skip, weight)
+    text = _compile(fwd, *args)
+    assert "reduce-window" not in text
+    compiled = jax.jit(jax.grad(loss, argnums=range(8))).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2 and "reduce-window" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
 def test_mamba_mixer_value_and_grad_through_its_checkpoint(chip, monkeypatch):
     """One `Mamba2Mixer` at the cell's widths and micro-batch (2 x 4,096
     tokens of 2,688 lanes, bf16): value and gradient through the module's own

@@ -113,6 +113,125 @@ def test_the_kernels_are_the_chunked_scan_and_the_recurrence(
         assert _rel(g, w) < 1.5 * bound, name
 
 
+def _gate_inputs(batch, seq, heads, p, groups, dtype, quiet=False):
+    """``z``, ``D`` and the norm's weight for a call of these shapes. With
+    ``quiet``, the first 64 tokens of the first group are gated to about
+    1e-12 (``silu(-30)``), so that ``eps`` is nearly all of their mean square
+    and sets their ``rstd``."""
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    z = jax.random.normal(ks[0], (batch, seq, heads * p))
+    if quiet:
+        z = z.at[:, :64, :heads * p // groups].set(-30.0)
+    skip = 1.0 + 0.5 * jax.random.normal(ks[1], (heads,))
+    weight = 1.0 + 0.5 * jax.random.normal(ks[2], (heads * p,))
+    return z.astype(dtype), skip, weight
+
+
+def _epilogue_of(scan, dtype):
+    """``scan`` (five operands to f32 ``y``) followed by the mixer's epilogue
+    in plain XLA, rounded where ``ssd`` rounds."""
+    def run(x, dt, a, b, c, z, skip, weight):
+        y = scan(x, dt, a, b, c)
+        return ssd_ops._epilogue(y, x, z, skip, weight, EPS, b.shape[2],
+                                 dtype)
+    return run
+
+
+EPS = 1e-5
+GATE_CASES = {**CASES, "quiet_group": dict(
+    batch=1, seq=256, heads=16, p=64, groups=2, quiet=True)}
+
+
+@pytest.mark.parametrize("case", GATE_CASES)
+def test_the_fused_epilogue_is_the_plain_one_and_the_recurrences(
+        case, interpreted):
+    """The scan with the skip, the gate and the grouped norm taken in
+    (``gate=``) against ``_chunked`` followed by the plain epilogue, and
+    against the recurrence followed by it in float32: the output, and the
+    gradients in all eight operands."""
+    spec = dict(GATE_CASES[case])
+    dtype = jnp.dtype(spec.pop("dtype", "float32"))
+    quiet = spec.pop("quiet", False)
+    args = _inputs(n=128, **spec)
+    gate = _gate_inputs(spec["batch"], spec["seq"], spec["heads"], spec["p"],
+                        spec["groups"], dtype, quiet)
+    cast = tuple(v.astype(dtype) if i in (0, 3, 4) else v
+                 for i, v in enumerate(args)) + gate
+    wide = tuple(v.astype(F32) for v in cast)
+    low_bits = dtype == jnp.bfloat16
+    tol = 2e-2 if low_bits else 2e-5
+    probe = jax.random.normal(jax.random.PRNGKey(9), gate[0].shape)
+
+    def kernels(x, dt, a, b, c, z, skip, weight):
+        return ssd_ops.ssd(x, dt, a, b, c, chunk=128,
+                           gate=(z, skip, weight, EPS))
+
+    plain = _epilogue_of(lambda *a: ssd_ops._chunked(*a, 128, dtype)[0],
+                         dtype)
+    exact = _epilogue_of(family.scan_recurrence, F32)
+
+    def grads(run, operands):
+        return jax.jit(jax.grad(
+            lambda *a: jnp.sum(run(*a).astype(F32) * probe),
+            argnums=range(8)))(*operands)
+
+    with jax.default_matmul_precision("highest"):
+        got, low = jax.jit(kernels)(*cast)
+        want = jax.jit(plain)(*cast)
+        truth = jax.jit(exact)(*wide)
+        got_g = grads(lambda *a: kernels(*a)[0], cast)
+        # Under the mixer's own recomputation: the forward pass without
+        # what a backward keeps, the backward from the recomputed forward.
+        again_g = grads(jax.checkpoint(lambda *a: kernels(*a)[0]), cast)
+        want_g = grads(plain, cast)
+        exact_g = grads(exact, wide)
+    _, want_low = jax.jit(lambda *a: ssd_ops._chunked(*a, 128, dtype))(
+        *cast[:5])
+    assert got.shape == gate[0].shape and got.dtype == dtype
+    assert bool(jnp.all(jnp.isfinite(got.astype(F32))))
+    # Rounded to the compute type at the end: within a unit of its last
+    # place of the plain form, nearer still the float32 recurrence's.
+    assert _rel(got, want) < (8e-3 if low_bits else 2e-5)
+    assert _rel(got, truth) < (6e-3 if low_bits else 5e-5)
+    assert float(low) == pytest.approx(float(want_low), rel=1e-5)
+    if quiet:
+        rows = (slice(None), slice(None, 64), slice(None, gate[0].shape[2]
+                                                    // spec["groups"]))
+        # eps sets their rstd (about 316): the output is z's gate, scaled.
+        assert float(jnp.max(jnp.abs(truth[rows]))) < 1e-6
+        assert _rel(got[rows], truth[rows]) < 5 * tol
+    slack = 40 if spec.get("dt_scale", 1.0) > 1 else 10
+    for name, g, r, w, e in zip("x dt a b c z D weight".split(), got_g,
+                                again_g, want_g, exact_g):
+        assert g.dtype == w.dtype, name
+        assert bool(jnp.all(jnp.isfinite(g.astype(F32)))), name
+        assert _rel(r, g) < 1e-6, name
+        bound = slack * tol
+        if name == "a" and spec.get("dt_scale", 1.0) > 1:
+            # As the scan alone: where most decays are zero, dA is what
+            # float32 leaves of sums that cancel.
+            bound = max(bound, 2 * _rel(w, e))
+        assert _rel(g, e) < bound, name
+        assert _rel(g, w) < 1.5 * bound, name
+
+
+def test_without_the_epilogue_the_scan_keeps_its_contract(interpreted):
+    """``ssd`` called as before returns ``y [b, s, H, P]`` in float32, and
+    through the same kernel body ``D x`` and the gate are not applied: the
+    fused call at ``D = 0``, ``z`` large and the weight 1 is the plain
+    scan's ``y`` normalised."""
+    args = _inputs(batch=1, seq=256, heads=8, p=64, groups=1, n=128)
+    y, _ = jax.jit(lambda *a: ssd_ops.ssd(*a, chunk=128))(*args)
+    assert y.shape == args[0].shape and y.dtype == F32
+    z = jnp.full((1, 256, 512), 30.0)              # silu(30) = 30
+    fused, _ = jax.jit(lambda *a: ssd_ops.ssd(
+        *a, chunk=128, gate=(z, jnp.zeros(8), jnp.ones(512), EPS)))(*args)
+    flat = y.reshape(1, 256, 512)
+    want = flat * jax.lax.rsqrt(jnp.mean(flat * flat, axis=-1,
+                                         keepdims=True) + EPS / 900)
+    assert _rel(fused, want) < 2e-5
+
+
 # --- which calls take the kernels ------------------------------------------------
 
 def _mixer_counts(seq, batch=1, **sizes):
@@ -213,3 +332,33 @@ def test_under_a_mesh_the_kernels_shard_the_batch_or_stand_aside(interpreted):
         argnums=range(5)))(*args)
     for g, w in zip(grads, want_g):
         assert _rel(g, w) < 2e-4
+
+
+def test_under_a_mesh_the_fused_epilogue_shards_the_batch(interpreted):
+    """With the epilogue taken in, under a 2 x 2 mesh: the output row by row,
+    and the gradients of ``D`` and the norm's weight (summed over the
+    programs inside a shard, then over the shards) as off the mesh."""
+    from tpu_trainer.parallel.context import mesh_scope
+    from tpu_trainer.parallel.mesh import MeshConfig, make_mesh
+
+    args = _inputs(batch=4, seq=256, heads=2, p=64, groups=1, n=128)
+    args += _gate_inputs(4, 256, 2, 64, 1, F32)
+
+    def fused(x, dt, a, b, c, z, skip, weight):
+        return ssd_ops.ssd(x, dt, a, b, c, chunk=128,
+                           gate=(z, skip, weight, EPS))[0]
+
+    def loss(*a):
+        return jnp.sum(fused(*a) ** 2)
+
+    want = jax.jit(fused)(*args)
+    want_g = jax.jit(jax.grad(loss, argnums=range(8)))(*args)
+    mesh = make_mesh(MeshConfig(data=2, fsdp=2), devices=jax.devices()[:4])
+    with mesh_scope(mesh):
+        assert ssd_ops.kernel_path(args[0].shape, args[3].shape,
+                                   128)[1] is mesh
+        got = jax.jit(fused)(*args)
+        got_g = jax.jit(jax.grad(loss, argnums=range(8)))(*args)
+    assert _rel(got, want) < 2e-5
+    for name, g, w in zip("x dt a b c z D weight".split(), got_g, want_g):
+        assert _rel(g, w) < 2e-4, name

@@ -861,8 +861,9 @@ def _mamba_core(cfg: GPTConfig, zxbcdt, conv_weight, conv_bias, dt_bias,
                 a_log, skip, norm_weight):
     """A Mamba-2 mixer between its two projections: ``[z, xBC, dt]`` in,
     the gated, group-normalised ``y [batch, seq, d_in]`` out (and the scan's
-    most negative in-chunk log-decay). One function so that the mixer can
-    recompute it in the backward (``Mamba2Mixer``)."""
+    most negative in-chunk log-decay); the skip, the gate and the norm are
+    ``ssd``'s epilogue. One function so that the mixer can recompute it in
+    the backward (``Mamba2Mixer``)."""
     b, s, _ = zxbcdt.shape
     heads, p = cfg.mamba_num_heads, cfg.mamba_head_dim
     groups, n = cfg.mamba_n_groups, cfg.ssm_state_size
@@ -874,18 +875,11 @@ def _mamba_core(cfg: GPTConfig, zxbcdt, conv_weight, conv_bias, dt_bias,
                                   conv_bias.astype(cd)))
     x, b_in, c_in = jnp.split(xbc, [inner, inner + groups * n], axis=-1)
     dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
-    x = x.reshape(b, s, heads, p)
-    y, low = ssd(x, dt, -jnp.exp(a_log.astype(f32)),
-                 b_in.reshape(b, s, groups, n), c_in.reshape(b, s, groups, n),
-                 chunk=cfg.mamba_chunk_size, dtype=cd)
-    y = (y + skip.astype(f32)[:, None] * x.astype(f32)).astype(cd)
-    # The gate first, then the norm, over each group's lanes in f32.
-    gated = (y.reshape(b, s, inner) * nn.silu(z)).astype(f32).reshape(
-        b, s, groups, inner // groups)
-    normed = gated * jax.lax.rsqrt(
-        jnp.mean(jnp.square(gated), axis=-1, keepdims=True) + cfg.norm_eps)
-    normed = normed.reshape(b, s, inner) * norm_weight.astype(f32)
-    return normed.astype(cd), low
+    # The scan with the skip, the gate and the grouped norm taken in.
+    return ssd(x.reshape(b, s, heads, p), dt, -jnp.exp(a_log.astype(f32)),
+               b_in.reshape(b, s, groups, n), c_in.reshape(b, s, groups, n),
+               chunk=cfg.mamba_chunk_size, dtype=cd,
+               gate=(z, skip, norm_weight, cfg.norm_eps))
 
 
 class Mamba2Mixer(nn.Module):
@@ -905,11 +899,13 @@ class Mamba2Mixer(nn.Module):
     in the backward from ``[z, xBC, dt]``, as the family's fused kernels do:
     the taps, their silu, the scan (on a TPU the Pallas kernels of
     ``ops/ssd.py``, whose ``[chunk, chunk]`` scores and decays never leave
-    VMEM; the states its chunks start from live only inside the block's
-    backward), the gate and the norm never live between the passes; a block
-    keeps its in-projection's result and the normalised ``y``. The step
-    metrics say what ran: ``ssm_tokens`` the tokens scanned,
-    ``ssd_kernel_tokens`` those of them whose scan took the kernels.
+    VMEM, and which add the skip and apply the gate and the norm to a chunk
+    while its ``y`` is still there; the states its chunks start from live
+    only inside the block's backward), the gate and the norm never live
+    between the passes; a block keeps its in-projection's result and the
+    normalised ``y``. The step metrics say what ran: ``ssm_tokens`` the
+    tokens scanned, ``ssd_kernel_tokens`` those of them whose scan took the
+    kernels.
 
     ``A_log``, ``dt_bias`` and ``D`` are consumed in float32
     (``F32_LEAVES``: whoever keeps a compute-type copy of the parameters
@@ -956,7 +952,8 @@ class Mamba2Mixer(nn.Module):
             zxbcdt, *leaves)
         tokens = u.shape[0] * u.shape[1]
         telemetry.count("ssm_tokens", jnp.asarray(tokens, f32))
-        # The tokens whose scan took the Pallas kernels (``ops/ssd.py``).
+        # The tokens whose scan took the Pallas kernels (``ops/ssd.py``), and
+        # with it the skip, the gate and the grouped norm inside them.
         kernels = ssd_kernel_path(
             (*u.shape[:2], heads, cfg.mamba_head_dim),
             (*u.shape[:2], cfg.mamba_n_groups, cfg.ssm_state_size),

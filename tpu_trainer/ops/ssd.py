@@ -45,6 +45,13 @@ published mesh), no option:
   the backward (as large as ``y``). The same mathematics, rounded where
   ``_chunked`` rounds.
 
+The Mamba-2 mixer's epilogue (``ssd(..., gate=...)``: the skip ``D_h x``,
+the gate ``* silu(z)`` and the RMS norm over each group's lanes) goes with
+the scan: after ``_chunked`` it is plain XLA (``_epilogue``); in the kernels
+a program's lanes are one norm group's, so the forward finishes a chunk's
+normalised rows with its ``y`` still in VMEM and writes them in the compute
+type, and the backward starts from their cotangent.
+
 ``models/gpt.py`` ``Mamba2Mixer`` recomputes the scan with the vector work
 round it in every backward (``jax.checkpoint``), so neither form's residuals
 live between the passes.
@@ -242,9 +249,36 @@ def _keep(mask, v):
     return v if mask is None else jnp.where(mask, v, jnp.zeros_like(v))
 
 
-def _fwd_kernel(x_ref, dtr_ref, dtc_ref, ac_ref, ar_ref, b_ref, c_ref, y_ref,
-                *rest, p: int):
-    starts_ref, state = rest if len(rest) == 2 else (None, rest[0])
+def _silu_parts(z_ref, lanes):
+    """``z``'s slab in float32, its sigmoid and ``silu(z)``."""
+    z = z_ref[0, :, lanes].astype(F32)
+    sig = jax.nn.sigmoid(z)
+    return z, sig, z * sig
+
+
+def _gated(skipped, silu, dtype):
+    """The gate ``y * silu(z)``, rounded to the compute type as the plain
+    path's product of two compute-type values is, read back in float32."""
+    return (skipped.astype(F32) * silu).astype(dtype).astype(F32)
+
+
+def _fwd_kernel(*refs, p: int, gate: bool, keep: bool, eps):
+    """One chunk of one group: the scan's ``y`` a slab at a time, then, with
+    ``gate``, the mixer's epilogue over the group's lanes (``_epilogue``'s
+    mathematics): ``y + D x`` rounded, times ``silu(z)``, RMS-normalised over
+    the group, times the weight. Refs: ``x, dt`` (row, column), ``a`` (column,
+    row), ``B, C``, [``z``, ``D`` (a row a group), weight]; out: ``y`` (f32;
+    with ``gate`` the normalised output), [the rounded ``y + D x`` and the
+    ``rstd`` column, with ``gate``], [the chunks' starting states]; scratch:
+    the carried state."""
+    (x_ref, dtr_ref, dtc_ref, ac_ref, ar_ref, b_ref, c_ref), refs = (
+        refs[:7], refs[7:])
+    if gate:
+        (z_ref, skip_ref, w_ref), refs = refs[:3], refs[3:]
+    y_ref, refs = refs[0], refs[1:]
+    if gate and keep:
+        (skipped_ref, rstd_ref), refs = refs[:2], refs[2:]
+    starts_ref, state = refs if keep else (None, refs[0])
 
     @pl.when(pl.program_id(2) == 0)
     def _from_zero():
@@ -262,7 +296,9 @@ def _fwd_kernel(x_ref, dtr_ref, dtc_ref, ac_ref, ar_ref, b_ref, c_ref, y_ref,
     scores = jax.lax.dot_general(cm, bm, _NT, preferred_element_type=F32)
     b_t = bm.T
     causal = sub >= lane
-    for slab in range(x_ref.shape[2] // _LANES):
+    slabs = x_ref.shape[2] // _LANES
+    gated = []
+    for slab in range(slabs):
         lanes = slice(slab * _LANES, (slab + 1) * _LANES)
         xs, start = x_ref[0, :, lanes], state[slab]
         if starts_ref is not None:
@@ -277,24 +313,100 @@ def _fwd_kernel(x_ref, dtr_ref, dtc_ref, ac_ref, ar_ref, b_ref, c_ref, y_ref,
             weights = scores * decay * dt_row[head:head + 1, :]
             y = y + jnp.dot(weights.astype(dtype), _keep(mask, xs),
                             preferred_element_type=F32)
-        y_ref[0, :, lanes] = y
+        if gate:
+            skipped = (y + _spread(skip_ref[0], slab, p)
+                       * xs.astype(F32)).astype(dtype)
+            if keep:
+                skipped_ref[0, :, lanes] = skipped
+            gated.append(_gated(skipped, _silu_parts(z_ref, lanes)[2], dtype))
+        else:
+            y_ref[0, :, lanes] = y
         # 2. and 3. The state the next chunk starts from.
         scaled = (xs.astype(F32) * _spread(to_end, slab, p)).astype(dtype)
         state[slab] = start * _spread(whole, slab, p) + jnp.dot(
             b_t, scaled, preferred_element_type=F32)
+    if gate:
+        # The mean of squares over the group's lanes: the slabs, then the
+        # lanes.
+        squares = sum(g * g for g in gated)
+        rstd = jax.lax.rsqrt(jnp.sum(squares, axis=1, keepdims=True)
+                             / (slabs * _LANES) + eps)       # [Q, 1]
+        if keep:
+            rstd_ref[0, 0] = rstd
+        for slab, g in enumerate(gated):
+            lanes = slice(slab * _LANES, (slab + 1) * _LANES)
+            y_ref[0, :, lanes] = (g * rstd * w_ref[:, lanes]).astype(
+                y_ref.dtype)
 
 
-def _bwd_kernel(x_ref, dtr_ref, dtc_ref, ac_ref, ar_ref, b_ref, c_ref,
-                starts_ref, dy_ref, dx_ref, db_ref, dc_ref, ddt_ref,
-                dlog_ref, dstate, *, p: int):
+def _gate_bwd(x_ref, z_ref, skipped_ref, rstd_ref, do_ref, w_ref, dz_ref,
+              dskip_ref, dw_ref, dy_ref, dtype):
+    """The epilogue's backward for one chunk of one group, in float32: from
+    the output's cotangent to ``y``'s (into ``dy_ref``, the scan's cotangent
+    as the kernel without the epilogue reads it), ``z``'s (written), and the
+    chunk's share of the skip's and the weight's, a lane each (added to the
+    program's resident rows)."""
+    @pl.when(pl.program_id(2) == 0)
+    def _from_zero():
+        dskip_ref[...] = jnp.zeros_like(dskip_ref)
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    slabs = x_ref.shape[2] // _LANES
+    rstd = rstd_ref[0, 0]                                    # [Q, 1]
+    parts, inner = [], 0.0
+    for slab in range(slabs):
+        lanes = slice(slab * _LANES, (slab + 1) * _LANES)
+        z, sig, silu = _silu_parts(z_ref, lanes)
+        skipped = skipped_ref[0, :, lanes].astype(F32)
+        normed = _gated(skipped, silu, dtype) * rstd
+        dout = do_ref[0, :, lanes].astype(F32)
+        dnormed = dout * w_ref[:, lanes]
+        inner = inner + dnormed * normed
+        dw_ref[0, :, lanes] += jnp.sum(dout * normed, axis=0, keepdims=True)
+        parts.append((z, sig, silu, skipped, normed, dnormed))
+    mean = jnp.sum(inner, axis=1, keepdims=True) / (slabs * _LANES)
+    for slab, (z, sig, silu, skipped, normed, dnormed) in enumerate(parts):
+        lanes = slice(slab * _LANES, (slab + 1) * _LANES)
+        dgated = rstd * (dnormed - normed * mean)
+        dy = dgated * silu
+        dy_ref[:, lanes] = dy
+        dsilu = sig * (1.0 + z * (1.0 - sig))
+        dz_ref[0, :, lanes] = (dgated * skipped * dsilu).astype(dz_ref.dtype)
+        dskip_ref[0, :, lanes] += jnp.sum(
+            dy * x_ref[0, :, lanes].astype(F32), axis=0, keepdims=True)
+
+
+def _bwd_kernel(*refs, p: int, gate: bool):
+    """The backward of ``_fwd_kernel`` for one chunk, the chunks in reverse.
+    Refs: the forward's operands, the starting states, then the cotangent of
+    ``y`` (f32) or, with ``gate``, ``z``, ``D``, the weight, the rounded ``y +
+    D x`` and ``rstd``, and the output's cotangent; out: ``dx, dB, dC``, the
+    ``dt`` and log-decay columns, [``dz`` and the skip's and weight's rows];
+    scratch: the state's cotangent, [``dy``]."""
+    (x_ref, dtr_ref, dtc_ref, ac_ref, ar_ref, b_ref, c_ref, starts_ref
+     ), refs = refs[:8], refs[8:]
+    if gate:
+        (z_ref, skip_ref, w_ref, skipped_ref, rstd_ref, do_ref), refs = (
+            refs[:6], refs[6:])
+    else:
+        dy_ref, refs = refs[0], refs[1:]
+    (dx_ref, db_ref, dc_ref, ddt_ref, dlog_ref), refs = refs[:5], refs[5:]
+    if gate:
+        (dz_ref, dskip_ref, dw_ref, dstate, dy_ref) = refs
+    else:
+        (dstate,) = refs
+
     @pl.when(pl.program_id(2) == 0)
     def _from_zero():
         dstate[...] = jnp.zeros_like(dstate)
 
+    dtype = x_ref.dtype
+    if gate:
+        _gate_bwd(x_ref, z_ref, skipped_ref, rstd_ref, do_ref, w_ref, dz_ref,
+                  dskip_ref, dw_ref, dy_ref, dtype)
     dt_row, dt_col, cum_row, cum_col, sub, lane = _decays(
         dtr_ref, dtc_ref, ac_ref, ar_ref)
     q, heads = dt_col.shape
-    dtype = x_ref.dtype
     total = cum_col[q - 1:q, :]
     through = jnp.exp(cum_col)
     rest = jnp.exp(total - cum_col)                          # to the end
@@ -320,7 +432,8 @@ def _bwd_kernel(x_ref, dtr_ref, dtc_ref, ac_ref, ar_ref, b_ref, c_ref,
     dtotal = jnp.zeros((1, heads), F32)
     for slab in range(x_ref.shape[2] // _LANES):
         lanes = slice(slab * _LANES, (slab + 1) * _LANES)
-        xs, dy = x_ref[0, :, lanes], dy_ref[0, :, lanes]
+        xs = x_ref[0, :, lanes]
+        dy = dy_ref[:, lanes] if gate else dy_ref[0, :, lanes]
         start, dend = starts_ref[0, 0, 0, slab], dstate[slab]
         dy_low, dend_low = dy.astype(dtype), dend.astype(dtype)
         to_end_s = _spread(to_end, slab, p)
@@ -340,6 +453,8 @@ def _bwd_kernel(x_ref, dtr_ref, dtc_ref, ac_ref, ar_ref, b_ref, c_ref,
         db = db + jax.lax.dot_general(scaled, dend_low, _NT,
                                       preferred_element_type=F32)
         dx = dscaled * to_end_s
+        if gate:                                     # the skip's part of dx
+            dx = dx + _spread(skip_ref[0], slab, p) * dy
         dto_end = dscaled * xs.astype(F32)                   # summed a head
         kept = jnp.sum(dend * start, axis=0, keepdims=True)  # [1, 128]
         dstate[slab] = dend * whole_s + dstart
@@ -403,10 +518,21 @@ def _specs(batch, seq, heads, p, groups, n, chunk, reverse: bool):
                          lambda ib, ig, ic: (ib, ig, at(ic))),
         col=pl.BlockSpec((1, 1, chunk, per),                 # [b, G, s, h]
                          lambda ib, ig, ic: (ib, ig, at(ic), 0)),
+        rstd=pl.BlockSpec((1, 1, chunk, 1),                  # [b, G, s, 1]
+                          lambda ib, ig, ic: (ib, ig, at(ic), 0)),
         a_col=pl.BlockSpec((1, per, 1), lambda ib, ig, ic: (ig, 0, 0)),
         a_row=pl.BlockSpec((1, 1, per), lambda ib, ig, ic: (ig, 0, 0)),
+        weight=pl.BlockSpec((1, per * p),                    # [1, H P]
+                            lambda ib, ig, ic: (0, ig)),
+        # [b, 1, H P]: a program's sum over its chunks, resident while the
+        # chunks go by.
+        partial=pl.BlockSpec((1, 1, per * p), lambda ib, ig, ic: (ib, 0, ig)),
         starts=pl.BlockSpec((1, 1, 1, per * p // _LANES, n, _LANES),
                             lambda ib, ig, ic: (ib, ig, at(ic), 0, 0, 0)))
+
+
+_OPERAND_SPECS = ("lanes", "row", "col", "a_col", "a_row", "state", "state")
+_GATE_SPECS = ("lanes", "a_row", "weight")
 
 
 def _operands(x, dt, a, b, c):
@@ -420,6 +546,14 @@ def _operands(x, dt, a, b, c):
             _columns(dt, groups), a[:, :, None], a[:, None, :],
             b.reshape(batch, seq, groups * n),
             c.reshape(batch, seq, groups * n))
+
+
+def _gate_operands(gate, groups):
+    """``(z, D, weight)`` in the kernels' layouts: ``D`` a row a group, the
+    weight one row."""
+    z, skip, weight = gate
+    return (z, skip.astype(F32).reshape(groups, 1, -1),
+            weight.astype(F32).reshape(1, -1))
 
 
 def _columns(v, groups):
@@ -442,95 +576,141 @@ def _params(interpret):
         interpret=interpret)
 
 
-def _forward(x, dt, a, b, c, *, chunk, interpret, keep_starts):
-    """``y [b, s, H, P]`` float32 and, for a backward, the state every chunk
-    starts from (``[b, G, chunks, slabs, N, 128]`` float32, transposed)."""
+def _forward(x, dt, a, b, c, gate, *, chunk, interpret, keep, eps):
+    """``y [b, s, H, P]`` float32 or, with ``gate`` (``(z, D, weight)``), the
+    normalised output ``[b, s, H P]`` in ``x``'s type; for a backward, the
+    state every chunk starts from (``[b, G, chunks, slabs, N, 128]`` float32,
+    transposed) and, with ``gate``, the rounded ``y + D x`` and ``rstd``
+    (``[b, G, s, 1]`` float32)."""
     from jax.experimental.pallas import tpu as pltpu
 
     batch, seq, heads, p = x.shape
     groups, n = b.shape[2], b.shape[3]
     spec = _specs(batch, seq, heads, p, groups, n, chunk, reverse=False)
     slabs = heads // groups * p // _LANES
-    outs = [(spec["lanes"],
-             jax.ShapeDtypeStruct((batch, seq, heads * p), F32))]
-    if keep_starts:
+    lanes = (batch, seq, heads * p)
+    outs = [(spec["lanes"], jax.ShapeDtypeStruct(
+        lanes, x.dtype if gate else F32))]
+    if gate and keep:
+        outs += [(spec["lanes"], jax.ShapeDtypeStruct(lanes, x.dtype)),
+                 (spec["rstd"], jax.ShapeDtypeStruct(
+                     (batch, groups, seq, 1), F32))]
+    if keep:
         outs.append((spec["starts"], jax.ShapeDtypeStruct(
             (batch, groups, seq // chunk, slabs, n, _LANES), F32)))
-    y, *starts = pl.pallas_call(
-        functools.partial(_fwd_kernel, p=p),
+    y, *saved = pl.pallas_call(
+        functools.partial(_fwd_kernel, p=p, gate=bool(gate), keep=keep,
+                          eps=eps),
         grid=(batch, groups, seq // chunk),
-        in_specs=[spec[k] for k in ("lanes", "row", "col", "a_col", "a_row",
-                                    "state", "state")],
+        in_specs=[spec[k] for k in _OPERAND_SPECS
+                  + (_GATE_SPECS if gate else ())],
         out_specs=[s for s, _ in outs], out_shape=[s for _, s in outs],
         scratch_shapes=[pltpu.VMEM((slabs, n, _LANES), F32)],
-        **_params(interpret))(*_operands(x, dt, a, b, c))
-    return y.reshape(x.shape), *starts
+        **_params(interpret))(
+            *_operands(x, dt, a, b, c),
+            *(_gate_operands(gate, groups) if gate else ()))
+    return (y if gate else y.reshape(x.shape)), tuple(saved)
 
 
-def _backward(x, dt, a, b, c, starts, dy, *, chunk, interpret):
+def _backward(x, dt, a, b, c, gate, saved, dy, *, chunk, interpret):
+    """The cotangents of ``x, dt, a, B, C`` and, with ``gate``, of ``z``,
+    ``D`` and the weight, from ``y``'s (or the normalised output's) and what
+    ``_forward`` saved."""
     from jax.experimental.pallas import tpu as pltpu
 
     batch, seq, heads, p = x.shape
     groups, n = b.shape[2], b.shape[3]
     spec = _specs(batch, seq, heads, p, groups, n, chunk, reverse=True)
+    slabs = heads // groups * p // _LANES
+    lanes = (batch, seq, heads * p)
     cols = jax.ShapeDtypeStruct((batch, groups, seq, heads // groups), F32)
-    dx, db, dc, ddt, dlog = pl.pallas_call(
-        functools.partial(_bwd_kernel, p=p),
+    if gate:
+        *saved_gate, starts = saved
+        cotangents = ((spec["lanes"], spec["rstd"], spec["lanes"]),
+                      (*saved_gate, dy))
+    else:
+        (starts,) = saved
+        # The cotangent as it comes, f32: rounded to the operands' type
+        # outside, it cost a pass of its own (PERF.md, section 6).
+        cotangents = ((spec["lanes"],), (dy.astype(F32).reshape(lanes),))
+    outs = [(spec["lanes"], jax.ShapeDtypeStruct(lanes, x.dtype)),
+            (spec["state"], jax.ShapeDtypeStruct(
+                (batch, seq, groups * n), b.dtype)),
+            (spec["state"], jax.ShapeDtypeStruct(
+                (batch, seq, groups * n), c.dtype)),
+            (spec["col"], cols), (spec["col"], cols)]
+    scratch = [pltpu.VMEM((slabs, n, _LANES), F32)]
+    if gate:
+        part = jax.ShapeDtypeStruct((batch, 1, heads * p), F32)
+        outs += [(spec["lanes"], jax.ShapeDtypeStruct(lanes, gate[0].dtype)),
+                 (spec["partial"], part), (spec["partial"], part)]
+        scratch.append(pltpu.VMEM((chunk, slabs * _LANES), F32))
+    dx, db, dc, ddt, dlog, *dgate = pl.pallas_call(
+        functools.partial(_bwd_kernel, p=p, gate=bool(gate)),
         grid=(batch, groups, seq // chunk),
-        in_specs=[spec[k] for k in ("lanes", "row", "col", "a_col", "a_row",
-                                    "state", "state", "starts", "lanes")],
-        out_specs=[spec[k] for k in ("lanes", "state", "state", "col", "col")],
-        out_shape=[jax.ShapeDtypeStruct((batch, seq, heads * p), x.dtype),
-                   jax.ShapeDtypeStruct((batch, seq, groups * n), b.dtype),
-                   jax.ShapeDtypeStruct((batch, seq, groups * n), c.dtype),
-                   cols, cols],
-        scratch_shapes=[pltpu.VMEM((heads // groups * p // _LANES, n, _LANES),
-                                   F32)],
-        **_params(interpret))(
+        in_specs=[spec[k] for k in _OPERAND_SPECS + ("starts",)]
+        + ([spec[k] for k in _GATE_SPECS] if gate else [])
+        + list(cotangents[0]),
+        out_specs=[s for s, _ in outs], out_shape=[s for _, s in outs],
+        scratch_shapes=scratch, **_params(interpret))(
             *_operands(x, dt, a, b, c), starts,
-            # The cotangent as it comes, f32: rounded to the operands' type
-            # outside, it cost a pass of its own (PERF.md section 6, PR 36).
-            dy.astype(F32).reshape(batch, seq, heads * p))
+            *(_gate_operands(gate, groups) if gate else ()), *cotangents[1])
     # The gradient with respect to log-decay dt a, token by token; dA is its
     # sum over batch and sequence against dt: XLA's.
     dlog = _rows(dlog)
     ddt = _rows(ddt) + dlog * a.astype(F32)
     da = jnp.sum(dlog * dt.astype(F32), axis=(0, 1))
-    return (dx.reshape(x.shape), ddt.astype(dt.dtype), da.astype(a.dtype),
-            db.reshape(b.shape), dc.reshape(c.shape))
+    grads = (dx.reshape(x.shape), ddt.astype(dt.dtype), da.astype(a.dtype),
+             db.reshape(b.shape), dc.reshape(c.shape))
+    if not gate:
+        return grads
+    # The skip's and the weight's lanes summed over the programs (and the
+    # skip's over its heads' lanes): XLA's.
+    dz, dskip, dw = dgate
+    dskip = jnp.sum(dskip.reshape(batch, heads, p), axis=(0, 2))
+    dw = jnp.sum(dw, axis=(0, 1))
+    return grads + (dz, dskip.astype(gate[1].dtype),
+                    dw.astype(gate[2].dtype))
 
 
 @functools.lru_cache(maxsize=None)
-def _kernels(chunk: int, interpret: bool):
-    """The scan through the kernels, differentiable in all five operands.
-    The bodies are jitted, so that a step's call sites (four blocks, each
-    forward, recomputed and backward) trace and lower each once a shape."""
+def _kernels(chunk: int, interpret: bool, eps):
+    """The scan through the kernels, differentiable in all its operands:
+    ``x, dt, a, B, C`` and, where ``eps`` is given (the epilogue's), ``z``,
+    ``D`` and the norm's weight. The bodies are jitted, so that a step's call
+    sites (four blocks, each forward, recomputed and backward) trace and
+    lower each once a shape. Under ``jax.checkpoint`` the forward pass runs
+    ``ssd_fwd`` alone and the backward recomputes ``ssd_fwd_keeping``."""
     kw = dict(chunk=chunk, interpret=interpret)
 
     @jax.jit
-    def ssd_fwd(x, dt, a, b, c):
-        return _forward(x, dt, a, b, c, keep_starts=False, **kw)[0]
+    def ssd_fwd(operands, gate):
+        return _forward(*operands, gate, keep=False, eps=eps, **kw)[0]
 
     @jax.jit
-    def ssd_fwd_keeping(x, dt, a, b, c):
-        return _forward(x, dt, a, b, c, keep_starts=True, **kw)
+    def ssd_fwd_keeping(operands, gate):
+        return _forward(*operands, gate, keep=True, eps=eps, **kw)
 
     @jax.jit
-    def ssd_bwd(x, dt, a, b, c, starts, dy):
-        return _backward(x, dt, a, b, c, starts, dy, **kw)
+    def ssd_bwd(operands, gate, saved, dy):
+        return _backward(*operands, gate, saved, dy, **kw)
+
+    def split(args):
+        return args[:5], args[5:]
 
     @jax.custom_vjp
-    def scan(x, dt, a, b, c):
-        return ssd_fwd(x, dt, a, b, c)
+    def scan(*args):
+        return ssd_fwd(*split(args))
 
-    def fwd(x, dt, a, b, c):
-        y, starts = ssd_fwd_keeping(x, dt, a, b, c)
-        return y, (x, dt, a, b, c, starts)
+    def fwd(*args):
+        y, saved = ssd_fwd_keeping(*split(args))
+        return y, (args, saved)
 
     def bwd(res, dy):
-        return ssd_bwd(*res, dy)
+        args, saved = res
+        return ssd_bwd(*split(args), saved, dy)
 
-    scan.defvjp(fwd, bwd)
+    scan.defvjp(fwd, bwd, optimize_remat=True)
     return scan
 
 
@@ -556,11 +736,11 @@ def kernel_path(x_shape, b_shape, chunk: int):
     return None if b_spec is None else (interpret, mesh, b_spec)
 
 
-def _kernel_scan(x, dt, a, b, c, chunk, path):
+def _kernel_scan(operands, gate, eps, chunk, path):
     interpret, mesh, b_spec = path
-    scan = _kernels(chunk, interpret)
+    scan = _kernels(chunk, interpret, eps)
     if mesh is None:
-        return scan(x, dt, a, b, c)
+        return scan(*operands, *gate)
     from jax.sharding import PartitionSpec as P
 
     from tpu_trainer.parallel.context import kernel_manual_axes
@@ -568,11 +748,35 @@ def _kernel_scan(x, dt, a, b, c, chunk, path):
 
     rows4, rows3 = P(b_spec, None, None, None), P(b_spec, None, None)
     return shard_map(
-        scan, mesh=mesh, in_specs=(rows4, rows3, P(None), rows4, rows4),
-        out_specs=rows4, axis_names=kernel_manual_axes(mesh, set(b_spec)),
-        check_vma=False)(x, dt, a, b, c)
+        scan, mesh=mesh,
+        in_specs=(rows4, rows3, P(None), rows4, rows4)
+        + ((rows3, P(None), P(None)) if gate else ()),
+        out_specs=rows3 if gate else rows4,
+        axis_names=kernel_manual_axes(mesh, set(b_spec)),
+        check_vma=False)(*operands, *gate)
 
-def _ssd(x, dt, a, b, c, chunk, dtype):
+
+def _epilogue(y, x, z, skip, weight, eps, groups, dtype):
+    """The Mamba-2 mixer's epilogue in plain XLA: ``y + D_h x`` rounded to
+    ``dtype``, the gate ``* silu(z)``, then an RMS norm over each group's
+    lanes in float32 times the ``[H P]`` weight; ``[b, s, H P]`` in
+    ``dtype``."""
+    b, s, inner = z.shape
+    y = (y + skip.astype(F32)[:, None] * x.astype(F32)).astype(dtype)
+    # The gate first, then the norm, over each group's lanes in f32.
+    gated = (y.reshape(b, s, inner) * jax.nn.silu(z)).astype(F32).reshape(
+        b, s, groups, inner // groups)
+    normed = gated * jax.lax.rsqrt(
+        jnp.mean(jnp.square(gated), axis=-1, keepdims=True) + eps)
+    normed = normed.reshape(b, s, inner) * weight.astype(F32)
+    return normed.astype(dtype)
+
+
+def _ssd(x, dt, a, b, c, chunk, dtype, gate):
+    eps = None
+    if gate is not None:
+        z, skip, weight, eps = gate
+        gate = (z.astype(dtype), skip, weight)
     path = kernel_path(x.shape, b.shape, chunk)
     if path is not None:
         # Decays only shrink (dt >= 0, a < 0): a chunk's most negative
@@ -580,20 +784,25 @@ def _ssd(x, dt, a, b, c, chunk, dtype):
         low = jnp.min(jnp.sum(
             (dt.astype(F32) * a.astype(F32)).reshape(
                 x.shape[0], -1, chunk, x.shape[2]), axis=2))
-        y = _kernel_scan(x.astype(dtype), dt, a, b.astype(dtype),
-                         c.astype(dtype), chunk, path)
+        y = _kernel_scan((x.astype(dtype), dt, a, b.astype(dtype),
+                          c.astype(dtype)), gate or (), eps, chunk, path)
         return y, low
     seq = x.shape[1]
     pad = -seq % chunk
+    padded = x
     if pad:
         # A padded step has dt = 0: it decays nothing and adds nothing.
-        x, dt, b, c = (jnp.pad(v, [(0, 0), (0, pad)] + [(0, 0)] * (v.ndim - 2))
-                       for v in (x, dt, b, c))
-    y, low = _chunked(x, dt, a, b, c, chunk, dtype)
-    return y[:, :seq], low
+        padded, dt, b, c = (
+            jnp.pad(v, [(0, 0), (0, pad)] + [(0, 0)] * (v.ndim - 2))
+            for v in (x, dt, b, c))
+    y, low = _chunked(padded, dt, a, b, c, chunk, dtype)
+    y = y[:, :seq]
+    if gate is not None:
+        y = _epilogue(y, x, *gate, eps, b.shape[2], dtype)
+    return y, low
 
 
-def ssd(x, dt, a, b, c, *, chunk: int = 128, dtype=None):
+def ssd(x, dt, a, b, c, *, chunk: int = 128, dtype=None, gate=None):
     """The state-space scan ``y_t = C_t . S_t``, ``S_t = exp(dt_t a) S_{t-1}
     + dt_t x_t B_t^T`` from a zero state.
 
@@ -602,9 +811,17 @@ def ssd(x, dt, a, b, c, *, chunk: int = 128, dtype=None):
     groups, N]`` (head ``h`` reads group ``h // (heads / groups)``).
     ``dtype``: the matmul operands' type (``x``'s if not given); decays and
     accumulation are float32. Returns ``(y [batch, seq, heads, P] in float32,
-    the most negative in-chunk cumulative dt a of the call)``; the skip term
-    ``D x`` is the caller's.
+    the most negative in-chunk cumulative dt a of the call)``.
+
+    ``gate=(z, D, weight, eps)`` takes in the Mamba-2 mixer's epilogue:
+    ``z [batch, seq, heads * P]``, the skip ``D [heads]``, the norm's weight
+    ``[heads * P]``; the first value returned is then ``RMSNorm_groups((y +
+    D_h x) * silu(z)) * weight``, ``[batch, seq, heads * P]`` in ``dtype``:
+    ``y + D_h x`` rounded to ``dtype``, the gate first, then the norm over
+    each group's ``heads * P / groups`` lanes in float32 (``_epilogue``). The
+    kernels compute it with the chunk's ``y`` still in VMEM, forward and
+    backward; off them it is ``_epilogue`` after ``_chunked``.
     """
     dtype = jnp.dtype(x.dtype if dtype is None else dtype)
     with jax.named_scope("ssd"):
-        return _ssd(x, dt, a, b, c, chunk, dtype)
+        return _ssd(x, dt, a, b, c, chunk, dtype, gate)

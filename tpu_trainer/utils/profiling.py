@@ -7,7 +7,8 @@ and what the device was doing land in the same trace file:
   module names, and ``grad_accum`` / ``grad_finalize`` / ``optimizer`` in
   ``training/trainer.py``, ``head_loss`` in ``models/gpt.py``; a Mamba-2
   mixer is ``mamba`` with ``taps`` round its depthwise convolution and
-  ``ssd`` round the chunked scan, ``ops/ssd.py``; an expert layer is
+  ``ssd`` round the chunked scan with the mixer's skip, gate and grouped
+  norm, ``ops/ssd.py``; an expert layer is
   ``moe_mlp`` with ``route`` / ``experts`` / ``shared_expert``). They are
   trace-time metadata: every compiled instruction carries the path of scopes
   it came from (``op_name``) and nothing about the computation changes.
