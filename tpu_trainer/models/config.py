@@ -35,7 +35,7 @@ CONV_TAPS = 3
 
 # ``GPTConfig.layer_types``: the sequence operators, and (blocks of one
 # sublayer only) the blocks that are an FFN alone.
-_OPERATORS = ("full_attention", "conv", "mamba")
+_OPERATORS = ("full_attention", "conv", "mamba", "linear_attention")
 _FFN_ONLY = ("moe", "mlp")
 
 
@@ -215,6 +215,30 @@ class GPTConfig:
     mamba_dt_min: float = 0.001
     mamba_dt_max: float = 0.1
     mamba_dt_floor: float = 1e-4
+
+    # --- Linear attention beside gated attention (Qwen3-Next's keys) -----
+    # Again what a published architecture states, never a tuning knob; at
+    # their defaults nothing changes.
+    # Gated DeltaNet (models/gpt.py GatedDeltaNet, ops/gated_delta.py) in
+    # every "linear_attention" layer: q and k in linear_num_key_heads heads
+    # of linear_key_head_dim lanes, v and the output gate z in
+    # linear_num_value_heads heads of linear_value_head_dim (a multiple:
+    # value head j reads key head j // (H_v / H_k)), a depthwise causal
+    # convolution of linear_conv_kernel_dim taps (no bias) over [q, k, v].
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    # RoPE over the first partial_rotary_factor x head_dim lanes of each
+    # attention head (pairs (i, i + rotated / 2)); the rest pass through.
+    partial_rotary_factor: float = 1.0
+    # Attention's output times sigmoid(gate), a gate per head and lane from
+    # a second half of each head of q_proj ([hidden, 2 x heads x head_dim]:
+    # a head's query lanes, then its gate lanes).
+    attention_output_gate: bool = False
+    # The shared expert's output times sigmoid(x w), w [hidden, 1].
+    moe_shared_expert_gate: bool = False
 
     # Optimization flags (reference config.py:30-32)
     use_flash_attention: bool = False
@@ -543,6 +567,38 @@ class GPTConfig:
             if self.latent_attention:
                 raise ValueError(
                     "latent attention beside mamba layers is not supported")
+        if "linear_attention" in types:
+            sizes = (self.linear_num_key_heads, self.linear_num_value_heads,
+                     self.linear_key_head_dim, self.linear_value_head_dim,
+                     self.linear_conv_kernel_dim)
+            if not all(isinstance(v, int) and v > 0 for v in sizes) or (
+                    self.linear_num_value_heads % self.linear_num_key_heads):
+                raise ValueError(
+                    f"a 'linear_attention' layer needs linear_num_key_heads, "
+                    f"linear_num_value_heads (a multiple of the key heads), "
+                    f"linear_key_head_dim, linear_value_head_dim and "
+                    f"linear_conv_kernel_dim; got {sizes!r}")
+            if self.latent_attention:
+                raise ValueError("latent attention beside linear_attention "
+                                 "layers is not supported")
+        if self.partial_rotary_factor != 1.0 and (
+                self.rotated_dim % 2
+                or self.rotated_dim != self.head_dim
+                * self.partial_rotary_factor
+                or not 0 < self.rotated_dim < self.head_dim):
+            raise ValueError(
+                f"partial_rotary_factor {self.partial_rotary_factor} of "
+                f"head_dim {self.head_dim} is not an even number of lanes")
+        if (self.partial_rotary_factor != 1.0 or self.attention_output_gate
+                ) and (self.latent_attention or self.layer_types is None):
+            raise ValueError(
+                "partial_rotary_factor < 1 and attention_output_gate are for "
+                "a hybrid stack (layer_types), which trains only: the decode "
+                "and ring paths rotate whole heads and gate nothing")
+        if self.moe_shared_expert_gate and not self.moe_shared_experts:
+            raise ValueError(
+                "moe_shared_expert_gate gates the shared expert "
+                "(moe_shared_experts >= 1)")
         if self.ffn_kind not in ("swiglu", "relu2"):
             raise ValueError(
                 f"unknown ffn_kind {self.ffn_kind!r}; choose swiglu or relu2")
@@ -588,6 +644,21 @@ class GPTConfig:
     @property
     def has_mamba(self) -> bool:
         return "mamba" in (self.layer_types or ())
+
+    @property
+    def has_linear_attention(self) -> bool:
+        return "linear_attention" in (self.layer_types or ())
+
+    @property
+    def rotated_dim(self) -> int:
+        """Lanes of an attention head that RoPE rotates."""
+        return int(round(self.head_dim * self.partial_rotary_factor))
+
+    @property
+    def linear_conv_dim(self) -> int:
+        """Channels a Gated DeltaNet's taps run over: q, k and v."""
+        return (2 * self.linear_num_key_heads * self.linear_key_head_dim
+                + self.linear_num_value_heads * self.linear_value_head_dim)
 
     @property
     def mamba_inner(self) -> int:
@@ -693,8 +764,9 @@ class GPTConfig:
     def _parameter_count(self, experts_counted: int) -> int:
         h, i = self.hidden_size, self.intermediate_size
         d = self.head_dim
-        # q/o full, k/v grouped
-        attn = 2 * h * self.attention_width + 2 * h * self.kv_heads * d
+        # q/o full (q twice as wide with its output gate), k/v grouped
+        attn = ((3 if self.attention_output_gate else 2) * h
+                * self.attention_width + 2 * h * self.kv_heads * d)
         if self.qk_norm:
             attn += 2 * d
         if self.latent_attention:
@@ -713,6 +785,7 @@ class GPTConfig:
                     "mamba": (h * (inner + conv_dim + self.mamba_num_heads)
                               + conv_dim * (self.mamba_conv_kernel + 1)
                               + 3 * self.mamba_num_heads + inner + inner * h),
+                    "linear_attention": self._linear_attention_params(),
                     "none": 0}
         router = h * self.num_experts
         if self.moe_router == "sigmoid":
@@ -721,7 +794,7 @@ class GPTConfig:
         shared = (self.shared_expert_width if self.moe_shared_experts else 0)
         ffn = {"dense": m * h * i,
                "moe": m * h * (experts_counted * self.expert_width + shared)
-               + router,
+               + router + h * self.moe_shared_expert_gate,
                "none": 0}
         # A norm for each sublayer a block holds.
         layers = sum(operator[op] + ffn[f]
@@ -735,6 +808,15 @@ class GPTConfig:
             2 * h * h + operator[last_op] + ffn[last_ffn] + 2 * h + 3 * h)
         return self.vocab_size * h + head + layers + mtp + h
 
+    def _linear_attention_params(self) -> int:
+        """in_proj_qkvz, in_proj_ba, the taps, dt_bias and A_log, the gated
+        norm's [d_v] weight, out_proj."""
+        h, hv = self.hidden_size, self.linear_num_value_heads
+        value = hv * self.linear_value_head_dim
+        return (h * (self.linear_conv_dim + value) + h * 2 * hv
+                + self.linear_conv_dim * self.linear_conv_kernel_dim
+                + 2 * hv + self.linear_value_head_dim + value * h)
+
     def num_parameters(self) -> int:
         """Exact parameter count of the actual model (what lives here: with
         ``moe_experts_held`` the held experts only).
@@ -746,12 +828,16 @@ class GPTConfig:
                    conv 3*H^2 (in) + H*L (taps) + H^2 (out); no bias; or
                    Mamba-2: H*(d_in + conv_dim + heads) (in), conv_dim*(K+1)
                    (taps and their bias), 3*heads (dt_bias, A_log, D), d_in
-                   (the gated norm), d_in*H (out)
+                   (the gated norm), d_in*H (out); or Gated DeltaNet:
+                   H*(conv_dim + H_v d_v) (q, k, v, z), H*2*H_v (b, a),
+                   conv_dim*K (taps), 2*H_v (dt_bias, A_log), d_v (the
+                   gated norm), H_v d_v * H (out); attention's q_proj twice
+                   as wide with its output gate
                    + FFN (m = 3 matrices, SwiGLU, or 2, relu^2): m*H*I
                    (dense) or E_held*m*H*I_e + H*E router
                    (+ E selection bias under the sigmoid router)
                    (+ the shared experts' m*H*I_e each, or m*H*width where
-                   the shared expert's width is stated)
+                   the shared expert's width is stated, + H for its gate)
                    + a RMSNorm weight vector for each sublayer the block
                    holds (2*H; H in a block of one sublayer)
         latent attention: the two latents' down- and up-projections with

@@ -49,6 +49,7 @@ from tpu_trainer.ops.loss import (
     fused_shifted_cross_entropy,
     vocab_sharded_shifted_cross_entropy,
 )
+from tpu_trainer.ops.gated_delta import gated_delta
 from tpu_trainer.ops.ssd import kernel_path as ssd_kernel_path, ssd
 from tpu_trainer.utils import telemetry
 
@@ -146,6 +147,17 @@ def _fused_projection(cfg: GPTConfig, x: jax.Array, specs) -> list:
     return jnp.split(out, bounds, axis=-1)
 
 
+def _partial_rope(q, k, rotated: int, theta: float):
+    """RoPE over the first ``rotated`` lanes of each head (pairs ``(i, i +
+    rotated / 2)``, frequencies ``theta^(-2i / rotated)``); the other lanes
+    pass through."""
+    cos, sin = rope_tables(q.shape[1], rotated, theta)
+    q_rot, k_rot = apply_rotary_pos_emb(
+        q[..., :rotated], k[..., :rotated], cos, sin)
+    return (jnp.concatenate([q_rot, q[..., rotated:]], axis=-1),
+            jnp.concatenate([k_rot, k[..., rotated:]], axis=-1))
+
+
 class CausalSelfAttention(nn.Module):
     """Multi-head causal self-attention (reference ``gpt.py:150-242``).
 
@@ -173,6 +185,10 @@ class CausalSelfAttention(nn.Module):
             kernel_init=nn.initializers.normal(cfg.initializer_range),
         )
         kv_features = cfg.kv_heads * cfg.head_dim
+        # With the output gate, each head of q_proj is its query lanes then
+        # its gate lanes.
+        q_features = cfg.attention_width * (
+            2 if cfg.attention_output_gate else 1)
         if _use_fused_projections(cfg):
             # One [H, H + 2*kv] matmul instead of three: x is read from HBM
             # once and the MXU sees one wide dot; params stay separate
@@ -180,14 +196,19 @@ class CausalSelfAttention(nn.Module):
             # _fused_projection / GPTConfig.fused_projections).
             q, k, v = _fused_projection(
                 cfg, x,
-                [("q_proj", cfg.attention_width), ("k_proj", kv_features),
+                [("q_proj", q_features), ("k_proj", kv_features),
                  ("v_proj", kv_features)],
             )
         else:
-            q = dense(features=cfg.attention_width, name="q_proj")(x)
+            q = dense(features=q_features, name="q_proj")(x)
             k = dense(features=kv_features, name="k_proj")(x)
             v = dense(features=kv_features, name="v_proj")(x)
 
+        gate = None
+        if cfg.attention_output_gate:
+            q, gate = jnp.split(
+                q.reshape(b, s, cfg.num_heads, 2 * cfg.head_dim), 2, axis=-1)
+            gate = gate.reshape(b, s, cfg.attention_width)
         # [b, s, h*d] -> [b, s, heads, head_dim] (BSHD; no BHSD transpose on
         # TPU). Under GQA the k/v head dim is num_kv_heads (< num_heads).
         q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
@@ -200,6 +221,13 @@ class CausalSelfAttention(nn.Module):
                 RMSNorm, eps=cfg.norm_eps, dtype=cfg.compute_dtype)
             q = qk_norm(name="q_layernorm")(q)
             k = qk_norm(name="k_layernorm")(k)
+
+        rotary = cfg.rotary_embedding
+        if rotary and cfg.rotated_dim < cfg.head_dim and not decode:
+            # RoPE over a head's first lanes only (a hybrid stack, which
+            # trains only): rotated here, and the kernel rotates nothing.
+            q, k = _partial_rope(q, k, cfg.rotated_dim, cfg.rope_theta)
+            rotary = False
 
         if decode:
             out = self._decode_attention(q, k, v)
@@ -263,11 +291,11 @@ class CausalSelfAttention(nn.Module):
                     # None: a hybrid stack whose other operators carry the
                     # order (the ring paths above are not reached by one:
                     # GPT._mixed_layers refuses a sequence axis).
-                    rope=(cos, sin) if cfg.rotary_embedding else None,
+                    rope=(cos, sin) if rotary else None,
                     segment_ids=segment_ids,
                 )
             else:
-                if cfg.rotary_embedding:
+                if rotary:
                     q, k = apply_rotary_pos_emb(q, k, cos, sin)
                 out = reference_attention(
                     q, k, v,
@@ -278,6 +306,8 @@ class CausalSelfAttention(nn.Module):
                 )
 
         out = out.reshape(b, s, cfg.attention_width)
+        if gate is not None:
+            out = out * jax.nn.sigmoid(gate)
         out = dense(features=cfg.hidden_size, name="o_proj")(out)
         out = residual_dropout(self, out, cfg.dropout, deterministic)
         return out
@@ -965,6 +995,96 @@ class Mamba2Mixer(nn.Module):
         return residual_dropout(self, out, cfg.dropout, deterministic)
 
 
+def _l2_normalised(x, eps: float = 1e-6):
+    """``x * rsqrt(sum(x^2) + eps)`` over the last axis, in float32."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+class GatedDeltaNet(nn.Module):
+    """The Gated DeltaNet mixer (arXiv:2412.06464; Qwen3-Next's linear-
+    attention layer), the sequence operator of a ``linear_attention`` layer.
+    With ``H_k`` key heads of ``d_k`` lanes and ``H_v`` value heads of
+    ``d_v`` (``r = H_v / H_k``):
+
+    - ``[q, k, v, z] = x W_qkvz``, the columns grouped by key head ``h``:
+      ``[q_h, k_h, v_{rh..rh+r-1}, z_{rh..rh+r-1}]``; ``[b, a] = x W_ba``,
+      grouped alike (``[b_{rh..}, a_{rh..}]``);
+    - ``[q, k, v] <- silu(causal depthwise conv of K taps, no bias)`` over
+      their ``2 H_k d_k + H_v d_v`` channels (scope ``taps``);
+    - ``beta = sigmoid(b)``; the log decay ``g = -exp(A_log) softplus(a +
+      dt_bias)``, per value head and token, in float32;
+    - ``q <- l2norm(q) / sqrt(d_k)``, ``k <- l2norm(k)``; value head ``j``
+      reads key head ``j // r``;
+    - the gated delta rule (``ops/gated_delta.py``, chunked): ``S' = e^g
+      S``, ``S <- S' + k (beta (v - S'^T k))^T``, ``o = S^T q``;
+    - ``o <- RMSNorm(o) w ⊙ silu(z)`` per value head over its ``d_v``
+      lanes (the norm first, then the gate), ``out = o W_out``.
+
+    ``A_log`` and ``dt_bias`` are consumed in float32 (``F32_LEAVES``) and
+    take no weight decay (``UNDECAYED``). The step metrics count the tokens
+    (``gated_delta_tokens``) and the most negative in-chunk cumulative log
+    decay (``gated_delta_min_log_decay``). Training and evaluation only."""
+
+    config: GPTConfig
+    F32_LEAVES = ("A_log", "dt_bias")
+    UNDECAYED = ("A_log", "dt_bias")
+
+    @nn.compact
+    def __call__(self, u: jax.Array, deterministic: bool = True,
+                 segment_ids: Optional[jax.Array] = None) -> jax.Array:
+        cfg = self.config
+        if segment_ids is not None:
+            raise NotImplementedError(
+                "segment_ids are not supported under linear_attention "
+                "layers: the delta rule does not reset its state at a "
+                "packed document's first token")
+        hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+        dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+        per, taps = hv // hk, cfg.linear_conv_kernel_dim
+        b, s, _ = u.shape
+        cd, f32 = cfg.compute_dtype, jnp.float32
+        dense = functools.partial(
+            nn.Dense, use_bias=False, dtype=cd, param_dtype=cfg.params_dtype,
+            kernel_init=nn.initializers.normal(cfg.initializer_range))
+        qkvz = dense(cfg.linear_conv_dim + hv * dv, name="in_proj_qkvz")(
+            u).reshape(b, s, hk, 2 * dk + 2 * per * dv)
+        q, k, v, z = jnp.split(qkvz, [dk, 2 * dk, 2 * dk + per * dv], axis=-1)
+        beta, a = jnp.split(
+            dense(2 * hv, name="in_proj_ba")(u).reshape(b, s, hk, 2 * per),
+            2, axis=-1)
+        bound = taps ** -0.5
+        weight = self.param(
+            "conv_weight", lambda key, shape, dtype: jax.random.uniform(
+                key, shape, dtype, -bound, bound),
+            (cfg.linear_conv_dim, taps), cfg.params_dtype)
+        dt_bias = self.param("dt_bias", nn.initializers.ones, (hv,), f32)
+        a_log = self.param("A_log", lambda key, shape, dtype: jnp.log(
+            jax.random.uniform(key, shape, dtype, 0.0, 16.0)), (hv,), f32)
+        norm_weight = _Weight(dv, name="norm")()
+
+        with jax.named_scope("taps"):
+            mixed = nn.silu(causal_taps(
+                jnp.concatenate([q.reshape(b, s, hk * dk),
+                                 k.reshape(b, s, hk * dk),
+                                 v.reshape(b, s, hv * dv)], axis=-1),
+                weight.astype(cd)))
+        q, k, v = jnp.split(mixed, [hk * dk, 2 * hk * dk], axis=-1)
+        q = _l2_normalised(q.reshape(b, s, hk, dk)) * dk ** -0.5
+        k = _l2_normalised(k.reshape(b, s, hk, dk))
+        beta = jax.nn.sigmoid(beta.reshape(b, s, hv).astype(f32))
+        g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+            a.reshape(b, s, hv).astype(f32) + dt_bias.astype(f32))
+        o, low = gated_delta(q, k, v.reshape(b, s, hv, dv), g, beta, dtype=cd)
+        o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+                              + cfg.norm_eps) * norm_weight
+        o = (o * jax.nn.silu(z.reshape(b, s, hv, dv).astype(f32))).astype(cd)
+        telemetry.count("gated_delta_tokens", jnp.asarray(b * s, f32))
+        telemetry.count("gated_delta_min_log_decay", low, reduce="min")
+        out = dense(cfg.hidden_size, name="out_proj")(o.reshape(b, s, hv * dv))
+        return residual_dropout(self, out, cfg.dropout, deterministic)
+
+
 class TransformerBlock(nn.Module):
     """Pre-norm block with two residuals (reference ``gpt.py:286-316``).
 
@@ -1011,6 +1131,9 @@ class TransformerBlock(nn.Module):
                         h, self.deterministic, segment_ids)
                 elif operator == "mamba":
                     h = Mamba2Mixer(cfg, name="mamba")(
+                        h, self.deterministic, segment_ids)
+                elif operator == "linear_attention":
+                    h = GatedDeltaNet(cfg, name="linear_attn")(
                         h, self.deterministic, segment_ids)
                 else:
                     attention = (LatentAttention if cfg.latent_attention
@@ -1060,24 +1183,34 @@ class TransformerBlock(nn.Module):
 # weight decay (``training/optimizer.decay_mask``): asked here, by the keys
 # of a leaf's path, never listed there by name.
 
+# The modules that declare some of their leaves, by the name they go by in a
+# block.
+_DECLARING = {"mamba": Mamba2Mixer, "linear_attn": GatedDeltaNet}
+
+
+def _declared(path_keys, what: str) -> bool:
+    return any(name in path_keys and path_keys[-1] in getattr(module, what)
+               for name, module in _DECLARING.items())
+
+
 def computed_in_f32(path_keys) -> bool:
     """Whether a leaf is one its module consumes in float32 whatever the
     compute type: the expert layers' router (``models/moe.py``), a Mamba-2
-    mixer's ``A_log``, ``dt_bias`` and ``D``."""
+    mixer's ``A_log``, ``dt_bias`` and ``D``, a Gated DeltaNet's ``A_log``
+    and ``dt_bias``."""
     from tpu_trainer.models import moe
 
-    return moe.computed_in_f32(path_keys) or (
-        "mamba" in path_keys and path_keys[-1] in Mamba2Mixer.F32_LEAVES)
+    return moe.computed_in_f32(path_keys) or _declared(path_keys,
+                                                       "F32_LEAVES")
 
 
 def undecayed(path_keys) -> bool:
     """Whether a leaf takes no weight decay: every norm's weight and every
     bias (the reference's exclusion by name, ``ddp_trainer.py:216-227``:
     modules here name them ``*norm*`` / ``*bias*``), and what a module
-    declares (``Mamba2Mixer.UNDECAYED``)."""
+    declares (``Mamba2Mixer.UNDECAYED``, ``GatedDeltaNet.UNDECAYED``)."""
     return any("norm" in k.lower() or "bias" in k.lower()
-               for k in path_keys) or (
-        "mamba" in path_keys and path_keys[-1] in Mamba2Mixer.UNDECAYED)
+               for k in path_keys) or _declared(path_keys, "UNDECAYED")
 
 
 def stack_name(kind: Tuple[str, str]) -> str:
@@ -1447,6 +1580,13 @@ class GPT(nn.Module):
                 "in_proj's [z, xBC, dt] columns, the taps and the heads' "
                 "A_log / dt_bias / D have no tensor-parallel rule "
                 "(parallel/sharding.py replicates them)")
+        if cfg.has_linear_attention and mesh is not None and (
+                mesh.shape.get("tensor", 1) > 1):
+            raise NotImplementedError(
+                "linear_attention layers do not run under a 'tensor' mesh "
+                "axis > 1: in_proj_qkvz's columns grouped by key head, the "
+                "taps and the heads' A_log / dt_bias have no tensor-parallel "
+                "rule (parallel/sharding.py replicates them)")
         for axis in ("stage", ring.SEQ_AXIS):
             if mesh is not None and mesh.shape.get(axis, 1) > 1:
                 raise NotImplementedError(
@@ -1532,10 +1672,19 @@ _NO_STATE_SPACE_GENERATION = (
     "positions beside keys and values (ROADMAP M8: other state than K/V)")
 
 
+_NO_DELTA_RULE_GENERATION = (
+    "generation is not supported for a model with linear_attention (Gated "
+    "DeltaNet) layers: no cache here holds a delta rule's [d_k, d_v] state a "
+    "value head and the taps' last positions beside keys and values (ROADMAP "
+    "M8: other state than K/V)")
+
+
 def _refuse_state_space(config: GPTConfig) -> None:
     """``generate*`` and the caches, for a model that no cache can serve."""
     if config.has_mamba:
         raise NotImplementedError(_NO_STATE_SPACE_GENERATION)
+    if config.has_linear_attention:
+        raise NotImplementedError(_NO_DELTA_RULE_GENERATION)
 
 
 def _masked_shifted_mean(ce: jax.Array, segment_ids) -> jax.Array:

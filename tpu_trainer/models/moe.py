@@ -522,7 +522,8 @@ class SharedExpert(nn.Module):
     """The shared experts beside the routed ones: one FFN of the experts'
     kind (``GPTConfig.ffn_kind``) of width ``shared_expert_width``
     (``moe_shared_experts x expert_width`` unless stated) over every token,
-    no routing."""
+    no routing; with ``moe_shared_expert_gate`` its output times
+    ``sigmoid(x w)``, ``w [hidden, 1]`` (``gate``)."""
 
     config: GPTConfig
 
@@ -540,7 +541,10 @@ class SharedExpert(nn.Module):
             act = {"silu": nn.silu, "gelu": nn.gelu}[cfg.activation]
             mid = act(dense(width, name="gate_proj")(x)) * dense(
                 width, name="up_proj")(x)
-        return dense(cfg.hidden_size, name="down_proj")(mid)
+        out = dense(cfg.hidden_size, name="down_proj")(mid)
+        if cfg.moe_shared_expert_gate:
+            out = out * jax.nn.sigmoid(dense(1, name="gate")(x))
+        return out
 
 
 class MoEMLP(nn.Module):

@@ -295,6 +295,14 @@ class Trainer:
                 "axes: in_proj's [z, xBC, dt] columns, the taps and the "
                 "heads' A_log / dt_bias / D have no tensor-parallel rule "
                 f"(got 'tensor' = {self.mesh.shape[mesh_lib.TENSOR_AXIS]})")
+        if self.model_config.has_linear_attention and (
+                self.mesh.shape.get(mesh_lib.TENSOR_AXIS, 1) > 1):
+            raise ValueError(
+                "linear_attention layers train under 'data', 'fsdp' and "
+                "'expert' mesh axes: in_proj_qkvz's columns grouped by key "
+                "head, the taps and the heads' A_log / dt_bias have no "
+                "tensor-parallel rule (got 'tensor' = "
+                f"{self.mesh.shape[mesh_lib.TENSOR_AXIS]})")
         if self.model_config.latent_attention:
             for axis in (mesh_lib.TENSOR_AXIS, mesh_lib.SEQUENCE_AXIS,
                          mesh_lib.STAGE_AXIS, mesh_lib.EXPERT_AXIS):
