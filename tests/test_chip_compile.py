@@ -222,15 +222,32 @@ def test_mamba_mixer_value_and_grad_through_its_checkpoint(chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.6e9
 
 
-def test_gated_delta_net_value_and_grad_at_the_cells_widths(chip):
+def _rule_as_on_a_tpu(monkeypatch):
+    """`ops/gated_delta.py` dispatches on `jax.devices()`, which is the CPU
+    here: steer the shapes its kernels take to them, compiled, as a TPU
+    would."""
+    from tpu_trainer.ops import gated_delta as gd_ops
+
+    def path(*shapes):
+        return (False, None, None) if gd_ops.fits(*shapes) else None
+
+    monkeypatch.setattr(gd_ops, "kernel_path", path)
+    monkeypatch.setattr("tpu_trainer.models.gpt.gated_delta_kernel_path",
+                        path)
+
+
+def test_gated_delta_net_value_and_grad_at_the_cells_widths(chip, monkeypatch):
     """One `GatedDeltaNet` at the Qwen3-Next cell's widths and micro-batch
     (2 x 4,096 tokens of 2,048 lanes, 16 key / 32 value heads of 128, bf16):
-    value and gradient compiled for the chip. The delta rule is plain XLA
-    (no kernel); its `[C, C]` chunk matrices are what its memory holds, and
-    the triangle's own gradient keeps `T` alone of the inverse's series."""
+    value and gradient compiled for the chip, the delta rule through its
+    Pallas kernels as a TPU takes it. Mosaic must accept the forward that
+    keeps each chunk's starting states and `T` and the backward that reads
+    them; the triangle's own gradient keeps `T` alone of the inverse's
+    series, and no `[C, C]` chunk matrix is left in HBM."""
     from perf import registry
     from tpu_trainer.models.gpt import GatedDeltaNet
 
+    _rule_as_on_a_tpu(monkeypatch)
     cfg_file = registry.config("qwen3-next-80b-a3b-ep32")
     cfg = registry.family(cfg_file).gpt_config(cfg_file, dtype="bfloat16")
     mixer = GatedDeltaNet(cfg)
@@ -246,7 +263,9 @@ def test_gated_delta_net_value_and_grad_at_the_cells_widths(chip):
 
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
         params, u).compile()
-    assert "gated_delta" in compiled.as_text()
+    text = compiled.as_text()
+    assert "gated_delta" in text
+    assert text.count("tpu_custom_call") >= 2
     # 3.51e9 B when written: a larger plan is a regression of the rule's
     # memory (the step fits 2 x 4 on the chip by some 10%).
     assert compiled.memory_analysis().temp_size_in_bytes < 4.0e9

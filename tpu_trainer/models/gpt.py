@@ -49,7 +49,8 @@ from tpu_trainer.ops.loss import (
     fused_shifted_cross_entropy,
     vocab_sharded_shifted_cross_entropy,
 )
-from tpu_trainer.ops.gated_delta import gated_delta
+from tpu_trainer.ops.gated_delta import (
+    gated_delta, kernel_path as gated_delta_kernel_path)
 from tpu_trainer.ops.ssd import kernel_path as ssd_kernel_path, ssd
 from tpu_trainer.utils import telemetry
 
@@ -1016,15 +1017,19 @@ class GatedDeltaNet(nn.Module):
       dt_bias)``, per value head and token, in float32;
     - ``q <- l2norm(q) / sqrt(d_k)``, ``k <- l2norm(k)``; value head ``j``
       reads key head ``j // r``;
-    - the gated delta rule (``ops/gated_delta.py``, chunked): ``S' = e^g
-      S``, ``S <- S' + k (beta (v - S'^T k))^T``, ``o = S^T q``;
+    - the gated delta rule (``ops/gated_delta.py``, chunked; on a TPU the
+      Pallas kernels, whose chunk matrices and carried states never leave
+      VMEM): ``S' = e^g S``, ``S <- S' + k (beta (v - S'^T k))^T``, ``o =
+      S^T q``;
     - ``o <- RMSNorm(o) w ⊙ silu(z)`` per value head over its ``d_v``
       lanes (the norm first, then the gate), ``out = o W_out``.
 
     ``A_log`` and ``dt_bias`` are consumed in float32 (``F32_LEAVES``) and
     take no weight decay (``UNDECAYED``). The step metrics count the tokens
-    (``gated_delta_tokens``) and the most negative in-chunk cumulative log
-    decay (``gated_delta_min_log_decay``). Training and evaluation only."""
+    (``gated_delta_tokens``), those of them whose rule took the kernels
+    (``gated_delta_kernel_tokens``) and the most negative in-chunk
+    cumulative log decay (``gated_delta_min_log_decay``). Training and
+    evaluation only."""
 
     config: GPTConfig
     F32_LEAVES = ("A_log", "dt_bias")
@@ -1080,6 +1085,10 @@ class GatedDeltaNet(nn.Module):
                               + cfg.norm_eps) * norm_weight
         o = (o * jax.nn.silu(z.reshape(b, s, hv, dv).astype(f32))).astype(cd)
         telemetry.count("gated_delta_tokens", jnp.asarray(b * s, f32))
+        # The tokens whose rule took the kernels (``ops/gated_delta.py``).
+        kernels = gated_delta_kernel_path(q.shape, (b, s, hv, dv)) is not None
+        telemetry.count("gated_delta_kernel_tokens",
+                        jnp.asarray(b * s if kernels else 0, f32))
         telemetry.count("gated_delta_min_log_decay", low, reduce="min")
         out = dense(cfg.hidden_size, name="out_proj")(o.reshape(b, s, hv * dv))
         return residual_dropout(self, out, cfg.dropout, deterministic)
