@@ -110,6 +110,25 @@ def test_flash_attention_rope_dropout_fused(chip, s):
     _compile(jax.grad(loss, argnums=(0, 1, 2)), x, x, x, tab, tab, key)
 
 
+@pytest.mark.parametrize("window", [1024, None])
+def test_banded_flash_attention_at_the_window_cells_widths(chip, window):
+    # The Mellum cell's micro-batch: 32 heads of 128 over 4 K/V heads at
+    # 8,192 tokens, RoPE in XLA; its window layers (a band of 1,024) and its
+    # full layer (no window: past 4,096 tokens the banded kernels carry the
+    # causal prefix, the streaming forward's VMEM growing with s).
+    b, s, h, kvh, d = 2, 8192, 32, 4, 128
+    q = chip((b, s, h, d), jnp.bfloat16)
+    kv = chip((b, s, kvh, d), jnp.bfloat16)
+    tab = chip((s, d), jnp.float32)
+
+    def loss(q, k, v, cos, sin):
+        return flash_attention(q, k, v, rope=(cos, sin), window=window
+                               ).astype(jnp.float32).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, tab, tab)
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
 def _scan_as_on_a_tpu(monkeypatch):
     """`ops/ssd.py` dispatches on `jax.devices()`, which is the CPU here:
     steer the shapes its kernels take to them, compiled, as a TPU would."""

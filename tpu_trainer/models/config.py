@@ -35,7 +35,8 @@ CONV_TAPS = 3
 
 # ``GPTConfig.layer_types``: the sequence operators, and (blocks of one
 # sublayer only) the blocks that are an FFN alone.
-_OPERATORS = ("full_attention", "conv", "mamba", "linear_attention")
+_OPERATORS = ("full_attention", "conv", "mamba", "linear_attention",
+              "sliding_attention")
 _FFN_ONLY = ("moe", "mlp")
 
 
@@ -239,6 +240,22 @@ class GPTConfig:
     attention_output_gate: bool = False
     # The shared expert's output times sigmoid(x w), w [hidden, 1].
     moe_shared_expert_gate: bool = False
+
+    # --- Window layers beside full ones (Mellum 2's keys) ----------------
+    # A "sliding_attention" layer is attention whose position i sees the
+    # keys i - sliding_window < j <= i (transformers' convention), at RoPE
+    # of rope_theta. The "full_attention" layers of such a stack may take
+    # YaRN (arXiv:2309.00071) where yarn_factor > 0: frequency i of d/2 is
+    # ramp_i / factor + (1 - ramp_i) of theta^(-2i/d), the ramp rising from
+    # the floor to the ceiling of the dimensions whose wavelength turns
+    # beta_fast and beta_slow times over yarn_original_max_position, and
+    # cos and sin are both multiplied by yarn_attention_factor.
+    sliding_window: int = 0
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.0
 
     # Optimization flags (reference config.py:30-32)
     use_flash_attention: bool = False
@@ -599,6 +616,23 @@ class GPTConfig:
             raise ValueError(
                 "moe_shared_expert_gate gates the shared expert "
                 "(moe_shared_experts >= 1)")
+        if ("sliding_attention" in types) != (self.sliding_window > 0):
+            raise ValueError(
+                f"sliding_window ({self.sliding_window}) is the window of "
+                f"the 'sliding_attention' layers, and they need one")
+        if "sliding_attention" in types and self.latent_attention:
+            raise ValueError(
+                "latent attention in sliding_attention layers is not "
+                "supported")
+        if self.yarn_factor and not (
+                self.yarn_factor > 1 and self.yarn_original_max_position > 0
+                and self.layer_types is not None
+                and not self.latent_attention):
+            raise ValueError(
+                "yarn_factor > 1 with yarn_original_max_position scales the "
+                "full-attention layers' RoPE in a stack whose layers differ "
+                "(layer_types), which trains only: the decode and ring "
+                "paths take the plain tables")
         if self.ffn_kind not in ("swiglu", "relu2"):
             raise ValueError(
                 f"unknown ffn_kind {self.ffn_kind!r}; choose swiglu or relu2")
@@ -648,6 +682,10 @@ class GPTConfig:
     @property
     def has_linear_attention(self) -> bool:
         return "linear_attention" in (self.layer_types or ())
+
+    @property
+    def has_sliding_window(self) -> bool:
+        return "sliding_attention" in (self.layer_types or ())
 
     @property
     def rotated_dim(self) -> int:
@@ -778,7 +816,7 @@ class GPTConfig:
                         self.qk_nope_head_dim + self.v_head_dim)
                     + heads * self.v_head_dim * h)
         inner, conv_dim = self.mamba_inner, self.mamba_conv_dim
-        operator = {"attention": attn,
+        operator = {"attention": attn, "sliding_attention": attn,
                     "conv": 3 * h * h + h * CONV_TAPS + h * h,
                     # in_proj [z, xBC, dt]; taps and their bias; dt_bias,
                     # A_log, D; the gated norm; out_proj

@@ -42,9 +42,10 @@ import numpy as np
 from tpu_trainer.models.config import CONV_TAPS, GPTConfig
 from tpu_trainer.ops import ring
 from tpu_trainer.ops.attention import (
-    flash_attention, mla_attention, reference_attention,
+    flash_attention, mla_attention, reference_attention, window_kernel_block,
 )
 from tpu_trainer.ops.dropout import residual_dropout
+from tpu_trainer.ops.flash import band_pairs
 from tpu_trainer.ops.loss import (
     fused_shifted_cross_entropy,
     vocab_sharded_shifted_cross_entropy,
@@ -78,6 +79,7 @@ from tpu_trainer.ops.rope import (  # noqa: E402,F401
     apply_rotary_pos_emb,
     rope_tables,
     rotate_half,
+    yarn_tables,
 )
 
 
@@ -148,6 +150,17 @@ def _fused_projection(cfg: GPTConfig, x: jax.Array, specs) -> list:
     return jnp.split(out, bounds, axis=-1)
 
 
+def _layer_rope_tables(cfg: GPTConfig, s: int, window: int):
+    """The RoPE tables of a layer: a window layer's at ``rope_theta``; a
+    full layer's at YaRN's frequencies and scale where the configuration
+    states YaRN (``yarn_factor``)."""
+    if window or not cfg.yarn_factor:
+        return rope_tables(s, cfg.head_dim, cfg.rope_theta)
+    return yarn_tables(s, cfg.head_dim, cfg.rope_theta, cfg.yarn_factor,
+                       cfg.yarn_original_max_position, cfg.yarn_beta_fast,
+                       cfg.yarn_beta_slow, cfg.yarn_attention_factor)
+
+
 def _partial_rope(q, k, rotated: int, theta: float):
     """RoPE over the first ``rotated`` lanes of each head (pairs ``(i, i +
     rotated / 2)``, frequencies ``theta^(-2i / rotated)``); the other lanes
@@ -170,6 +183,9 @@ class CausalSelfAttention(nn.Module):
     """
 
     config: GPTConfig
+    # A window layer's window (GPTConfig.sliding_window), 0 for full
+    # attention: position i then sees the keys i - window < j <= i.
+    window: int = 0
 
     @nn.compact
     def __call__(
@@ -178,6 +194,11 @@ class CausalSelfAttention(nn.Module):
     ) -> jax.Array:
         cfg = self.config
         b, s, _ = x.shape
+        if cfg.has_sliding_window and segment_ids is not None:
+            raise NotImplementedError(
+                "segment_ids are not supported in a model with "
+                "sliding_attention layers: the banded kernels mask by "
+                "position alone")
         dense = functools.partial(
             nn.Dense,
             use_bias=False,
@@ -233,7 +254,7 @@ class CausalSelfAttention(nn.Module):
         if decode:
             out = self._decode_attention(q, k, v)
         else:
-            cos, sin = rope_tables(s, cfg.head_dim, cfg.rope_theta)
+            cos, sin = _layer_rope_tables(cfg, s, self.window)
             needs_rng = cfg.attention_dropout > 0.0 and not deterministic
             dropout_rng = self.make_rng("dropout") if needs_rng else None
             manual_ctx = ring.current_manual_context()
@@ -281,6 +302,13 @@ class CausalSelfAttention(nn.Module):
                     dropout_rate=cfg.attention_dropout if needs_rng else 0.0,
                     dropout_rng=dropout_rng,
                 )
+            elif self.window:
+                # A window layer: the banded kernels (ops/flash.py) under a
+                # scope of their own inside `attention`, RoPE with them.
+                with jax.named_scope("sliding_window"):
+                    out = self._window_attention(
+                        q, k, v, (cos, sin) if rotary else None,
+                        deterministic, dropout_rng)
             elif cfg.use_flash_attention:
                 # RoPE rides into the kernel (rotation happens in VMEM on
                 # TPU; external otherwise — ops/attention.py decides).
@@ -311,6 +339,34 @@ class CausalSelfAttention(nn.Module):
             out = out * jax.nn.sigmoid(gate)
         out = dense(features=cfg.hidden_size, name="o_proj")(out)
         out = residual_dropout(self, out, cfg.dropout, deterministic)
+        return out
+
+    def _window_attention(self, q, k, v, rope, deterministic, dropout_rng):
+        """Attention of a window layer, and its counters: the tokens, those
+        whose attention took the banded kernels, and the key blocks the
+        kernels' band visits (a block pair a head and sequence)."""
+        cfg = self.config
+        b, s, h, d = q.shape
+        block = (window_kernel_block(q.shape) if cfg.use_flash_attention
+                 else None)
+        if cfg.use_flash_attention:
+            out = flash_attention(
+                q, k, v, dropout_rate=cfg.attention_dropout,
+                deterministic=deterministic, dropout_rng=dropout_rng,
+                rope=rope, window=self.window)
+        else:
+            if rope is not None:
+                q, k = apply_rotary_pos_emb(q, k, *rope)
+            out = reference_attention(
+                q, k, v, dropout_rate=cfg.attention_dropout,
+                deterministic=deterministic, dropout_rng=dropout_rng,
+                window=self.window)
+        f32 = jnp.float32
+        telemetry.count("window_attn_tokens", jnp.asarray(b * s, f32))
+        telemetry.count("window_kernel_tokens",
+                        jnp.asarray(b * s if block else 0, f32))
+        telemetry.count("window_key_blocks", jnp.asarray(
+            b * h * band_pairs(s, self.window, block) if block else 0, f32))
         return out
 
     def _decode_attention(self, q, k, v) -> jax.Array:
@@ -1144,6 +1200,13 @@ class TransformerBlock(nn.Module):
                 elif operator == "linear_attention":
                     h = GatedDeltaNet(cfg, name="linear_attn")(
                         h, self.deterministic, segment_ids)
+                elif operator == "sliding_attention":
+                    # Named `attention` too, so that the `flash` and
+                    # `attn_proj` regions hold window layers as they hold
+                    # full ones.
+                    h = CausalSelfAttention(
+                        cfg, window=cfg.sliding_window, name="attention")(
+                            h, self.deterministic, self.decode, segment_ids)
                 else:
                     attention = (LatentAttention if cfg.latent_attention
                                  else CausalSelfAttention)
@@ -1688,12 +1751,20 @@ _NO_DELTA_RULE_GENERATION = (
     "M8: other state than K/V)")
 
 
+_NO_WINDOW_GENERATION = (
+    "generation is not supported for a model with sliding_attention layers: "
+    "no cache here releases the blocks behind a layer's window or holds "
+    "window and full layers side by side (ROADMAP M5: its serving half)")
+
+
 def _refuse_state_space(config: GPTConfig) -> None:
     """``generate*`` and the caches, for a model that no cache can serve."""
     if config.has_mamba:
         raise NotImplementedError(_NO_STATE_SPACE_GENERATION)
     if config.has_linear_attention:
         raise NotImplementedError(_NO_DELTA_RULE_GENERATION)
+    if config.has_sliding_window:
+        raise NotImplementedError(_NO_WINDOW_GENERATION)
 
 
 def _masked_shifted_mean(ce: jax.Array, segment_ids) -> jax.Array:

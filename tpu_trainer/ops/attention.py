@@ -191,6 +191,7 @@ def reference_attention(
     deterministic: bool = True,
     dropout_rng: Optional[jax.Array] = None,
     segment_ids: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Manual causal attention (reference ``gpt.py:230-234``).
 
@@ -199,12 +200,16 @@ def reference_attention(
     K/V (``num_kv_heads < num_heads``) by head repetition — the GQA oracle.
     ``segment_ids`` ([batch, seq] int) additionally restricts attention to
     same-segment pairs — the dense oracle for the packed flash kernels.
+    ``window`` keeps the keys ``i - window < j <= i`` of position ``i``.
     """
     _, s, h, d = q.shape
     k, v = repeat_kv(k, v, h)
     scale = 1.0 / math.sqrt(d)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    mask = causal_mask(s)[None, None, :, :]
+    mask = causal_mask(s)
+    if window is not None:
+        mask = mask & ~jnp.tril(mask, -window)
+    mask = mask[None, None, :, :]
     if segment_ids is not None:
         mask = mask & segment_mask(segment_ids)
     scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
@@ -225,6 +230,7 @@ def flash_attention(
     dropout_rng: Optional[jax.Array] = None,
     rope: Optional[tuple] = None,
     segment_ids: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Fused causal attention (reference flash path, ``gpt.py:199-206``).
 
@@ -234,7 +240,9 @@ def flash_attention(
     Off-TPU, applies rope externally and uses XLA's fused attention, with
     the manual path covering the dropout case (same semantics as the
     reference's manual branch). ``segment_ids`` ([batch, seq] int)
-    isolates attention within packed documents on every path.
+    isolates attention within packed documents on every path. ``window``
+    keeps the keys ``i - window < j <= i`` of position ``i`` (the banded
+    kernels of ``ops/flash.py`` on TPU; the masked reference otherwise).
     """
     active_dropout = dropout_rate > 0.0 and not deterministic
     interpret = os.environ.get(_INTERPRET_ENV, "0") == "1"
@@ -250,6 +258,8 @@ def flash_attention(
             interpret=interpret,
             segment_ids=segment_ids,
         )
+        if window is not None:
+            kernel_kwargs["window"] = window
         mesh = _flash_mesh(q)
         if mesh is not None:
             return _sharded_kernel(q, k, v, mesh, kernel_kwargs)
@@ -258,17 +268,30 @@ def flash_attention(
         from tpu_trainer.ops.rope import apply_rotary_pos_emb
 
         q, k = apply_rotary_pos_emb(q, k, rope[0], rope[1])
-    if active_dropout or segment_ids is not None:
+    if active_dropout or segment_ids is not None or window is not None:
         return reference_attention(
             q, k, v,
             dropout_rate=dropout_rate if active_dropout else 0.0,
             deterministic=deterministic and not active_dropout,
             dropout_rng=dropout_rng,
             segment_ids=segment_ids,
+            window=window,
         )
     # jax.nn.dot_product_attention handles grouped K/V natively (K heads
     # dividing N) — pass the compact tensors straight through.
     return jax.nn.dot_product_attention(q, k, v, is_causal=True)
+
+
+def window_kernel_block(q_shape) -> Optional[int]:
+    """The block at which a window layer's attention of ``q_shape`` ([b, s,
+    heads, head_dim]) runs the banded kernels of ``ops/flash.py`` (on TPU,
+    or under the interpret hook), or None where it runs in XLA."""
+    interpret = os.environ.get(_INTERPRET_ENV, "0") == "1"
+    if not (interpret or any(d.platform == "tpu" for d in jax.devices())):
+        return None
+    from tpu_trainer.ops.flash import band_block
+
+    return band_block(q_shape[1], q_shape[3], interpret)
 
 
 def _mla_reference(q_nope, q_rope, k_nope, k_rope, v, scale):

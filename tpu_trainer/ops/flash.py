@@ -1568,8 +1568,15 @@ def flash_attention(
     return_lse: bool = False,
     backward: Optional[str] = None,
     segment_ids: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Blockwise causal flash attention; BSHD in, BSHD out.
+
+    ``window`` (positions) limits position ``i`` to the keys ``i - window <
+    j <= i``. A window, or a sequence longer than ``STREAMING_MAX_SEQ``,
+    takes the banded kernels (``band_attention``) where they fit: causal, no
+    dropout, no segments, no exposed lse, the default backward. Past that a
+    window runs in XLA through the masked reference.
 
     ``segment_ids`` ([batch, seq] int) isolates attention within packed
     documents: position i attends position j only when
@@ -1621,6 +1628,25 @@ def flash_attention(
         raise ValueError(
             f"num_heads {h} not divisible by num_kv_heads {k.shape[2]}"
         )
+    if window is not None or s > STREAMING_MAX_SEQ:
+        plain = (causal and dropout_rate == 0.0 and not segmented
+                 and not return_lse and backward is None)
+        block = block_q or band_block(s, d, interpret)
+        if plain and block is not None and s % block == 0:
+            return band_attention(q, k, v, window=window or s, rope=rope,
+                                  block=block, interpret=interpret)
+        if window is not None:
+            if not plain:
+                raise NotImplementedError(
+                    "a sliding window is causal attention with no dropout, "
+                    "no segment ids, no exposed lse and the default backward")
+            from tpu_trainer.ops.attention import reference_attention
+
+            if rope is not None:
+                from tpu_trainer.ops.rope import apply_rotary_pos_emb
+
+                q, k = apply_rotary_pos_emb(q, k, rope[0], rope[1])
+            return reference_attention(q, k, v, window=window)
     if return_lse and (s % 128 != 0 or s < 128
                        or not (interpret or d == 64 or d % 128 == 0)):
         # The lse variant exists for blockwise composition (ring attention);
@@ -1747,6 +1773,354 @@ def flash_attention(
         o3, lse = out
         return o3.reshape(b, s, h_k, d)[:, :, :h], lse[:, :h]
     return out.reshape(b, s, h_k, d)[:, :, :h]
+
+
+# --------------------------------------------------------------------------
+# banded causal attention: a sliding window, or the whole prefix past 4,096
+# --------------------------------------------------------------------------
+#
+# Position ``i`` attends ``j`` for ``i - window < j <= i``; a window of the
+# sequence's length is plain causal attention. Every operand reaches the
+# kernels as ``[block, d]`` tiles through the grid, so nothing resident
+# grows with the sequence: the forward and the ``dq`` kernel walk, for each
+# query block, the ``band`` key blocks that can meet it (the innermost grid
+# axis); the ``dk``/``dv`` kernel walks, for each key block, the query
+# blocks of its band for every query head of its K/V head, so that a K/V
+# head's gradient is summed over its group in VMEM and written once. A step
+# whose block lies outside the band (before the sequence's first block, or
+# past its last) computes nothing, and its index map is clamped to the
+# block the neighbouring step reads, so that it moves no data either. Only
+# block pairs that cross an edge of the band pay the element mask. GQA is
+# an index map. RoPE and the 1/sqrt(d) scale are applied in XLA before the
+# kernels (``band_attention``): no table sits in VMEM.
+
+BAND_BLOCK = 512
+# The longest sequence the kernels above take without a window: past it
+# their forward's whole-sequence K/V blocks and ``[s, d]`` RoPE tables
+# overflow the 16 MB scope, and the banded kernels carry causal attention.
+STREAMING_MAX_SEQ = 4096
+_BAND_COMPILED_BLOCKS = (512, 256, 128)
+
+
+def band_blocks(window: int, block: int, blocks: int) -> int:
+    """Key blocks one query block of ``block`` rows can meet (and query
+    blocks one key block can), of the sequence's ``blocks``."""
+    return min(blocks, -(-(window - 1) // block) + 1)
+
+
+def band_block(s: int, d: int, interpret: bool) -> Optional[int]:
+    """The block the banded kernels run a sequence of ``s`` at, or None
+    where they cannot (compiled: whole 128-lane heads and a block of 128,
+    256 or 512 rows that divides the sequence)."""
+    if interpret:
+        return next((b for b in (BAND_BLOCK, 256, 128, 64, 32, 16, 8)
+                     if b <= s and s % b == 0), None)
+    if d % _LANES:
+        return None
+    return next((b for b in _BAND_COMPILED_BLOCKS if b <= s and s % b == 0),
+                None)
+
+
+def band_pairs(s: int, window: int, block: int) -> int:
+    """(query block, key block) pairs the kernels compute for one head of
+    one sequence: those with some (row, key) pair inside the band."""
+    blocks = s // block
+    return sum(1 for iq in range(blocks) for ik in range(iq + 1)
+               if (ik + 1) * block - 1 > iq * block - window)
+
+
+def _band_predicates(q_start, k_start, block, window):
+    """``(needed, full)`` of one block pair: some (row, key) pair lies in
+    the band; every pair does."""
+    needed = (k_start <= q_start + block - 1) & (
+        k_start + block - 1 > q_start - window)
+    full = (k_start + block - 1 <= q_start) & (
+        k_start > q_start + block - 1 - window)
+    return needed, full
+
+
+def _band_mask(s, q_start, k_start, window):
+    """Scores outside the band set to a large finite negative: a row whose
+    first computed block holds none of its keys then leaves finite garbage
+    that its first valid block wipes (``_SEG_MASK``'s reasoning)."""
+    diff = (q_start - k_start
+            + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+    return jnp.where((diff >= 0) & (diff < window), s, _SEG_MASK)
+
+
+def _band_when(needed, full, body):
+    pl.when(full)(functools.partial(body, False))
+    pl.when(needed & jnp.logical_not(full))(functools.partial(body, True))
+
+
+_LANES_DOT = (((1,), (1,)), ((), ()))
+_ROWS_DOT = (((0,), (0,)), ((), ()))
+
+
+def _band_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
+                     acc_scr, *, window, band):
+    """Grid ``(b, h, s // block, band)``: one query block of one head, its
+    band's key blocks along the last axis, an online softmax in VMEM
+    scratch (``m`` and ``l`` replicated over 128 lanes, as the streaming
+    forward keeps them). ``q`` arrives rotated and scaled."""
+    block, width = q_ref.shape[1], q_ref.shape[2]
+    iq, j = pl.program_id(2), pl.program_id(3)
+    q_start = iq * block
+    k_start = (iq - band + 1 + j) * block
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    def body(masked):
+        v = v_ref[0]
+        s = jax.lax.dot_general(q_ref[0], k_ref[0], _LANES_DOT,
+                                preferred_element_type=jnp.float32)
+        if masked:
+            s = _band_mask(s, q_start, k_start, window)
+        m = m_scr[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - _lane_tile(m_new, block))
+        alpha = jnp.exp(m - m_new)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_scr[...] = m_new
+        acc_scr[...] = acc_scr[...] * _lane_tile(alpha, width) + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+    needed, full = _band_predicates(q_start, k_start, block, window)
+    inside = k_start >= 0
+    _band_when(needed & inside, full & inside, body)
+
+    @pl.when(j == band - 1)
+    def _flush():
+        l = l_scr[...]
+        o_ref[0] = (acc_scr[...] / _lane_tile(l, width)).astype(o_ref.dtype)
+        lse_ref[0, 0, 0, :] = m_scr[...][:, 0] + jnp.log(l[:, 0])
+
+
+def _band_scores(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q_start,
+                 k_start, window, masked):
+    """One block pair's probabilities ``p`` and score gradient ``ds``, f32
+    ``[bq, bk]``, from the saved log-sum-exp and ``delta = rowsum(dO O)``."""
+    s = jax.lax.dot_general(q_ref[0], k_ref[0], _LANES_DOT,
+                            preferred_element_type=jnp.float32)
+    if masked:
+        s = _band_mask(s, q_start, k_start, window)
+    p = jnp.exp(s - lse_ref[0, 0, 0, :][:, None])
+    dp = jax.lax.dot_general(do_ref[0], v_ref[0], _LANES_DOT,
+                             preferred_element_type=jnp.float32)
+    return p, p * (dp - delta_ref[0, 0, 0, :][:, None])
+
+
+def _band_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                    dq_scr, *, window, band):
+    """``dq`` of one query block of one head: the forward's grid and walk."""
+    block = q_ref.shape[1]
+    iq, j = pl.program_id(2), pl.program_id(3)
+    q_start = iq * block
+    k_start = (iq - band + 1 + j) * block
+
+    @pl.when(j == 0)
+    def _init():
+        dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
+
+    def body(masked):
+        _, ds = _band_scores(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                             q_start, k_start, window, masked)
+        dq_scr[...] += jnp.dot(ds.astype(k_ref.dtype), k_ref[0],
+                               preferred_element_type=jnp.float32)
+
+    needed, full = _band_predicates(q_start, k_start, block, window)
+    inside = k_start >= 0
+    _band_when(needed & inside, full & inside, body)
+
+    @pl.when(j == band - 1)
+    def _flush():
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+
+
+def _band_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+                     dv_ref, dk_scr, dv_scr, *, window, band, blocks, group):
+    """``dk``, ``dv`` of one key block of one K/V head. Grid ``(b, kv heads,
+    s // block, group * band)``: the last axis walks the band's query
+    blocks of each query head of the group in turn, so both gradients sum
+    over the group in f32 scratch and leave once."""
+    block = k_ref.shape[1]
+    ik, t = pl.program_id(2), pl.program_id(3)
+    iq = ik + t % band
+    q_start, k_start = iq * block, ik * block
+
+    @pl.when(t == 0)
+    def _init():
+        dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
+        dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
+
+    def body(masked):
+        p, ds = _band_scores(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                             q_start, k_start, window, masked)
+        dv_scr[...] += jax.lax.dot_general(
+            p.astype(do_ref.dtype), do_ref[0], _ROWS_DOT,
+            preferred_element_type=jnp.float32)
+        dk_scr[...] += jax.lax.dot_general(
+            ds.astype(q_ref.dtype), q_ref[0], _ROWS_DOT,
+            preferred_element_type=jnp.float32)
+
+    needed, full = _band_predicates(q_start, k_start, block, window)
+    inside = iq < blocks
+    _band_when(needed & inside, full & inside, body)
+
+    @pl.when(t == group * band - 1)
+    def _flush():
+        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _band_forward(q3, k3, v3, *, heads, kv_heads, window, block, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, _ = q3.shape
+    d = q3.shape[2] // heads
+    group, blocks = heads // kv_heads, s // block
+    band = band_blocks(window, block, blocks)
+    tile = lambda index: pl.BlockSpec((1, block, d), index)  # noqa: E731
+    q_spec = tile(lambda ib, ih, iq, j: (ib, iq, ih))
+    kv_spec = tile(lambda ib, ih, iq, j: (
+        ib, jnp.maximum(iq - band + 1 + j, 0), ih // group))
+    lse_spec = pl.BlockSpec((1, 1, 1, block),
+                            lambda ib, ih, iq, j: (ib, ih, 0, iq))
+    return pl.pallas_call(
+        functools.partial(_band_fwd_kernel, window=window, band=band),
+        grid=(b, heads, blocks, band),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, lse_spec],
+        out_shape=[jax.ShapeDtypeStruct(q3.shape, q3.dtype),
+                   jax.ShapeDtypeStruct((b, heads, 1, s), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block, _LANES), jnp.float32)] * 2
+        + [pltpu.VMEM((block, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(q3, k3, v3)
+
+
+def _band_backward(q3, k3, v3, o3, lse, do3, *, heads, kv_heads, window,
+                   block, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, _ = q3.shape
+    d = q3.shape[2] // heads
+    group, blocks = heads // kv_heads, s // block
+    band = band_blocks(window, block, blocks)
+    delta = jnp.moveaxis(
+        (do3.astype(jnp.float32) * o3.astype(jnp.float32))
+        .reshape(b, s, heads, d).sum(axis=-1), 1, 2)[:, :, None, :]
+    tile = lambda index: pl.BlockSpec((1, block, d), index)  # noqa: E731
+    row = lambda index: pl.BlockSpec((1, 1, 1, block), index)  # noqa: E731
+    params = pltpu.CompilerParams(dimension_semantics=(
+        "parallel", "parallel", "parallel", "arbitrary"))
+
+    # dq: the forward's grid.
+    q_spec = tile(lambda ib, ih, iq, j: (ib, iq, ih))
+    kv_spec = tile(lambda ib, ih, iq, j: (
+        ib, jnp.maximum(iq - band + 1 + j, 0), ih // group))
+    row_spec = row(lambda ib, ih, iq, j: (ib, ih, 0, iq))
+    dq = pl.pallas_call(
+        functools.partial(_band_dq_kernel, window=window, band=band),
+        grid=(b, heads, blocks, band),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q3.shape, q3.dtype),
+        scratch_shapes=[pltpu.VMEM((block, d), jnp.float32)],
+        compiler_params=params, interpret=interpret,
+    )(q3, k3, v3, do3, lse, delta)
+
+    # dk, dv: a K/V head's key block, the band's query blocks of each of its
+    # query heads.
+    def head(ikv, t):
+        return ikv * group + t // band
+
+    def q_block(ik, t):
+        return jnp.minimum(ik + t % band, blocks - 1)
+
+    q_spec = tile(lambda ib, ikv, ik, t: (ib, q_block(ik, t), head(ikv, t)))
+    kv_spec = tile(lambda ib, ikv, ik, t: (ib, ik, ikv))
+    row_spec = row(lambda ib, ikv, ik, t: (ib, head(ikv, t), 0,
+                                           q_block(ik, t)))
+    dk, dv = pl.pallas_call(
+        functools.partial(_band_dkv_kernel, window=window, band=band,
+                          blocks=blocks, group=group),
+        grid=(b, kv_heads, blocks, group * band),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct(k3.shape, k3.dtype),
+                   jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
+        scratch_shapes=[pltpu.VMEM((block, d), jnp.float32)] * 2,
+        compiler_params=params, interpret=interpret,
+    )(q3, k3, v3, do3, lse, delta)
+    return dq, dk, dv
+
+
+@functools.lru_cache(maxsize=None)
+def _band_kernels(window: int, block: int, heads: int, kv_heads: int,
+                  interpret: bool):
+    """custom_vjp'd banded attention over folded ``[b, s, heads * d]``
+    operands (q rotated and scaled). The bodies are jitted under names of
+    their own (``band_flash_fwd``, ``band_flash_bwd``), so that a trace's
+    op names tell them from the streaming kernels."""
+    kw = dict(heads=heads, kv_heads=kv_heads, window=window, block=block,
+              interpret=interpret)
+
+    @jax.jit
+    def band_flash_fwd(q3, k3, v3):
+        return _band_forward(q3, k3, v3, **kw)
+
+    @jax.jit
+    def band_flash_bwd(q3, k3, v3, o3, lse, do3):
+        return _band_backward(q3, k3, v3, o3, lse, do3, **kw)
+
+    @jax.custom_vjp
+    def band(q3, k3, v3):
+        return band_flash_fwd(q3, k3, v3)[0]
+
+    def fwd(q3, k3, v3):
+        o3, lse = band_flash_fwd(q3, k3, v3)
+        return o3, (q3, k3, v3, o3, lse)
+
+    def bwd(res, do3):
+        return band_flash_bwd(*res, do3)
+
+    band.defvjp(fwd, bwd)
+    return band
+
+
+def band_attention(q, k, v, *, window: int, rope=None,
+                   block: Optional[int] = None, interpret: bool = False):
+    """Causal attention within a band of ``window`` positions through the
+    banded kernels; BSHD in, BSHD out. ``rope=(cos, sin)`` (``[s, d]``
+    tables, any scaling already in them) rotates q and k here, in f32, and
+    q takes the ``1/sqrt(d)`` scale before its one rounding to the compute
+    type. ``block`` defaults to ``band_block``'s choice."""
+    from tpu_trainer.ops.rope import rotate_half
+
+    b, s, h, d = q.shape
+    kvh = k.shape[2]
+    block = block or band_block(s, d, interpret)
+    if block is None or s % block:
+        raise ValueError(f"no banded block divides s={s} at head_dim {d}")
+    q32 = q.astype(jnp.float32)
+    if rope is not None:
+        cos = rope[0].astype(jnp.float32)[None, :, None, :]
+        sin = rope[1].astype(jnp.float32)[None, :, None, :]
+        q32 = q32 * cos + rotate_half(q32) * sin
+        k32 = k.astype(jnp.float32)
+        k = (k32 * cos + rotate_half(k32) * sin).astype(k.dtype)
+    q = (q32 * (1.0 / math.sqrt(d))).astype(q.dtype)
+    fn = _band_kernels(min(window, s), block, h, kvh, interpret)
+    return fn(q.reshape(b, s, h * d), k.reshape(b, s, kvh * d),
+              v.reshape(b, s, kvh * d)).reshape(b, s, h, d)
 
 
 # --- flash-decode: single-query attention over a PAGED KV cache -------------

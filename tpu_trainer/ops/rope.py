@@ -9,6 +9,7 @@ reference persists them as buffers in every state_dict (SURVEY.md §2.1 b8).
 
 from __future__ import annotations
 
+import math
 from typing import Tuple
 
 import jax
@@ -28,6 +29,44 @@ def rope_tables(
     freqs = jnp.outer(t, inv_freq)
     emb = jnp.concatenate([freqs, freqs], axis=-1)
     return jnp.cos(emb), jnp.sin(emb)
+
+
+def yarn_inv_freq(dim: int, base: float, factor: float,
+                  original_max_position: int, beta_fast: float,
+                  beta_slow: float) -> jax.Array:
+    """YaRN's inverse frequencies (arXiv:2309.00071; transformers'
+    ``_compute_yarn_parameters`` with ``truncate``): frequency ``i`` of
+    ``dim / 2`` is ``ramp_i theta_i / factor + (1 - ramp_i) theta_i``,
+    ``theta_i = base^(-2i / dim)``, the ramp ``clip((i - low) / (high -
+    low), 0, 1)`` between ``low = floor(c(beta_fast))`` and ``high =
+    ceil(c(beta_slow))``, ``c(beta) = dim ln(original_max_position / (2 pi
+    beta)) / (2 ln base)``: the dimension whose wavelength turns ``beta``
+    times over the original context."""
+    def correction(beta):
+        return (dim * math.log(original_max_position / (beta * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(correction(beta_fast)), 0)
+    high = min(math.ceil(correction(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    theta = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return ramp * theta / factor + (1.0 - ramp) * theta
+
+
+def yarn_tables(seq_len: int, dim: int, base: float, factor: float,
+                original_max_position: int, beta_fast: float,
+                beta_slow: float, attention_factor: float
+                ) -> Tuple[jax.Array, jax.Array]:
+    """``rope_tables`` at YaRN's frequencies (``yarn_inv_freq``), cos and
+    sin both times ``attention_factor``: ``[seq_len, dim]`` each."""
+    inv_freq = yarn_inv_freq(dim, base, factor, original_max_position,
+                             beta_fast, beta_slow)
+    freqs = jnp.outer(jnp.arange(seq_len, dtype=jnp.float32), inv_freq)
+    emb = jnp.concatenate([freqs, freqs], axis=-1)
+    return jnp.cos(emb) * attention_factor, jnp.sin(emb) * attention_factor
 
 
 def rotate_half(x: jax.Array) -> jax.Array:
